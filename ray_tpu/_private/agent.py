@@ -329,19 +329,14 @@ class Agent:
             staged = await loop.run_in_executor(None, _stage_dir, self.scratch_dir, mod)
             extra_paths.append(staged if os.path.isdir(staged) else os.path.dirname(staged))
         argv = [sys.executable, "-m", "ray_tpu._private.worker_main"]
-        if needs_tpu:
-            env.pop("JAX_PLATFORMS", None)
-        else:
-            if "JAX_PLATFORMS" not in user_env_vars:
-                env["JAX_PLATFORMS"] = "cpu"
-            argv.insert(1, "-S")
-        # workers run -S: carry this agent's sys.path (plus staged dirs first)
-        from .spawn import child_pythonpath
+        # the worker imports what this agent imports (staged dirs first)
+        from .spawn import child_pythonpath, set_worker_jax_env
 
         env["PYTHONPATH"] = child_pythonpath(
             extra_paths,
             inherited=env["PYTHONPATH"] if "PYTHONPATH" in user_env_vars else None,
         )
+        set_worker_jax_env(env, needs_tpu, user_env_vars)
         if cfg.log_to_driver:
             # per-worker log file; _log_forward_loop tails it and sends
             # increments to the head, which republishes to drivers

@@ -2103,12 +2103,30 @@ class Head:
         # release the actor's node resources NOW: state is already "dead",
         # so the worker-death path's release is skipped — without this the
         # resources leak and pending actors starve (deadlock under kill-
-        # and-replace loops like Tune teardown / Serve scale-down)
-        self._release_actor_node(rec, w)
+        # and-replace loops like Tune teardown / Serve scale-down).
+        # Except a TPU share: the chip is free only once the process that
+        # opened it is gone, and the next TPU actor must not start before.
+        holds_chip = (rec.spec.get("resources") or {}).get("TPU", 0) > 0
+        if not holds_chip:
+            self._release_actor_node(rec, w)
         if w is not None:
             await self._kill_worker(w, reason="actor killed")
+            if holds_chip and w.proc is not None:
+                await self._wait_proc_exit(w.proc)
+        self._release_actor_node(rec, w)  # idempotent
         await self._fail_backlog(rec)
         return True
+
+    @staticmethod
+    async def _wait_proc_exit(proc: subprocess.Popen, grace_s: float = 10.0):
+        """Wait for a terminated local worker to be gone; SIGKILL it when
+        the grace runs out."""
+        deadline = time.monotonic() + grace_s
+        while proc.poll() is None:
+            if time.monotonic() > deadline:
+                proc.kill()
+                deadline = float("inf")
+            await asyncio.sleep(0.02)
 
     def _adopt_actor_resources(self, rec: ActorRecord, node_id: str) -> None:
         """Charge a re-adopted (head-restart survivor) actor against its
@@ -3481,16 +3499,15 @@ class Head:
                 staged = await loop.run_in_executor(None, self._stage_dir, mod)
                 # a staged single-file module is importable via its parent
                 extra_paths.append(staged if os.path.isdir(staged) else os.path.dirname(staged))
-        if extra_paths:
-            # workers run -S, so PYTHONPATH must carry the full driver
-            # sys.path (site-packages included), with staged dirs first and
-            # any user-specified PYTHONPATH in between
-            from .spawn import child_pythonpath
+        # the worker imports what the driver imports: staged dirs first,
+        # a user-specified PYTHONPATH next, then the driver's sys.path
+        from .spawn import child_pythonpath, set_worker_jax_env
 
-            env["PYTHONPATH"] = child_pythonpath(
-                extra_paths,
-                inherited=env["PYTHONPATH"] if "PYTHONPATH" in user_env_vars else None,
-            )
+        env["PYTHONPATH"] = child_pythonpath(
+            extra_paths,
+            inherited=env["PYTHONPATH"] if "PYTHONPATH" in user_env_vars else None,
+        )
+        set_worker_jax_env(env, needs_tpu, user_env_vars)
         argv = [sys.executable, "-m", "ray_tpu._private.worker_main"]
         log_file = None
         if cfg.log_to_driver:
@@ -3500,30 +3517,6 @@ class Head:
             log_dir = os.path.join(self.session_dir, "logs")
             os.makedirs(log_dir, exist_ok=True)
             log_file = open(os.path.join(log_dir, f"{worker_id}.out"), "ab")
-        if needs_tpu:
-            # TPU workers get the full interpreter (site hooks may register
-            # the PJRT plugin) and inherit JAX_PLATFORMS as-is.
-            env.pop("JAX_PLATFORMS", None)
-        else:
-            # Non-TPU workers must not grab the chips: exactly one process per
-            # host may own them. Overwrite (not setdefault) — the inherited
-            # value may name a TPU plugin platform whose registration hook
-            # lives in `site` packages, which -S below skips. Also skip `site`
-            # (-S) — site hooks can be arbitrarily slow — and hand down the
-            # driver's sys.path instead.
-            if "JAX_PLATFORMS" not in user_env_vars:
-                env["JAX_PLATFORMS"] = "cpu"
-            if not extra_paths:
-                # always hand down sys.path: with -S and only a user
-                # PYTHONPATH the child could not even import ray_tpu
-                from .spawn import child_pythonpath
-
-                env["PYTHONPATH"] = child_pythonpath(
-                    inherited=env["PYTHONPATH"]
-                    if "PYTHONPATH" in user_env_vars
-                    else None,
-                )
-            argv.insert(1, "-S")
         if log_file is not None:
             env["PYTHONUNBUFFERED"] = "1"  # prints reach the tail promptly
             w.proc = subprocess.Popen(

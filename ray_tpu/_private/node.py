@@ -8,7 +8,6 @@ hosts the head service on a background asyncio thread of the driver process
 
 from __future__ import annotations
 
-import glob
 import os
 import shutil
 import time
@@ -17,20 +16,8 @@ from typing import Dict, Optional
 
 from .config import GLOBAL_CONFIG as cfg
 from .head import Head
+from .spawn import detect_tpu_chips
 from .worker import EventLoopThread
-
-
-def detect_tpu_chips() -> int:
-    """Count local TPU chips without importing jax (device files on TPU VMs)."""
-    n = len(glob.glob("/dev/accel*"))
-    if n:
-        return n
-    if os.environ.get("TPU_CHIPS_PER_HOST_BOUNDS"):
-        try:
-            return int(os.environ["TPU_CHIPS_PER_HOST_BOUNDS"].split(",")[-1])
-        except ValueError:
-            pass
-    return 0
 
 
 def default_resources(num_cpus=None, num_tpus=None, resources=None) -> Dict[str, float]:

@@ -84,9 +84,7 @@ class Cluster:
         env.setdefault("JAX_PLATFORMS", "cpu")
         # own process group: kill_node(force) can take the whole node (agent
         # + its workers) down at once, like killing a host
-        proc = subprocess.Popen(
-            [sys.executable, "-S"] + argv[1:], env=env, start_new_session=True
-        )
+        proc = subprocess.Popen(argv, env=env, start_new_session=True)
         self._procs[node_id] = proc
         self._nodes.append(node_id)
         if wait:

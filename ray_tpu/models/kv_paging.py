@@ -56,6 +56,7 @@ stats() reads are safe from other threads (plain int reads).
 from __future__ import annotations
 
 import hashlib
+import logging
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -70,6 +71,8 @@ from .transformer import (
     make_paged_decoder,
     paged_kv_block_bytes,
 )
+
+logger = logging.getLogger(__name__)
 
 
 class InsufficientBlocksError(RuntimeError):
@@ -363,12 +366,27 @@ class PagedDecodeEngine:
         fused_impl = "auto"
         if attention_impl.startswith("fused:"):
             attention_impl, fused_impl = "fused", attention_impl[6:]
+        # the device this engine computes on, resolved once and reported in
+        # stats(): nothing below may choose a path from the backend again
+        self._device = jax.devices()[0]
+        self.platform = self._device.platform
+        self.device_kind = self._device.device_kind
+        if self.platform != "tpu":
+            from ray_tpu._private.spawn import detect_tpu_chips
+
+            if detect_tpu_chips():
+                # legitimate for CPU rollout workers; for a serving replica
+                # it means the deployment was not granted the node's TPU
+                logger.warning(
+                    "PagedDecodeEngine is computing on %r in a process "
+                    "pinned off this host's TPU (stats()['platform'] says "
+                    "so); a replica meant for the chip must hold a TPU "
+                    "resource", self.platform,
+                )
         if attention_impl == "auto":
             # the fused kernel is the TPU fast path; the gather step stays
             # the exact (and cheapest-to-dispatch) path on CPU CI hosts
-            attention_impl = (
-                "fused" if jax.default_backend() == "tpu" else "gather"
-            )
+            attention_impl = "fused" if self.platform == "tpu" else "gather"
         if attention_impl not in ("gather", "fused") or fused_impl not in (
             "auto", "kernel", "xla"
         ):
@@ -379,6 +397,19 @@ class PagedDecodeEngine:
                 f"got {attention_impl!r}"
                 + (f" with backend {fused_impl!r}" if fused_impl != "auto"
                    else "")
+            )
+        if fused_impl == "auto":
+            fused_impl = "kernel" if self.platform == "tpu" else "xla"
+        # what actually attends: the Pallas kernel compiled by Mosaic, the
+        # same kernel under the Pallas interpreter (CPU tests only), its
+        # chunked XLA twin, or the gather step
+        if attention_impl == "gather":
+            self.attention_kernel = "gather"
+        elif fused_impl == "xla":
+            self.attention_kernel = "xla"
+        else:
+            self.attention_kernel = (
+                "pallas" if self.platform == "tpu" else "pallas-interpret"
             )
         self.attention_impl = attention_impl
         chunk_blocks = int(
@@ -1591,6 +1622,7 @@ class PagedDecodeEngine:
 
     def stats(self) -> Dict[str, Any]:
         used = self.allocator.num_usable - self.allocator.num_free
+        mem = self._device.memory_stats() or {}  # None on the CPU backend
         return {
             # flight recorder (serve/telemetry.py): events currently held
             # in the ring + lifetime total (dropped = total - held)
@@ -1613,6 +1645,12 @@ class PagedDecodeEngine:
             "block_tokens": self.block_tokens,
             "kv_cache_dtype": self.kv_cache_dtype,
             "attention_impl": self.attention_impl,
+            "attention_kernel": self.attention_kernel,
+            "platform": self.platform,
+            "device_kind": self.device_kind,
+            "device_bytes_in_use": mem.get("bytes_in_use"),
+            "device_peak_bytes": mem.get("peak_bytes_in_use"),
+            "device_bytes_limit": mem.get("bytes_limit"),
             "attention_chunk_blocks": self.chunk_blocks,
             "kv_block_bytes": self.kv_block_bytes,
             # true pool HBM: counts the reserved null block too, so this

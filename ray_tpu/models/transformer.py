@@ -447,22 +447,37 @@ def make_forward(
         if inner_attn is not None and mesh is not None:
             from jax.sharding import PartitionSpec as P
 
-            from ..parallel.sharding import shard_map_compat
+            from ..parallel.sharding import manual_shard_map
 
             spec = P(None, "sp", None, None)
-            return shard_map_compat(
+            return manual_shard_map(
                 inner_attn, mesh, (spec, spec, spec), spec, {"sp"}
             )(q, k, v)
         if head_major:
             if cfg.attention == "flash":
                 from ..ops.flash_attention import flash_attention
 
-                return flash_attention(
-                    q, k, v,
+                flash = partial(
+                    flash_attention,
                     block_q=min(cfg.flash_block_q, q.shape[2]),
                     block_k=min(cfg.flash_block_k, k.shape[2]),
                     layout="bhsd",
                 )
+                if mesh is None or rules is None or mesh.size == 1:
+                    return flash(q, k, v)
+                # GSPMD cannot partition a Mosaic kernel ("wrap the call
+                # in a shard_map"): run it per shard of batch and heads —
+                # attention is independent across both. Manual over EVERY
+                # mesh axis: the compiler refuses the kernel while any
+                # axis, even one of size 1, is left to it
+                from ..parallel.sharding import manual_shard_map
+
+                q_spec = rules.spec("batch", "heads", None, None)
+                kv_spec = rules.spec("batch", "kv_heads", None, None)
+                return manual_shard_map(
+                    flash, mesh, (q_spec, kv_spec, kv_spec), q_spec,
+                    mesh.axis_names,
+                )(q, k, v)
             return causal_attention_bhsd(q, k, v)
         # ring/ulysses without a mesh: dense correctness oracle
         return causal_attention(q, k, v)
@@ -554,7 +569,6 @@ def make_forward(
             # (flat ICI pipeline) or ("dcn", "pp") (multislice pp-outer:
             # stage-groups mapped one per slice, boundary hops over DCN)
             stage_axes = rules.mesh_axes("stage") if rules is not None else None
-            batch_axes = rules.mesh_axes("batch") if rules is not None else None
             return pipeline_apply(
                 stage_fn,
                 params["layers"],
@@ -562,7 +576,6 @@ def make_forward(
                 mesh=mesh,
                 n_microbatches=cfg.pp_microbatches,
                 axis_name=stage_axes or "pp",
-                batch_axes=batch_axes if batch_axes is not None else ("dp", "fsdp"),
                 virtual_stages_per_device=cfg.pp_interleave,
             )
         if not cfg.scan_layers:
@@ -937,7 +950,7 @@ def make_paged_decoder(
         from jax.sharding import PartitionSpec as P
 
         from ..ops.paged_attention import merge_partials, paged_attention
-        from ..parallel.sharding import shard_map_compat
+        from ..parallel.sharding import manual_shard_map
 
         scales = dict(k_scale=ksc, v_scale=vsc) if quant else {}
         block_axes = _flat_axes("batch")
@@ -1009,7 +1022,7 @@ def make_paged_decoder(
             (qspec, P(None, None, hspec), P(None, None, hspec))
             if partial else qspec
         )
-        return shard_map_compat(
+        return manual_shard_map(
             inner, mesh, tuple(in_specs), out_specs, manual
         )(*args)
 

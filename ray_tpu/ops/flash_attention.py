@@ -26,12 +26,15 @@ kernels.
 from __future__ import annotations
 
 import functools
+import logging
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+logger = logging.getLogger(__name__)
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -392,6 +395,12 @@ def flash_attention(
     block_q = min(block_q, q.shape[seq_axis])
     block_k = min(block_k, k.shape[seq_axis])
     if q.shape[seq_axis] % block_q or k.shape[seq_axis] % block_k:
+        # loud, once per trace: dense attention materializes [L, L] scores
+        logger.warning(
+            "flash_attention: seq %d/%d does not tile by blocks %d/%d — "
+            "tracing DENSE attention instead of the kernel",
+            q.shape[seq_axis], k.shape[seq_axis], block_q, block_k,
+        )
         dense = causal_attention_bhsd if layout == "bhsd" else causal_attention
         return dense(q, k, v, scale=scale, causal=causal)
     if q.shape[head_axis] % k.shape[head_axis]:

@@ -71,17 +71,6 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _mosaic_params(dimension_semantics):
-    """compiler_params across jax versions: the dataclass was named
-    TPUCompilerParams on 0.4.x/0.5.x, CompilerParams later; before either,
-    pallas_call took a {"mosaic": {...}} dict."""
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None
-    )
-    if cls is not None:
-        return cls(dimension_semantics=dimension_semantics)
-    return dict(mosaic=dict(dimension_semantics=dimension_semantics))
-
 # last resolved implementation ("kernel" | "xla"), recorded at trace time —
 # test observability: parity suites assert the path they intended to
 # exercise actually ran instead of silently falling back
@@ -145,9 +134,10 @@ def _pa_kernel(tables_ref, pos_ref, kvlen_ref, q_ref, k_ref, v_ref, *rest,
         k = k_ref[0]  # [bt, KV, D]
         v = v_ref[0]
         if quantized:
-            blk = jnp.maximum(entry, 0)
-            k = k.astype(jnp.float32) * ks_ref[blk][None, :, None]
-            v = v.astype(jnp.float32) * vs_ref[blk][None, :, None]
+            # scale tiles are [1, KV, 1]: KV already sits on the sublane
+            # axis it has in k/v, so this is a lane broadcast, no relayout
+            k = k.astype(jnp.float32) * ks_ref[...]
+            v = v.astype(jnp.float32) * vs_ref[...]
         else:
             k = k.astype(jnp.float32)
             v = v.astype(jnp.float32)
@@ -223,10 +213,16 @@ def _paged_attention_pallas(q, k_pool, v_pool, ptable, positions, kv_len,
     in_specs = [q_spec, kv_spec, kv_spec]
     operands = [q, k_pool, v_pool]
     if quantized:
-        # scales ride whole in VMEM ([N, KV] f32 is tiny) and are indexed
-        # in-body — a (1, KV) block would fight the sublane tiling rules
-        in_specs += [pl.BlockSpec(memory_space=pltpu.ANY)] * 2
-        operands += [k_scale, v_scale]
+        # a block's scales follow its K/V tile through the same table
+        # lookup. [N, KV] goes in as [N, KV, 1] so the (1, KV, 1) tile
+        # spans the array's last two dims whole — a (1, KV) block of the
+        # 2-D array would break the sublane tiling rule
+        sc_spec = pl.BlockSpec(
+            (1, kv, 1),
+            lambda b_, qt_, j_, tbl, pos, kvl: (jnp.maximum(tbl[b_, j_], 0), 0, 0),
+        )
+        in_specs += [sc_spec, sc_spec]
+        operands += [k_scale[:, :, None], v_scale[:, :, None]]
     o_map = lambda b_, qt_, j_, *_: (b_, qt_, 0, 0)
     if partial_out:
         out_specs = [
@@ -265,7 +261,9 @@ def _paged_attention_pallas(q, k_pool, v_pool, ptable, positions, kv_len,
         # j == 0); the block walk is sequential — it carries the
         # online-softmax scratch. Telling Mosaic lets it
         # parallelize/pipeline over (b, qt) while keeping each walk ordered.
-        compiler_params=_mosaic_params(("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
         interpret=interpret,
     )(ptable, positions, kv_len, *operands)
     if partial_out:

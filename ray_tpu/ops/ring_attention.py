@@ -89,7 +89,7 @@ def make_sharded_ring_attention(mesh, axis_name: str = "sp", causal: bool = True
 
     spec = P(None, axis_name, None, None)
 
-    from ..parallel.sharding import shard_map_compat
+    from ..parallel.sharding import manual_shard_map
 
     fn = partial(ring_attention, axis_name=axis_name, causal=causal)
-    return shard_map_compat(fn, mesh, (spec, spec, spec), spec, {axis_name})
+    return manual_shard_map(fn, mesh, (spec, spec, spec), spec, {axis_name})

@@ -21,7 +21,6 @@ import math
 from typing import Optional, Sequence, Tuple
 
 import jax
-import numpy as np
 from jax.sharding import Mesh
 
 AXIS_ORDER: Tuple[str, ...] = ("pp", "dp", "fsdp", "ep", "sp", "tp")
@@ -100,12 +99,11 @@ def build_mesh(
     devices = list(devices if devices is not None else jax.devices())
     spec = (spec or MeshSpec()).resolve(len(devices))
     shape = tuple(spec.degrees()[a] for a in axis_order)
-    try:
-        from jax.experimental import mesh_utils
+    from jax.experimental import mesh_utils
 
-        dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
-    except Exception:
-        dev_array = np.array(devices).reshape(shape)
+    # raises when the degrees cannot be laid on the chips' physical
+    # topology — a plain reshape there would run, on the wrong links
+    dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
     return Mesh(dev_array, axis_order)
 
 

@@ -231,7 +231,6 @@ def pipeline_apply(
     mesh,
     n_microbatches: int,
     axis_name: Union[str, Tuple[str, ...]] = "pp",
-    batch_axes: Union[None, str, Tuple[str, ...]] = ("dp", "fsdp"),
     virtual_stages_per_device: int = 1,
     stage_order: str = "model",
 ):
@@ -244,14 +243,6 @@ def pipeline_apply(
     axis_name: mesh axis the stages live on, or a ("dcn", "pp") pair for
     multi-slice stage→slice placement — stages are laid out slice-major
     (dcn-major), so stage s lives on slice s // stages_per_slice.
-
-    batch_axes: mesh axes the batch dim is sharded over (the rule table's
-    "batch" mapping). Only used by the jax-0.4.x fully-manual fallback,
-    which would otherwise all-gather the batch to full replication at the
-    region boundary — a gather GSPMD is then free to route over the slow
-    `dcn` axis. Keeping the batch sharded through the region keeps every
-    non-pipeline byte on ICI (the multislice byte-counter tests assert
-    exactly this).
 
     virtual_stages_per_device: v>1 switches to the interleaved schedule —
     each device runs v round-robin stage chunks (stage chunk q on device
@@ -306,17 +297,6 @@ def pipeline_apply(
     x_mb = x.reshape((n_microbatches, mb) + x.shape[1:])
 
     x_spec = P()
-    if not hasattr(jax, "shard_map"):
-        if isinstance(batch_axes, str):
-            batch_axes = (batch_axes,)
-        bax = tuple(
-            a for a in (batch_axes or ()) if a in mesh.shape and a not in axes
-        )
-        n_bax = 1
-        for a in bax:
-            n_bax *= mesh.shape[a]
-        if n_bax > 1 and mb % n_bax == 0:
-            x_spec = P(None, bax)
 
     stage_spec = P(axes if len(axes) > 1 else axes[0])
     pspec = jax.tree.map(lambda _: stage_spec, stage_params)
@@ -327,9 +307,9 @@ def pipeline_apply(
         n_microbatches=n_microbatches,
         virtual_stages_per_device=v,
     )
-    from .sharding import shard_map_compat
+    from .sharding import manual_shard_map
 
-    out_mb = shard_map_compat(
+    out_mb = manual_shard_map(
         fn, mesh, (pspec, x_spec), x_spec, set(axes)
     )(stage_params, x_mb)
     return out_mb.reshape((b,) + out_mb.shape[2:])

@@ -14,17 +14,10 @@ FSDP :92-101, DeepSpeed launcher) with one declarative table:
 
 from __future__ import annotations
 
-import contextvars
 from typing import Dict, Optional, Tuple, Union
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-# True while TRACING the body of a fully-manual shard_map fallback region
-# (shard_map_compat on jax 0.4.x) — constrain() must no-op there
-_IN_MANUAL_REGION: "contextvars.ContextVar[bool]" = contextvars.ContextVar(
-    "ray_tpu_in_manual_shard_map", default=False
-)
 
 # canonical logical axis names used by models/
 LOGICAL_AXES = (
@@ -158,47 +151,26 @@ def logical_sharding(mesh: Mesh, rules: ShardingRules, *axes: Optional[str]) -> 
 
 
 def constrain(x, rules: ShardingRules, *axes: Optional[str], mesh: Optional[Mesh] = None):
-    """with_sharding_constraint by logical names (inside jit). Inside a
-    fully-manual shard_map_compat fallback region constraints are a no-op:
-    every mesh axis is manual there, and 0.4.x rejects constraints naming
-    manual axes (they were only GSPMD layout hints anyway)."""
-    if _IN_MANUAL_REGION.get():
-        return x
+    """with_sharding_constraint by logical names (inside jit)."""
     spec = rules.spec(*axes)
     if mesh is not None:
         return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
     return jax.lax.with_sharding_constraint(x, spec)
 
 
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs, manual_axes):
-    """shard_map across jax versions. The public `jax.shard_map`
-    (check_vma/axis_names kwargs) landed after 0.4.x and supports
-    partial-manual lowering (manual over `manual_axes`, GSPMD elsewhere).
-    Older releases only have jax.experimental.shard_map.shard_map, whose
-    partial-manual `auto=` mode is the unstable half (all_to_all under
-    non-empty auto SIGABRTs 0.4.37) — so the fallback goes FULLY manual:
-    axes the specs don't mention are replicated into the region, which
-    preserves semantics at the cost of an all-gather when the caller had
-    them sharded. Fine for CPU-mesh CI; real TPU installs carry a jax with
-    the native path."""
+def manual_shard_map(f, mesh: Mesh, in_specs, out_specs, manual_axes):
+    """jax.shard_map, manual over `manual_axes` only: the other mesh axes
+    stay under GSPMD inside the region (partial-manual lowering). Nested
+    inside another manual region (flash attention under the pipeline's),
+    the region takes the context mesh and claims only the axes that are
+    still automatic."""
     manual = frozenset(manual_axes)
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False, axis_names=manual,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def traced(*args):
-        token = _IN_MANUAL_REGION.set(True)
-        try:
-            return f(*args)
-        finally:
-            _IN_MANUAL_REGION.reset(token)
-
-    return _shard_map(
-        traced, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
+    outer = jax.sharding.get_abstract_mesh()
+    if not outer.empty and outer.manual_axes:
+        mesh, manual = None, manual - frozenset(outer.manual_axes)
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False, axis_names=manual,
     )
 
 

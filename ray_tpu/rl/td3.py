@@ -159,7 +159,7 @@ class TD3Learner(Learner):
             params = {"nets": nets, "target": target, "it": it}
             return TrainState(params, opt_state, rng), metrics
 
-        # NOT donated: on this rig's jax build (0.4.37 CPU), THIS executable
+        # NOT donated: on the CPU backend THIS executable
         # comes back from the persistent compilation cache (tests/conftest.py)
         # with its donated-input aliasing broken — nets/target outputs return
         # the unmodified inputs (targets never move) while `it` and the

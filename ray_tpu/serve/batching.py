@@ -518,6 +518,9 @@ class ContinuousBatcher:
                           "kv_blocks_cached", "preemptions", "prefix_hits",
                           "kv_block_bytes", "kv_pool_bytes",
                           "kv_cache_dtype", "attention_impl",
+                          "attention_kernel", "platform", "device_kind",
+                          "device_bytes_in_use", "device_peak_bytes",
+                          "device_bytes_limit",
                           "prefill_chunk_tokens", "prefill_chunks",
                           "chunked_prefills", "prefilling",
                           "prefill_tokens", "prefix_tokens_reused",

@@ -29,6 +29,10 @@ class _DeploymentState:
         self.init_kwargs = init_kwargs
         self.config: DeploymentConfig = config
         self.replicas: List[Any] = []  # ActorHandles
+        # actor ids of replicas whose constructor has not answered ready()
+        # yet: a model-sized __init__ outlasts the health check's deadline,
+        # and a starting replica is not a dead one
+        self.starting: set = set()
         self.draining = False  # whole deployment slated for removal
         # prefix-affinity digest: hint -> (replica actor_id, cached chain
         # depth in blocks). Bounded LRU, harvested from replica stats on
@@ -431,6 +435,22 @@ class ServeController:
 
         opts = dict(state.config.ray_actor_options)
         opts.setdefault("num_cpus", 1)
+        # the placement rule for accelerator deployments, decided here and
+        # nowhere else: a deployment class that builds a PagedDecodeEngine
+        # (it says so with `runs_paged_engine`) takes one TPU chip per
+        # replica when the cluster has chips, so the engine is never
+        # built in a CPU-pinned worker beside an idle chip
+        wants = opts.get("num_tpus") or (opts.get("resources") or {}).get("TPU")
+        if (
+            not wants
+            and getattr(state.func_or_class, "runs_paged_engine", False)
+            and ray_tpu.cluster_resources().get("TPU", 0) >= 1
+        ):
+            wants = opts["num_tpus"] = 1
+        if wants:
+            from ..util.accelerators import require_tpus
+
+            require_tpus(wants, f"serve deployment {state.name!r}")
         ReplicaCls = ray_tpu.remote(Replica)
         return ReplicaCls.options(max_concurrency=8, **opts).remote(
             state.name, state.func_or_class, state.init_args, state.init_kwargs
@@ -439,8 +459,12 @@ class ServeController:
     def _reconcile(self, state: _DeploymentState):
         import ray_tpu
 
+        spawned = []
         while len(state.replicas) < state.target:
-            state.replicas.append(self._spawn_replica(state))
+            r = self._spawn_replica(state)
+            state.starting.add(r._actor_id)
+            spawned.append(r)
+            state.replicas.append(r)
         if len(state.replicas) > state.target:
             victims = state.replicas[state.target :]
             state.replicas = state.replicas[: state.target]
@@ -450,7 +474,10 @@ class ServeController:
             self._publish_replicas(state)
             self._drain_then_stop(victims, state.config)
         # block until new replicas constructed
-        ray_tpu.get([r.ready.remote() for r in state.replicas])
+        try:
+            ray_tpu.get([r.ready.remote() for r in state.replicas])
+        finally:
+            state.starting.difference_update(r._actor_id for r in spawned)
         self._publish_replicas(state)
 
     def _publish_replicas(self, state: _DeploymentState):
@@ -554,16 +581,21 @@ class ServeController:
     def _health_check(self, state: _DeploymentState):
         import ray_tpu
 
-        alive = []
-        dead = 0
+        alive, dead = [], []
         for r in state.replicas:
+            if r._actor_id in state.starting:
+                alive.append(r)  # still constructing: _reconcile is waiting
+                continue
             try:
                 ray_tpu.get(r.check_health.remote(), timeout=10)
                 alive.append(r)
             except Exception:
-                dead += 1
+                dead.append(r)
         if dead:
             state.replicas = alive
+            # a replica that fails its check may still hold its resources
+            # (a TPU replica holds the chip): reap it before replacing it
+            self._kill_replicas(dead)
             self._reconcile(state)  # replace dead replicas
 
     def _reconcile_loop(self):

@@ -408,6 +408,8 @@ class KVGenerationServer:
     Roles: "monolithic" (default — peer pulls within one deployment),
     "prefill" / "decode" (the two pools of deploy_disaggregated)."""
 
+    runs_paged_engine = True  # controller._spawn_replica: one chip per replica
+
     def __init__(self, cfg, *, weights_seed: int = 0,
                  engine_kwargs: Optional[Dict[str, Any]] = None,
                  deployment: str = "", role: str = "monolithic",
@@ -503,8 +505,9 @@ class KVGenerationServer:
             self.kv._update_hit_rate()
         return payload
 
-    def generate(self, tokens, max_new_tokens: int = 16) -> Dict[str, Any]:
-        toks = [int(t) for t in tokens]
+    def _submit(self, toks: List[int], max_new_tokens: int):
+        """Admit one generation (pulling its prefix over the transfer
+        path first, where this role does) and return its token stream."""
         payload = None
         if self.role == "decode" and self._prefill_handle is not None:
             payload = self._pull_from_prefill(toks)
@@ -513,18 +516,28 @@ class KVGenerationServer:
         kw: Dict[str, Any] = {}
         if payload is not None:
             kw["kv_import"] = payload
-        stream = self.batcher.submit(
+        return self.batcher.submit(
             tokens=toks, max_new_tokens=int(max_new_tokens), **kw
         )
-        out = [int(t) for t in stream]
+
+    def generate(self, tokens, max_new_tokens: int = 16) -> Dict[str, Any]:
+        toks = [int(t) for t in tokens]
+        out = [int(t) for t in self._submit(toks, max_new_tokens)]
         self.kv.note_prompt(toks)
         return {"tokens": out}
 
-    def __call__(self, body) -> Dict[str, Any]:
+    def __call__(self, body):
+        """HTTP ingress: `{"tokens": [...], "max_new_tokens": n}` answers
+        with the whole generation as JSON; with `"stream": true` each token
+        is its own server-sent event as the batcher emits it."""
         req = body if isinstance(body, dict) else {}
-        return self.generate(
-            req.get("tokens") or (), int(req.get("max_new_tokens") or 16)
-        )
+        toks = [int(t) for t in req.get("tokens") or ()]
+        max_new = int(req.get("max_new_tokens") or 16)
+        if req.get("stream"):
+            from .http_proxy import sse_stream
+
+            return sse_stream(self._submit(toks, max_new))
+        return self.generate(toks, max_new)
 
     def engine_stats(self) -> Dict[str, Any]:
         return self.batcher.stats()

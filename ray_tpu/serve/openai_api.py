@@ -253,6 +253,7 @@ class OpenAICompletions:
     replica process, owns engine + batcher, routes OpenAI requests."""
 
     _serve_ingress = True  # serve.run hands us the raw http_proxy.Request
+    runs_paged_engine = True  # controller._spawn_replica: one chip per replica
 
     def __init__(
         self,

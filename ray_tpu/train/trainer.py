@@ -91,40 +91,15 @@ class TrainWorker:
             )
             hb_s = int(gcfg.train_dist_heartbeat_timeout_s)
             if hb_s > 0:
-                # bound gang peer-death detection: jax's default
-                # coordination-service heartbeat budget (10s x 10 missing
-                # = ~100s) parks every SURVIVING rank that long at the
+                # bound gang peer-death detection: jax's default budget
+                # (100s) parks every SURVIVING rank that long at the
                 # shutdown barrier when a gang member dies hard — the
-                # latency floor of the whole gang-restart path. The knobs
-                # are not in the public initialize() on this jax line, so
-                # reach the internal state initializer (same call the
-                # wrapper makes) and fall back to defaults on any other
-                # jax internals. Heartbeats run on a C++ thread, so a
-                # long jit compile cannot miss them.
-                interval = max(1, hb_s // 6)
-                missing = max(2, -(-hb_s // interval))
-                try:
-                    from jax._src import distributed as _dist
-                    from jax._src import xla_bridge as _xb
-
-                    if _xb.backends_are_initialized():
-                        raise RuntimeError(
-                            "jax.distributed must initialize before any "
-                            "JAX computations"
-                        )
-                    _dist.global_state.initialize(
-                        **kwargs,
-                        service_heartbeat_interval_seconds=interval,
-                        service_max_missing_heartbeats=missing,
-                        client_heartbeat_interval_seconds=interval,
-                        client_max_missing_heartbeats=missing,
-                    )
-                    dist_inited = True
-                except (ImportError, AttributeError, TypeError):
-                    pass  # unknown jax internals: default heartbeats
-            if not dist_inited:
-                jax.distributed.initialize(**kwargs)
-                dist_inited = True
+                # latency floor of the whole gang-restart path. Heartbeats
+                # run on a C++ thread, so a long jit compile cannot miss
+                # them.
+                kwargs["heartbeat_timeout_seconds"] = hb_s
+            jax.distributed.initialize(**kwargs)
+            dist_inited = True
         self.ctx = TrainContext(
             world_rank=self.rank,
             world_size=self.world_size,
@@ -241,6 +216,10 @@ class JaxTrainer:
         sc = self.scaling_config
         n = sc.num_workers
         res = sc.worker_resources()
+        if res.get("TPU"):
+            from ray_tpu.util.accelerators import require_tpus
+
+            require_tpus(res["TPU"], "JaxTrainer's ScalingConfig")
         strategy = None
         if n > 1:
             pg = placement_group([dict(res) for _ in range(n)], strategy=sc.placement_strategy)

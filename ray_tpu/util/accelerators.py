@@ -79,3 +79,22 @@ def detect_local_generation() -> Optional[str]:
         except ValueError:
             return None
     return None
+
+
+def require_tpus(n: float, what: str) -> None:
+    """Raise now, with a message, when `what` asks for more TPU chips than
+    any node of this cluster has: its actor could never be placed and the
+    caller would otherwise wait on it in silence."""
+    import ray_tpu
+
+    have = max(
+        (nd["resources"].get("TPU", 0.0) for nd in ray_tpu.nodes() if nd["alive"]),
+        default=0.0,
+    )
+    if have < n:
+        raise RuntimeError(
+            f"{what} asks for TPU={n:g} but the largest node of this cluster "
+            f"has TPU={have:g}. Chips are counted from the host's device "
+            "nodes (/dev/accel<n>, /dev/vfio/<n>) when ray_tpu.init() starts "
+            "the head; pass num_tpus= to init() to state them yourself."
+        )

@@ -123,7 +123,7 @@ def test_ring_equals_dense_loss():
     np.testing.assert_allclose(float(dense), float(ring), rtol=2e-2)
 
 
-def test_moe_training():
+def test_moe_training(fresh_compile):
     cfg = CONFIGS["tiny_moe"]
     mesh = build_mesh(MeshSpec(dp=2, ep=4))
     rules = PRESET_RULES["fsdp_tp_ep"].with_overrides(embed=None, heads=None, mlp=None, vocab=None)
@@ -187,3 +187,36 @@ def test_hbm_limit_memory_levers():
     assert losses[-1] < losses[0] - 0.3, losses
     # gpt_1b is the HBM-limit config the bench uses; keep it registered
     assert CONFIGS["gpt_1b"].num_params() > 1.0e9
+
+
+@pytest.mark.parametrize(
+    "spec,rules,extra",
+    [
+        pytest.param(
+            MeshSpec(tp=2, fsdp=4), PRESET_RULES["fsdp_tp"], {}, id="tp2_fsdp4"
+        ),
+        pytest.param(
+            MeshSpec(pp=2, dp=4),
+            PRESET_RULES["full"].with_overrides(seq=None, kv_seq=None),
+            dict(pp_stages=2, pp_microbatches=2, n_layers=4),
+            id="pp2_dp4",
+        ),
+    ],
+)
+def test_flash_on_a_mesh_matches_dense(fresh_compile, spec, rules, extra):
+    """On a multi-device mesh the flash kernel runs per shard of batch and
+    heads inside a shard_map (a Mosaic kernel cannot be auto-partitioned)
+    — nested inside the pipeline's own manual region under pp. Same loss
+    as dense attention on the same mesh, parameters and batch."""
+    losses = {}
+    for attention in ("dense", "flash"):
+        cfg = dataclasses.replace(
+            CONFIGS["tiny"], attention=attention, dtype=jnp.float32, **extra
+        )
+        mesh = build_mesh(spec)
+        opt = default_optimizer(lr=1e-3, warmup=1)
+        init_fn, shardings = make_sharded_init(cfg, mesh, rules, opt)
+        step = make_train_step(cfg, mesh, rules, opt, shardings)
+        _, m = step(init_fn(jax.random.PRNGKey(0)), _batch(cfg))
+        losses[attention] = float(m["loss"])
+    np.testing.assert_allclose(losses["flash"], losses["dense"], rtol=1e-4)

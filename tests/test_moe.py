@@ -85,7 +85,7 @@ def test_dispatch_trains():
     assert float(jnp.abs(grads["layers"]["router"]).sum()) > 0
 
 
-def test_dispatch_multidevice_ep_sharding():
+def test_dispatch_multidevice_ep_sharding(fresh_compile):
     """The dispatch path compiles and runs under an ep-sharded mesh (GSPMD
     inserts the all-to-alls from the sharding constraints)."""
     if len(jax.devices()) < 8:

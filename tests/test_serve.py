@@ -233,3 +233,31 @@ def test_delete_application(serve_cluster):
     assert "f" in serve.status()
     serve.delete("todelete")
     assert "f" not in serve.status()
+
+
+def test_slow_constructor_is_not_replaced(serve_cluster):
+    """A replica whose __init__ outlasts the health check's 10 s deadline
+    (a model-sized constructor: weights, engine, compiles) is still
+    STARTING, not dead. The control loop used to drop it unreaped and
+    spawn a replacement every pass — on a one-chip host the replacement
+    then waited forever for the TPU the first one held."""
+    from ray_tpu.experimental.state.api import list_actors
+    from ray_tpu.serve.handle import CONTROLLER_NAME
+
+    @serve.deployment
+    class Slow:
+        def __init__(self):
+            time.sleep(12)
+
+        def __call__(self, _):
+            import os
+
+            return os.getpid()
+
+    h = serve.run(Slow.bind(), name="slow")
+    pid = h.remote(None).result(timeout_s=30)
+    assert h.remote(None).result(timeout_s=30) == pid
+    ctl = ray_tpu.get_actor(CONTROLLER_NAME)
+    assert len(ray_tpu.get(ctl.get_replicas.remote("Slow"), timeout=10)) == 1
+    replicas = [a for a in list_actors() if a.get("class_name") == "Replica"]
+    assert len(replicas) == 1, replicas

@@ -52,13 +52,25 @@ def _gen(eng, slot, prompt, n):
 
 class _WrongDrafter:
     """Proposes k confidently wrong tokens: every draft rejects, so every
-    verify step exercises the full rollback path."""
+    verify step exercises the full rollback path.
 
-    def __init__(self, vocab):
+    Given the true `sequences` (prompt + reference continuation) the first
+    draft is wrong BY CONSTRUCTION — one past the token the model will
+    pick — and acceptance stops at the first mismatch, so not a single
+    draft can be accepted. Without them the drafts are merely arbitrary,
+    and one may match the model by chance."""
+
+    def __init__(self, vocab, sequences=()):
         self.vocab = vocab
+        self.sequences = [[int(t) for t in s] for s in sequences]
 
     def propose(self, tokens, k):
-        return [(int(tokens[-1]) + 7 + i) % self.vocab for i in range(k)]
+        hist = [int(t) for t in tokens]
+        draft = [(hist[-1] + 7 + i) % self.vocab for i in range(k)]
+        for seq in self.sequences:
+            if len(seq) > len(hist) and seq[:len(hist)] == hist:
+                draft[0] = (seq[len(hist)] + 1) % self.vocab
+        return draft
 
 
 @pytest.fixture(scope="module")
@@ -134,7 +146,9 @@ def test_spec_greedy_identical_multislot(tiny_f32, baselines):
         "replay": ReplayDrafter(
             [list(p) + r for p, r in zip(prompts, refs)]
         ),
-        "wrong": _WrongDrafter(cfg.vocab_size),
+        "wrong": _WrongDrafter(
+            cfg.vocab_size, [list(p) + r for p, r in zip(prompts, refs)]
+        ),
         "ngram": NGramDrafter(),
     }
     for name, drafter in drafters.items():
@@ -164,7 +178,11 @@ def test_spec_greedy_identical_multislot(tiny_f32, baselines):
             assert st["spec_accept_rate"] > 0.9, st
             assert st["spec_tokens_per_step"] > 3.0, st
         if name == "wrong":
+            # every verify step proposed, rejected at the first draft and
+            # rolled back: nothing accepted, one token emitted per slot-step
+            assert st["spec_proposed_tokens"] > 0, st
             assert st["spec_accepted_tokens"] == 0, st
+            assert st["spec_emitted_tokens"] == st["spec_slot_steps"], st
 
 
 def test_spec_greedy_identical_int8(tiny_f32):
