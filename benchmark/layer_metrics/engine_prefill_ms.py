@@ -1,0 +1,9 @@
+"""Mean engine prefill dispatch over the run:
+serve_engine_step_s{phase=prefill}, sum delta / count delta."""
+from benchmark.common import hist_mean_ms
+
+
+def read(facts):
+    if facts["kind"] != "serve":
+        return None
+    return hist_mean_ms(facts, "prefill_step")
