@@ -62,6 +62,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..util.profiling import span
 from .decoding import default_prefill_buckets
 from .transformer import (
     NEG_INF,
@@ -930,16 +931,25 @@ class PagedDecodeEngine:
         is slot-owned — prefix-hit sharing is whole-block — so the quant
         path's requantize-owned rule keeps the CoW invariant; tests pin
         the straddle edge)."""
-        import jax
-
-        t0 = time.monotonic() if self._tel is not None else 0.0
-        bt = self.block_tokens
         prompt = self._chunk_state[slot]
         ctx = int(self._positions[slot])
-        length = int(prompt.size)
-        rem = length - ctx
+        rem = int(prompt.size) - ctx
         take = rem if whole else min(self.prefill_chunk_tokens, rem)
         last = take == rem
+        # the span ends after the first-token fetch when the chunk is the
+        # last: the histogram and the recorder see the prefill itself, not
+        # its enqueue
+        with span("engine.prefill", self._tel, phase="prefill", slot=slot,
+                  event="prefill_chunk", tokens=take, ctx=ctx,
+                  last=bool(last)):
+            return self._prefill_chunk(slot, prompt, ctx, take, last)
+
+    def _prefill_chunk(self, slot: int, prompt, ctx: int, take: int,
+                       last: bool) -> Optional[int]:
+        import jax
+
+        bt = self.block_tokens
+        length = int(prompt.size)
         bucket = self._bucket(take)
         padded = np.zeros(bucket, np.int32)
         padded[:take] = prompt[ctx:ctx + take]
@@ -959,14 +969,6 @@ class PagedDecodeEngine:
         self._positions[slot] = ctx + take
         self.prefill_tokens += take
         self.prefill_chunks += 1
-        if self._tel is not None:
-            dur = time.monotonic() - t0
-            self._tel.observe_phase("prefill", dur)
-            if self._rec is not None:
-                self._rec.record(
-                    "prefill_chunk", slot=slot, dur=dur,
-                    args={"tokens": take, "ctx": ctx, "last": bool(last)},
-                )
         if not last:
             return None
         tok = int(next_tok[0])
@@ -1152,56 +1154,66 @@ class PagedDecodeEngine:
         return surviving
 
     def _plain_step(self, surviving: List[int]) -> Dict[int, Tuple[Any, bool]]:
+        with span("engine.decode", self._tel, phase="decode",
+                  event="decode") as step_span:
+            return self._plain_step_spanned(surviving, step_span)
+
+    def _plain_step_spanned(self, surviving, step_span):
         bt = self.block_tokens
-        t0 = time.monotonic() if self._tel is not None else 0.0
 
         # resolve this step's block needs (new block at a block boundary,
         # copy-on-write when the write block is shared) under pool pressure
-        surviving = self._reserve_write_spans(
-            surviving,
-            lambda s: (int(self._positions[s]) // bt,),
-        )
+        with span("engine.reserve"):
+            surviving = self._reserve_write_spans(
+                surviving,
+                lambda s: (int(self._positions[s]) // bt,),
+            )
         if not surviving:
+            step_span.drop()
             return {}
 
-        B = self.max_batch_size
-        write_phys = np.zeros(B, np.int32)  # inactive rows -> null block
-        write_off = np.zeros(B, np.int32)
-        for s in surviving:
-            pos = int(self._positions[s])
-            write_phys[s] = self._tables[s, pos // bt]
-            write_off[s] = pos % bt
-        next_toks, logits, self.pool = self._decode_step(
-            self.params, self.pool, self._tables, self._last_tokens,
-            self._positions, write_phys, write_off, self._next_key(),
-        )
-        toks = np.asarray(next_toks)
-        lps = (
-            np.asarray(self._lp_fn(logits, next_toks))
-            if self.logprobs else None
-        )
-        out: Dict[int, Tuple[Any, bool]] = {}
-        for s in surviving:
-            tok = int(toks[s])
-            self._positions[s] += 1
-            self._last_tokens[s] = tok
-            self._new_counts[s] += 1
-            hist = self._history[s]
-            if hist is not None:
-                hist.append(tok)
-            item = (tok, float(lps[s])) if lps is not None else tok
-            out[s] = (item, self._done(s, tok))
-            if (self._rec is not None and self.eos_id is not None
-                    and tok == self.eos_id):
-                self._rec.record("eos", slot=s)
-        self.decode_steps += 1
-        self.tokens_generated += len(surviving)
-        if self._tel is not None:
-            dur = time.monotonic() - t0
-            self._tel.observe_phase("decode", dur)
-            if self._rec is not None:
-                self._rec.record("decode", dur=dur,
-                                 args={"slots": tuple(surviving)})
+        with span("engine.inputs"):
+            B = self.max_batch_size
+            write_phys = np.zeros(B, np.int32)  # inactive rows -> null block
+            write_off = np.zeros(B, np.int32)
+            kv_tokens = 0
+            for s in surviving:
+                pos = int(self._positions[s])
+                write_phys[s] = self._tables[s, pos // bt]
+                write_off[s] = pos % bt
+                kv_tokens += pos + 1  # the step attends to 0..pos
+            key = self._next_key()
+        # slots: the recorder keeps the ids (one timeline lane each), the
+        # trace their number; kv_tokens is what the paged kernel must read
+        step_span.set(slots=tuple(surviving), kv_tokens=kv_tokens)
+        with span("engine.dispatch"):
+            next_toks, logits, self.pool = self._decode_step(
+                self.params, self.pool, self._tables, self._last_tokens,
+                self._positions, write_phys, write_off, key,
+            )
+        with span("engine.fetch"):
+            toks = np.asarray(next_toks)
+            lps = (
+                np.asarray(self._lp_fn(logits, next_toks))
+                if self.logprobs else None
+            )
+        with span("engine.bookkeep"):
+            out: Dict[int, Tuple[Any, bool]] = {}
+            for s in surviving:
+                tok = int(toks[s])
+                self._positions[s] += 1
+                self._last_tokens[s] = tok
+                self._new_counts[s] += 1
+                hist = self._history[s]
+                if hist is not None:
+                    hist.append(tok)
+                item = (tok, float(lps[s])) if lps is not None else tok
+                out[s] = (item, self._done(s, tok))
+                if (self._rec is not None and self.eos_id is not None
+                        and tok == self.eos_id):
+                    self._rec.record("eos", slot=s)
+            self.decode_steps += 1
+            self.tokens_generated += len(surviving)
         return out
 
     # ----------------------------------------------------- speculative path
@@ -1274,8 +1286,12 @@ class PagedDecodeEngine:
         blocks), and the rejected tail is rolled back afterwards by
         truncating the table — unused blocks go straight back to the
         allocator."""
+        with span("engine.verify", self._tel, phase="verify",
+                  event="verify") as step_span:
+            return self._spec_step_spanned(surviving, drafts, step_span)
+
+    def _spec_step_spanned(self, surviving, drafts, step_span):
         bt = self.block_tokens
-        t0 = time.monotonic() if self._tel is not None else 0.0
 
         def _span_blocks(s: int):
             p = int(self._positions[s])
@@ -1293,14 +1309,17 @@ class PagedDecodeEngine:
             self.prefix_cache.evictable() if self.prefix_cache else 0
         )
         if need > self.allocator.num_free + evictable:
+            step_span.drop()
             return self._plain_step(surviving)
         self._reclaim(need)
         if need > self.allocator.num_free:
             # reclaim under-delivered (evictable() counts blocks only a
             # cascade of leaf evictions could reach): still no preemption
+            step_span.drop()
             return self._plain_step(surviving)
         surviving = self._reserve_write_spans(surviving, _span_blocks)
         if not surviving:
+            step_span.drop()
             return {}
 
         kmax = max(len(drafts[s]) for s in surviving)
@@ -1369,16 +1388,9 @@ class PagedDecodeEngine:
         self.decode_steps += 1
         self.spec_steps += 1
         self.spec_shapes.add(K1)
-        if self._tel is not None:
-            dur = time.monotonic() - t0
-            self._tel.observe_phase("verify", dur)
-            if self._rec is not None:
-                self._rec.record(
-                    "verify", dur=dur,
-                    args={"slots": tuple(surviving),
-                          "proposed": int(draft_len[list(surviving)].sum()),
-                          "accepted": int(accepted[list(surviving)].sum())},
-                )
+        step_span.set(slots=tuple(surviving),
+                      proposed=int(draft_len[list(surviving)].sum()),
+                      accepted=int(accepted[list(surviving)].sum()))
         return results
 
     def take_preempted(self) -> List[Tuple[int, Dict[str, Any]]]:

@@ -1162,19 +1162,31 @@ def make_paged_decoder(
         logits = _constrain(logits, "batch", "vocab")
         return _sample(logits, key), logits, _pool_dict(new_leaves)
 
+    # jax.jit names a program after its function, and that name is what the
+    # profiler's "XLA Modules" line shows: jit_paged_prefill,
+    # jit_paged_decode, jit_paged_verify, jit_copy_blocks. The benchmark's
+    # reduction finds the programs by these names (PERF.md, spans table).
     _prefill_jits: Dict[int, Any] = {}
 
-    def paged_prefill(params, pool, table, tokens, length, ctx_len, key,
-                      ctx_blocks: int):
+    def _prefill_program(G: int):
+        def paged_prefill(params, pool, table, tokens, length, ctx_len, key):
+            return _prefill_body(
+                G, params, pool, table, tokens, length, ctx_len, key)
+
+        return jax.jit(paged_prefill, donate_argnums=(1,))
+
+    def prefill_dispatch(params, pool, table, tokens, length, ctx_len, key,
+                         ctx_blocks: int):
         Sb = tokens.shape[1]
         G = min(int(ctx_blocks) + -(-Sb // bt), table.shape[0])
         fn = _prefill_jits.get(G)
         if fn is None:
-            fn = jax.jit(partial(_prefill_body, G), donate_argnums=(1,))
-            _prefill_jits[G] = fn
+            fn = _prefill_jits[G] = _prefill_program(G)
         return fn(params, pool, table, tokens, length, ctx_len, key)
 
-    def _decode_body(params, pool, tables, tokens, positions, write_phys,
+    prefill_dispatch.programs = _prefill_jits  # window width G -> program
+
+    def paged_decode(params, pool, tables, tokens, positions, write_phys,
                      write_off, key):
         params = _cast_matmul_params(cfg, params)
         B, Nmax = tables.shape
@@ -1281,7 +1293,7 @@ def make_paged_decoder(
         )
         return {"k": kc, "v": vc, "k_scale": ksc, "v_scale": vsc}
 
-    def _verify_body(params, pool, tables, tokens, positions, draft_len,
+    def paged_verify(params, pool, tables, tokens, positions, draft_len,
                      write_phys, write_off, key):
         params = _cast_matmul_params(cfg, params)
         B, K1 = tokens.shape
@@ -1371,17 +1383,19 @@ def make_paged_decoder(
         pool = _verify_commit(pool, ks, vs, wp, write_off)
         return out, accepted, pool
 
-    def _copy_body(pool, src, dst):
+    def copy_blocks(pool, src, dst):
         # every pool leaf (K/V blocks AND their scales) has the physical
         # block dim at axis 1
         return {
             name: a.at[:, dst].set(a[:, src]) for name, a in pool.items()
         }
 
-    paged_decode_step = jax.jit(_decode_body, donate_argnums=(1,))
-    paged_verify_step = jax.jit(_verify_body, donate_argnums=(1,))
-    copy_blocks = jax.jit(_copy_body, donate_argnums=(0,))
-    return paged_prefill, paged_decode_step, paged_verify_step, copy_blocks
+    return (
+        prefill_dispatch,
+        jax.jit(paged_decode, donate_argnums=(1,)),
+        jax.jit(paged_verify, donate_argnums=(1,)),
+        jax.jit(copy_blocks, donate_argnums=(0,)),
+    )
 
 
 def make_decoder(

@@ -21,6 +21,13 @@ are recomputed from the saved log-sum-exp rows, so backward memory is
 O(L * BLOCK) instead of O(L^2) and all four matmuls per block pair run on
 the MXU in f32 accumulation. Causally-dead block pairs are skipped in both
 kernels.
+
+Each pallas_call carries a `name` — flash_attention_fwd, flash_attention_bwd
+(the fused single-block case), flash_attention_bwd_dq, flash_attention_bwd_dkv
+— which pallas_call also enters as a named scope, so the HLO instruction and
+with it the profiler's event is `%flash_attention_fwd.<n>` whatever JAX
+wrapper (checkpoint, jvp, shard_map) the kernel was called under. The
+benchmark's reduction finds the kernels by these names.
 """
 
 from __future__ import annotations
@@ -130,6 +137,7 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k, interpret):
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(qt, kt, vt)
     return out, lse[..., 0]
 
@@ -290,6 +298,7 @@ def _flash_backward(scale, causal, block_q, block_k, interpret, res, do):
                 jax.ShapeDtypeStruct((b, h, lk, d), v.dtype),
             ],
             interpret=interpret,
+            name="flash_attention_bwd",
         )(qt, kt, vt, dot, lse4, delta)
         return dq, dk, dv
     dq = pl.pallas_call(
@@ -302,6 +311,7 @@ def _flash_backward(scale, causal, block_q, block_k, interpret, res, do):
         out_shape=[jax.ShapeDtypeStruct((b, h, lq, d), q.dtype)],
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(qt, kt, vt, dot, lse4, delta)[0]
 
     # kv kernel: grid (b, kv_head, kv_block, n_rep * q_blocks) — the whole
@@ -336,6 +346,7 @@ def _flash_backward(scale, causal, block_q, block_k, interpret, res, do):
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_bwd_dkv",
     )(qt, kt, vt, dot, lse4, delta)
 
     return dq, dk, dv

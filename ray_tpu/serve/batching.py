@@ -34,6 +34,8 @@ from collections import deque
 from concurrent.futures import Future
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from ray_tpu.util.profiling import span
+
 
 class _BatchQueue:
     _serve_drainable = True
@@ -613,6 +615,17 @@ class ContinuousBatcher:
         # queue wait ends where ADMISSION STARTS: admit() runs the prefill
         # (possibly a whole long prompt), which must not read as queue time
         t_admit = time.monotonic()
+        # rid<->slot correlation for the trace and the timeline: the
+        # engine's own spans and "admit" event know the slot, not the
+        # request id. The recorder event is named once admission succeeded
+        with span("batcher.admit", self._tel, slot=slot,
+                  rid=stream.request_id) as admit_span:
+            return self._admit_into(slot, stream, request, t_admit,
+                                    admit_span)
+
+    def _admit_into(self, slot: int, stream: GenerationStream,
+                    request: Dict[str, Any], t_admit: float,
+                    admit_span) -> bool:
         try:
             tok, done = self.engine.admit(slot, request)
         except Exception as e:  # noqa: BLE001 — bad request must not kill the loop
@@ -634,12 +647,7 @@ class ContinuousBatcher:
             return True
         if self._tel is not None:
             self._tel.queue_wait.observe(t_admit - stream.t_enqueue)
-            if self._rec is not None:
-                # rid<->slot correlation for the timeline: the engine's own
-                # "admit" event knows the slot but not the request id
-                self._rec.record(
-                    "readmit" if stream.preempted else "request",
-                    slot=slot, args={"rid": stream.request_id})
+            admit_span.event = "readmit" if stream.preempted else "request"
         # a chunked-prefill admission (PagedDecodeEngine with
         # prefill_chunk_tokens) returns no token yet — the prompt streams
         # in chunk-per-step and the first sampled token arrives via step()
@@ -752,96 +760,113 @@ class ContinuousBatcher:
 
     def _loop(self) -> None:
         while not self._shutdown:
-            self._run_loop_calls()
-            if not self._active:
-                if self._draining:
-                    self._bounce_pending()
-                    # preempted generations parked in holdback are
-                    # in-flight work: keep readmitting them until done or
-                    # the drain deadline cuts them
-                    with self._lock:
-                        has_parked = bool(self._holdback)
-                    if has_parked:
-                        self._gather(first_timeout=0.0)
-                    if (self._draining and self._drain_deadline is not None
-                            and time.monotonic() >= self._drain_deadline):
-                        self._cut_parked()
-                    if not self._active:
-                        time.sleep(0.01)
-                        continue
-                # idle: park on the queue; once the first request lands,
-                # hold the batch open for the coalescing window so
-                # near-simultaneous requests share the first step
-                self._gather(first_timeout=0.05)
-                if self._active and self.batch_wait_timeout_s > 0:
-                    deadline = time.monotonic() + self.batch_wait_timeout_s
-                    while (len(self._free) > 0
-                           and time.monotonic() < deadline):
-                        self._gather(
-                            first_timeout=max(0.0, deadline - time.monotonic())
-                        )
-                        if not self._free:
-                            break
-                if not self._active:
-                    continue
-            else:
-                # running batch: admit whatever is queued, no waiting
-                self._gather(first_timeout=0.0)
-
-            with self._lock:
-                slots = sorted(self._active)
-                ids = tuple(self._active[s].request_id for s in slots)
-            if not slots:
-                continue
+            # one pass = one span; a pass that steps the engine carries
+            # `slots`, an idle pass (parked on the queue) does not
+            with span("batcher.iteration") as it_span:
+                self._iteration(it_span)
+        # loop exit (close()): fail parked cross-thread calls, or their
+        # callers would block until their timeout
+        while self._loop_calls:
             try:
-                results = self.engine.step(slots)
-            except Exception as e:  # noqa: BLE001 — engine fault fails the batch
-                if self._tel is not None:
-                    if self._rec is not None:
-                        self._rec.record(
-                            "engine_fault",
-                            args={"error": repr(e)[:200],
-                                  "slots": tuple(slots)})
-                    # a faulting engine is exactly when the post-mortem
-                    # window matters: get it off this process NOW
-                    self._tel.flush_events(force=True)
-                # discard any preemptions staged before the fault: their
-                # streams are errored with everyone else's below, and a
-                # stale parked entry must never hijack the slot's NEXT
-                # stream on a later successful step
-                take = getattr(self.engine, "take_preempted", None)
-                if take is not None:
-                    try:
-                        take()
-                    except Exception:
-                        pass
-                for slot in slots:
-                    stream = self._active.get(slot)
-                    if stream is not None:
-                        stream._finish(error=e)
-                    self._retire(slot)
-                continue
-            # slots the engine preempted mid-step are absent from results:
-            # park their streams (still open) for recompute-on-readmit
-            self._absorb_preempted()
-            self._steps += 1
-            self._occupancy.append((self._steps, len(slots), ids))
-            if self._tel is not None and self._steps % 8 == 1:
-                # cheap occupancy/pool gauges (attribute reads, no
-                # engine.stats() call — that walks the prefix-cache trie),
-                # refreshed every 8th step: gauge freshness at sub-step
-                # granularity buys nothing, the hot loop's budget does
-                self._tel.occupancy.set(len(slots))
-                alloc = getattr(self.engine, "allocator", None)
-                if alloc is not None:
-                    self._tel.kv_util.set(
-                        (alloc.num_usable - alloc.num_free)
-                        / max(1, alloc.num_usable))
-                if getattr(self.engine, "speculative_k", 0):
-                    self._tel.spec_accept.set(
-                        self.engine.spec_accepted
-                        / max(1, self.engine.spec_proposed))
-                self._tel.flush_events()
+                _, box, done = self._loop_calls.popleft()
+            except IndexError:
+                break
+            box["error"] = RuntimeError("batcher loop exited")
+            done.set()
+
+    def _iteration(self, it_span) -> None:
+        self._run_loop_calls()
+        if not self._active:
+            if self._draining:
+                self._bounce_pending()
+                # preempted generations parked in holdback are
+                # in-flight work: keep readmitting them until done or
+                # the drain deadline cuts them
+                with self._lock:
+                    has_parked = bool(self._holdback)
+                if has_parked:
+                    self._gather(first_timeout=0.0)
+                if (self._draining and self._drain_deadline is not None
+                        and time.monotonic() >= self._drain_deadline):
+                    self._cut_parked()
+                if not self._active:
+                    time.sleep(0.01)
+                    return
+            # idle: park on the queue; once the first request lands,
+            # hold the batch open for the coalescing window so
+            # near-simultaneous requests share the first step
+            self._gather(first_timeout=0.05)
+            if self._active and self.batch_wait_timeout_s > 0:
+                deadline = time.monotonic() + self.batch_wait_timeout_s
+                while (len(self._free) > 0
+                       and time.monotonic() < deadline):
+                    self._gather(
+                        first_timeout=max(0.0, deadline - time.monotonic())
+                    )
+                    if not self._free:
+                        break
+            if not self._active:
+                return
+        else:
+            # running batch: admit whatever is queued, no waiting
+            self._gather(first_timeout=0.0)
+
+        with self._lock:
+            slots = sorted(self._active)
+            ids = tuple(self._active[s].request_id for s in slots)
+        if not slots:
+            return
+        it_span.set(slots=len(slots))
+        try:
+            results = self.engine.step(slots)
+        except Exception as e:  # noqa: BLE001 — engine fault fails the batch
+            if self._tel is not None:
+                if self._rec is not None:
+                    self._rec.record(
+                        "engine_fault",
+                        args={"error": repr(e)[:200],
+                              "slots": tuple(slots)})
+                # a faulting engine is exactly when the post-mortem
+                # window matters: get it off this process NOW
+                self._tel.flush_events(force=True)
+            # discard any preemptions staged before the fault: their
+            # streams are errored with everyone else's below, and a
+            # stale parked entry must never hijack the slot's NEXT
+            # stream on a later successful step
+            take = getattr(self.engine, "take_preempted", None)
+            if take is not None:
+                try:
+                    take()
+                except Exception:
+                    pass
+            for slot in slots:
+                stream = self._active.get(slot)
+                if stream is not None:
+                    stream._finish(error=e)
+                self._retire(slot)
+            return
+        # slots the engine preempted mid-step are absent from results:
+        # park their streams (still open) for recompute-on-readmit
+        self._absorb_preempted()
+        self._steps += 1
+        self._occupancy.append((self._steps, len(slots), ids))
+        if self._tel is not None and self._steps % 8 == 1:
+            # cheap occupancy/pool gauges (attribute reads, no
+            # engine.stats() call — that walks the prefix-cache trie),
+            # refreshed every 8th step: gauge freshness at sub-step
+            # granularity buys nothing, the hot loop's budget does
+            self._tel.occupancy.set(len(slots))
+            alloc = getattr(self.engine, "allocator", None)
+            if alloc is not None:
+                self._tel.kv_util.set(
+                    (alloc.num_usable - alloc.num_free)
+                    / max(1, alloc.num_usable))
+            if getattr(self.engine, "speculative_k", 0):
+                self._tel.spec_accept.set(
+                    self.engine.spec_accepted
+                    / max(1, self.engine.spec_proposed))
+            self._tel.flush_events()
+        with span("batcher.emit"):
             for slot, (tok, done) in results.items():
                 stream = self._active.get(slot)
                 if stream is None:
@@ -860,21 +885,12 @@ class ContinuousBatcher:
                 if done:
                     stream._finish()
                     self._retire(slot)
-            # drain deadline: cut whatever is still running or parked
-            if (self._draining and self._drain_deadline is not None
-                    and time.monotonic() >= self._drain_deadline):
-                with self._lock:
-                    leftover = dict(self._active)
-                for slot, stream in leftover.items():
-                    stream._finish(cut=True)
-                    self._retire(slot)
-                self._cut_parked()
-        # loop exit (close()): fail parked cross-thread calls, or their
-        # callers would block until their timeout
-        while self._loop_calls:
-            try:
-                _, box, done = self._loop_calls.popleft()
-            except IndexError:
-                break
-            box["error"] = RuntimeError("batcher loop exited")
-            done.set()
+        # drain deadline: cut whatever is still running or parked
+        if (self._draining and self._drain_deadline is not None
+                and time.monotonic() >= self._drain_deadline):
+            with self._lock:
+                leftover = dict(self._active)
+            for slot, stream in leftover.items():
+                stream._finish(cut=True)
+                self._retire(slot)
+            self._cut_parked()

@@ -13,6 +13,11 @@ renderable by any flamegraph tool and cheap to aggregate in the dashboard.
 Memory profiling uses stdlib tracemalloc: `memory_profile(duration)` diffs
 two snapshots taken `duration` apart and reports the top allocation sites
 (memray's core use-case: "where is memory going right now").
+
+`span()` is the serving hot path's one timing primitive: a context manager
+that writes a layer boundary ONCE to three sinks — the jax.profiler trace
+(so it lies on the device's clock), the serve_engine_step_s histogram and
+the flight recorder (serve/telemetry.py).
 """
 
 from __future__ import annotations
@@ -21,7 +26,83 @@ import sys
 import threading
 import time
 from collections import Counter
-from typing import Dict, List, Optional
+from typing import Any, Dict, List, Optional
+
+_TRACE_ANNOTATION = None  # jax.profiler.TraceAnnotation, resolved on first use
+
+
+class Span:
+    """One timed layer boundary (see `span`). `event` and `slot` may be
+    assigned inside the block — an admission learns only at its end
+    whether it is a `request` or a `readmit`, or was parked (no event)."""
+
+    __slots__ = ("_ann", "_tel", "_t0", "phase", "event", "slot", "args")
+
+    def __init__(self, name, tel, phase, slot, event, counts):
+        global _TRACE_ANNOTATION
+        if _TRACE_ANNOTATION is None:
+            # importing jax.profiler opens no backend
+            from jax.profiler import TraceAnnotation
+
+            _TRACE_ANNOTATION = TraceAnnotation
+        attrs = _scalars(counts)
+        if slot >= 0:
+            attrs["slot"] = slot
+        self._ann = _TRACE_ANNOTATION(name, **attrs)
+        self._tel = tel
+        self.phase, self.event, self.slot = phase, event, slot
+        self.args = counts
+
+    def set(self, **counts) -> None:
+        """Counts known only inside the block (how many slots survived the
+        reservation, how many tokens they attend to)."""
+        self._ann.set_metadata(**_scalars(counts))
+        if self._tel is not None:
+            self.args.update(counts)
+
+    def drop(self) -> None:
+        """The block was not the phase it set out to be (every slot was
+        preempted, a verify fell back to the plain step): the trace keeps
+        the interval, the histogram and the recorder get nothing."""
+        self.phase = self.event = None
+
+    def __enter__(self):
+        self._ann.__enter__()
+        if self._tel is not None:
+            self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc):
+        tel = self._tel
+        if tel is not None:
+            dur = time.monotonic() - self._t0  # the one clock read
+            if self.phase is not None:
+                tel.observe_phase(self.phase, dur)
+            if self.event is not None and tel.recorder is not None:
+                tel.recorder.record(self.event, slot=self.slot, dur=dur,
+                                    args=self.args or None)
+        return self._ann.__exit__(*exc)
+
+
+def _scalars(counts: Dict[str, Any]) -> Dict[str, Any]:
+    """Trace attributes are scalars: a sequence (the flight recorder's
+    tuple of slot ids) is written to the trace as its length."""
+    return {k: len(v) if isinstance(v, (tuple, list)) else v
+            for k, v in counts.items()}
+
+
+def span(name: str, tel=None, phase: Optional[str] = None, slot: int = -1,
+         event: Optional[str] = None, **counts) -> Span:
+    """`with span("engine.decode", tel, phase="decode", event="decode")`:
+    a `jax.profiler.TraceAnnotation(name, **counts)` around the block —
+    under two microseconds when no profiler session is active — and,
+    when `tel` (a ServeTelemetry) is given, ONE duration taken on exit and
+    fed to `tel.observe_phase(phase, dur)` and to the flight recorder as
+    `event` with the same counts. The dotted `name` is the trace's; `event`
+    is the recorder's documented name (`prefill_chunk`, `decode`, `verify`,
+    `request` / `readmit`). With `tel=None` no clock is read and no
+    histogram or recorder work is done."""
+    return Span(name, tel, phase, slot, event, counts)
 
 
 def _frame_label(frame) -> str:

@@ -1,0 +1,327 @@
+"""The serving hot path's one span primitive (ray_tpu/util/profiling.py:
+span), the spans the batcher and the paged engine open with it, and the
+names the device programs and Pallas kernels carry.
+
+Everything here runs on the CPU: `jax.profiler.start_trace` works there and
+host spans land on the `/host:CPU` plane, one line per thread, with their
+attributes as event stats. Nothing here is a device time."""
+
+import dataclasses
+import glob
+import os
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models import CONFIGS
+from ray_tpu.models.kv_paging import PagedDecodeEngine
+from ray_tpu.serve import telemetry
+from ray_tpu.util import profiling
+from ray_tpu.util.profiling import span
+
+DECODE_LEAVES = ("engine.reserve", "engine.inputs", "engine.dispatch",
+                 "engine.fetch", "engine.bookkeep")
+
+
+class _Trace:
+    """`with _Trace(dir) as tr:` traces the block (Python tracer off: the
+    spans are TraceMe events, not Python frames); afterwards
+    `tr.spans(name)` -> [(start_ns, end_ns, stats)] of the host events of
+    that name, in time order."""
+
+    def __init__(self, log_dir):
+        self.dir = str(log_dir)
+
+    def __enter__(self):
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(self.dir, profiler_options=opts)
+        return self
+
+    def __exit__(self, *exc):
+        jax.profiler.stop_trace()
+        path, = glob.glob(os.path.join(
+            self.dir, "plugins", "profile", "*", "*.xplane.pb"))
+        self.events = []
+        for plane in jax.profiler.ProfileData.from_file(path).planes:
+            if plane.name.startswith("/host:"):
+                for line in plane.lines:
+                    self.events += [
+                        (ev.name, float(ev.start_ns),
+                         float(ev.start_ns + ev.duration_ns), dict(ev.stats))
+                        for ev in line.events
+                        if ev.name.startswith(("engine.", "batcher.", "t."))]
+
+    def spans(self, name):
+        return sorted((s, e, st) for n, s, e, st in self.events if n == name)
+
+
+def _inside(inner, outers):
+    """the one of `outers` whose interval contains `inner`'s."""
+    hits = [o for o in outers if o[0] <= inner[0] and inner[1] <= o[1]]
+    assert len(hits) == 1, (inner, hits)
+    return hits[0]
+
+
+def _tiny_engine(tel, **kw):
+    cfg = dataclasses.replace(CONFIGS["tiny"], max_seq_len=128)
+    return cfg, PagedDecodeEngine(cfg, max_batch_size=2, seed=0,
+                                  block_tokens=8, telemetry=tel, **kw)
+
+
+def _prefill_hist(tel):
+    snap = tel.engine_step._snapshot()["values"]
+    ents = [e for k, e in snap.items() if ("phase", "prefill") in set(k)]
+    return sum(e["sum"] for e in ents), sum(e["count"] for e in ents)
+
+
+# ------------------------------------------------------------- (a) helper
+
+
+@pytest.mark.parametrize("with_tel", [True, False], ids=["tel", "no-tel"])
+def test_span_feeds_three_sinks_one_duration(tmp_path, monkeypatch, with_tel):
+    tel = telemetry.ServeTelemetry(recorder_capacity=16) if with_tel else None
+    seen = []
+    if with_tel:
+        monkeypatch.setattr(
+            tel, "observe_phase", lambda p, d: seen.append((p, d)))
+    else:
+        # no telemetry: the span may not even read the clock
+        class _NoClock:
+            @staticmethod
+            def monotonic():
+                raise AssertionError("span read the clock without telemetry")
+
+        monkeypatch.setattr(profiling, "time", _NoClock)
+    with _Trace(tmp_path) as tr:
+        with span("t.span", tel, phase="decode", event="decode", slot=1,
+                  tokens=5) as sp:
+            time.sleep(0.02)
+            sp.set(slots=(0, 3), kv_tokens=41)
+        with span("t.dropped", tel, phase="verify", event="verify") as sp:
+            sp.drop()
+    (s, e, stats), = tr.spans("t.span")
+    # a sequence (the recorder's slot ids) is written to the trace as its length
+    assert stats == {"slot": 1, "tokens": 5, "slots": 2, "kv_tokens": 41}
+    assert len(tr.spans("t.dropped")) == 1  # the trace keeps the interval
+    if not with_tel:
+        return
+    (phase, dur), = seen  # the dropped span fed nothing
+    ev, = tel.recorder.snapshot()
+    assert phase == "decode" and ev["name"] == "decode" and ev["slot"] == 1
+    assert ev["dur"] == dur  # ONE duration, not two clock reads
+    assert ev["args"] == {"tokens": 5, "slots": (0, 3), "kv_tokens": 41}
+    assert 0.02 <= dur < 0.5 and abs((e - s) / 1e9 - dur) < 5e-3
+
+
+def test_span_without_recorder_event_or_phase():
+    tel = telemetry.ServeTelemetry(recorder_capacity=16)
+    before = _prefill_hist(tel)
+    with span("t.leaf", tel):  # no phase, no event: trace only
+        pass
+    with span("t.admit", tel, slot=0, rid=9) as sp:
+        sp.event = "request"  # named once the admission succeeded
+    ev, = tel.recorder.snapshot()
+    assert (ev["name"], ev["slot"], ev["args"]) == ("request", 0, {"rid": 9})
+    assert _prefill_hist(tel) == before
+
+
+# ------------------------------------------- (b) spans of batcher + engine
+
+
+def test_batcher_and_engine_spans_nest(tmp_path):
+    from ray_tpu.serve.batching import ContinuousBatcher
+
+    tel = telemetry.ServeTelemetry(recorder_capacity=512)
+    cfg, eng = _tiny_engine(tel, prefix_cache=False)
+    rng = np.random.default_rng(0)
+    want = {"a": (11, 6), "b": (19, 4)}  # prompt length, max_new_tokens
+    # compile outside the trace, then run the traced requests
+    eng.admit(0, {"tokens": rng.integers(1, cfg.vocab_size, size=11),
+                  "max_new_tokens": 3})
+    eng.step([0])
+    eng.release(0)
+    with _Trace(tmp_path) as tr:
+        # the loop starts inside the trace: a pass already open when the
+        # session starts is cut by the window's edge and not recorded
+        b = ContinuousBatcher(eng, max_batch_size=2, batch_wait_timeout_s=0.0,
+                              telemetry=tel)
+        try:
+            streams = {
+                k: b.submit(tokens=rng.integers(1, cfg.vocab_size, size=p),
+                            max_new_tokens=n)
+                for k, (p, n) in want.items()}
+            outs = {k: list(s) for k, s in streams.items()}
+        finally:
+            b.close()
+    assert {k: len(o) for k, o in outs.items()} == {"a": 6, "b": 4}
+
+    its = tr.spans("batcher.iteration")
+    admits = tr.spans("batcher.admit")
+    prefills = tr.spans("engine.prefill")
+    decodes = tr.spans("engine.decode")
+    by_rid = {streams[k].request_id: want[k] for k in want}
+    assert sorted(st["rid"] for _, _, st in admits) == sorted(by_rid)
+    for pf in prefills:  # iteration > admit > prefill
+        ad = _inside(pf, admits)
+        _inside(ad, its)
+        assert pf[2]["tokens"] == by_rid[ad[2]["rid"]][0]
+        assert pf[2]["slot"] == ad[2]["slot"] and pf[2]["last"] == 1
+    assert len(prefills) == len(admits) == 2
+
+    # kv_tokens and slots, recomputed from first principles: a request
+    # admitted with prompt P and max_new N holds position P after its
+    # prefill (which emits token 1) and takes part in the next N - 1
+    # decode steps; step k of those attends to P + k + 1 tokens
+    live = {}  # rid -> [position, decode steps left]
+    pending = sorted(admits, key=lambda a: a[1])
+    assert len(decodes) >= 5
+    for dec in decodes:
+        it = _inside(dec, its)  # iteration > decode
+        while pending and pending[0][1] <= dec[0]:
+            rid = pending.pop(0)[2]["rid"]
+            live[rid] = [by_rid[rid][0], by_rid[rid][1] - 1]
+        assert dec[2]["slots"] == it[2]["slots"] == len(live)
+        assert dec[2]["kv_tokens"] == sum(p + 1 for p, _ in live.values())
+        for rid in list(live):
+            live[rid][0] += 1
+            live[rid][1] -= 1
+            if not live[rid][1]:
+                del live[rid]
+        kids = [k for leaf in DECODE_LEAVES for k in tr.spans(leaf)
+                if dec[0] <= k[0] and k[1] <= dec[1]]
+        assert len(kids) == len(DECODE_LEAVES)  # one of each, in order
+        assert [k[0] for k in kids] == sorted(k[0] for k in kids)
+    assert not live and not pending
+    for em in tr.spans("batcher.emit"):
+        _inside(em, its)
+    assert len(tr.spans("batcher.emit")) == len(decodes)
+    # the recorder keeps its documented names, fed from the same spans
+    names = [e["name"] for e in tel.recorder.snapshot()]
+    assert names.count("request") == 2 and names.count("prefill_chunk") == 3
+    assert names.count("decode") == len(decodes) + 1  # + the warm-up step
+    dec_ev = [e for e in tel.recorder.snapshot() if e["name"] == "decode"]
+    assert all(isinstance(e["args"]["slots"], tuple) for e in dec_ev)
+
+
+# -------------------------------- (c) the prefill span covers the fetch
+
+
+def test_prefill_span_covers_first_token_fetch(tmp_path):
+    tel = telemetry.ServeTelemetry(recorder_capacity=64)
+    cfg, eng = _tiny_engine(tel, prefix_cache=False)
+    wait_s = 0.05
+
+    class _Late:
+        """a device result that takes `wait_s` to materialise."""
+
+        def __getitem__(self, i):
+            time.sleep(wait_s)
+            return 7
+
+    def stub_prefill(params, pool, *a):
+        return _Late(), None, pool  # returns at once, like an enqueue
+
+    eng._prefill = stub_prefill
+    sum0, n0 = _prefill_hist(tel)
+    with _Trace(tmp_path) as tr:
+        tok, done = eng.admit(0, {"tokens": np.arange(1, 10),
+                                  "max_new_tokens": 4})
+    assert tok == 7 and not done
+    sum1, n1 = _prefill_hist(tel)
+    assert n1 - n0 == 1 and sum1 - sum0 >= wait_s
+    ev, = [e for e in tel.recorder.snapshot() if e["name"] == "prefill_chunk"]
+    assert ev["dur"] == pytest.approx(sum1 - sum0)  # one duration, two sinks
+    assert ev["args"] == {"tokens": 9, "ctx": 0, "last": True}
+    (s, e, stats), = tr.spans("engine.prefill")
+    assert (e - s) / 1e9 >= wait_s
+    assert stats == {"slot": 0, "tokens": 9, "ctx": 0, "last": 1}
+
+
+# --------------------------------------------- (d) names on the device
+
+
+def _program_args(eng, which):
+    B, K1 = eng.max_batch_size, 3
+    zB = np.zeros(B, np.int32)
+    key = jax.random.PRNGKey(0)
+    if which == "paged_decode":
+        return eng._decode_step, (eng.params, eng.pool, eng._tables, zB, zB,
+                                  zB, zB, key)
+    if which == "paged_verify":
+        zK = np.zeros((B, K1), np.int32)
+        return eng._verify_step, (eng.params, eng.pool, eng._tables, zK, zB,
+                                  zB, zK, zK, key)
+    if which == "copy_blocks":
+        one = np.ones(1, np.int32)
+        return eng._copy_blocks, (eng.pool, one, one)
+    fn, = eng._prefill.programs.values()
+    return fn, (eng.params, eng.pool, eng._tables[0],
+                np.zeros((1, 16), np.int32), np.int32(9), np.int32(0), key)
+
+
+@pytest.mark.parametrize(
+    "which", ["paged_prefill", "paged_decode", "paged_verify", "copy_blocks"])
+def test_serving_programs_lower_under_stable_names(which):
+    _, eng = _tiny_engine(False, prefix_cache=False,
+                          prefill_buckets=(16,))
+    if which == "paged_prefill":  # the prefill program is built on demand
+        eng.admit(0, {"tokens": np.arange(1, 10), "max_new_tokens": 2})
+    fn, args = _program_args(eng, which)
+    assert f"module @jit_{which} " in fn.lower(*args).as_text()[:200]
+
+
+def _flash_fwd(q, k, v):
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    return flash_attention(q, k, v, block_q=16, block_k=16, interpret=True)
+
+
+def _flash_bwd(q, k, v):
+    return jax.grad(lambda *a: _flash_fwd(*a).sum(), argnums=(0, 1, 2))(
+        q, k, v)
+
+
+def _flash_bwd_one_block(q, k, v):
+    return _flash_bwd(q[:, :16], k[:, :16], v[:, :16])
+
+
+def _paged(q, k, v):
+    from ray_tpu.ops.paged_attention import paged_attention
+
+    pool = jnp.zeros((4, 8, 2, 16), jnp.float32)
+    return paged_attention(
+        q[:, :1, :, :], pool, pool, jnp.zeros((1, 2), jnp.int32),
+        jnp.zeros((1,), jnp.int32), impl="kernel", interpret=True)
+
+
+@pytest.mark.parametrize("fn,kv_heads,names", [
+    (_flash_fwd, 2, ["flash_attention_fwd"]),
+    (_flash_bwd, 1, ["flash_attention_fwd", "flash_attention_bwd_dq",
+                     "flash_attention_bwd_dkv"]),
+    (_flash_bwd_one_block, 2, ["flash_attention_fwd", "flash_attention_bwd"]),
+    (_paged, 2, ["paged_attention"]),
+], ids=["flash_fwd", "flash_bwd", "flash_bwd_fused", "paged"])
+def test_kernels_carry_their_names(fn, kv_heads, names):
+    q = jnp.ones((1, 32, 2, 16), jnp.float32)
+    kv = jnp.ones((1, 32, kv_heads, 16), jnp.float32)
+    calls = {}  # kernel name -> the scope it was called under
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                name = eqn.params["name"]
+                calls[name] = str(eqn.source_info.name_stack)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(q, kv, kv).jaxpr)
+    assert sorted(calls) == sorted(names)
+    # pallas_call enters its name as a named scope too (under grad:
+    # "jvp(flash_attention_fwd)"), which is what the TPU compiler names the
+    # instruction after (%flash_attention_fwd.<n>)
+    for name, scope in calls.items():
+        assert name in scope.split("/")[-1], (name, scope)
