@@ -7,7 +7,10 @@ model-parallel story is external Alpa, release/alpa_tests/):
     DP/FSDP/TP/EP are sharding-table entries, not code paths.
   - layers are STACKED and scanned (lax.scan over a [L, ...] leading dim):
     one compiled layer body regardless of depth — compile time O(1) in
-    layers, and XLA pipelines the scan on TPU.
+    layers, and XLA pipelines the scan on TPU. What a layer only READS
+    (its weights) is the scan's xs; state a layer WRITES a few rows of —
+    the paged KV pool — is carried whole and addressed by layer index, so
+    it is updated in place instead of sliced out and written back.
   - each scan step is jax.checkpoint'ed (rematerialization: trade MXU FLOPs
     for HBM, the standard TPU memory trade).
   - attention impl is selectable: dense (small L), ring (sequence-parallel
@@ -26,8 +29,12 @@ batching drives.
 Paged variant (serving at scale): `init_paged_kv_cache` + `make_paged_decoder`
 swap the per-slot slab for a pool of fixed-size token blocks addressed
 through per-slot block tables (gathered inside the jitted step — one
-compiled shape regardless of live lengths). Host-side allocation, prefix
-reuse and preemption live in `ray_tpu/models/kv_paging.py`.
+compiled shape regardless of live lengths). The pool's leaves stay stacked
+[L, N, ...] through every program's layer loop: writes scatter into
+`leaf[l, block, offset]` of the donated buffer and reads fetch
+`leaf[l, block]`, so a step moves the tokens it writes and the blocks it
+attends, never the pool. Host-side allocation, prefix reuse and preemption
+live in `ray_tpu/models/kv_paging.py`.
 """
 
 from __future__ import annotations
@@ -763,6 +770,17 @@ def make_paged_decoder(
     paged_verify_step, copy_blocks) over a block pool from
     `init_paged_kv_cache`.
 
+    One loop shape for all three model programs, every implementation and
+    every pool dtype: `lax.scan` over (stacked layer weights, layer index),
+    with the pool's stacked [L, N, ...] leaves as loop-carried state
+    (prefill, decode) or closed over read-only (verify, which commits
+    after the loop). A layer writes `leaf.at[l, block, offset]` and reads
+    `leaf[l, block]` — under "fused" the kernel takes the stacked leaf and
+    `layer=l` and DMAs block `table[b, j]` of layer `l` itself. The pool
+    argument is donated, so the scatter lands in the caller's buffer: no
+    instruction of a compiled program scales with the pool
+    (tests/test_chip_compile.py holds the programs to that).
+
     paged_prefill(params, pool, table[Nmax], tokens[1,Sb], length, ctx_len,
                   key, ctx_blocks) -> (next_token[1], logits[1,V], pool)
       B=1 prefill of a prompt SUFFIX whose first `ctx_len` tokens are
@@ -874,15 +892,28 @@ def make_paged_decoder(
 
     _sample = _make_sampler(temperature, cfg.vocab_pad)
 
-    def _scan_leaves(pool):
-        """Pool leaves in the fixed order the layer scans unpack."""
-        if quant:
-            return (pool["k"], pool["v"], pool["k_scale"], pool["v_scale"])
-        return (pool["k"], pool["v"])
+    def _pool_leaves(pool):
+        """(k, v, k_scale, v_scale) of the STACKED pool, scales None for fp
+        pools. The layer loops carry these whole and address them by layer
+        (`leaf[l, block]`): handed to `lax.scan` as xs/ys instead, every
+        layer's [N, bt, KV, D] slice would be copied out of the stack and
+        copied back, whole-pool traffic for a few tokens' write."""
+        return (pool["k"], pool["v"], pool.get("k_scale"), pool.get("v_scale"))
 
-    def _pool_dict(leaves):
-        names = ("k", "v", "k_scale", "v_scale") if quant else ("k", "v")
-        return dict(zip(names, leaves))
+    def _pool_dict(kc, vc, ksc, vsc):
+        if quant:
+            return {"k": kc, "v": vc, "k_scale": ksc, "v_scale": vsc}
+        return {"k": kc, "v": vc}
+
+    layer_ids = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+
+    def _gather_window(kc, ksc, l, tables):
+        """[B, Nmax] tables -> each slot's window [B, Nmax*bt, KV, D] of
+        layer `l` in compute dtype (the "gather" implementation's read)."""
+        kw = kc[l, tables]
+        if quant:
+            kw = _dequant(kw, ksc[l, tables])
+        return kw.reshape(tables.shape[0], -1, *kc.shape[3:])
 
     def _dequant(blocks, scales):
         """[..., bt, KV, D] int8 x [..., KV] -> compute dtype."""
@@ -933,9 +964,10 @@ def make_paged_decoder(
             axes = (axes,)
         return tuple(a for a in axes if a in mesh.shape)
 
-    def _fused_attend(qx, kc, vc, ksc, vsc, tables, positions, kv_len=None,
-                      partial=False):
-        """qx [B, Q, H, D] against the (possibly sharded) per-layer pool.
+    def _fused_attend(qx, kc, vc, ksc, vsc, l, tables, positions,
+                      kv_len=None, partial=False):
+        """qx [B, Q, H, D] against layer `l` of the (possibly sharded)
+        stacked pool.
 
         One fused formulation for every phase: decode (Q=1), prefill
         (Q=chunk) and speculative verify (Q=k+1) — query i of slot b sits
@@ -960,7 +992,7 @@ def make_paged_decoder(
             kv_len = positions + qx.shape[1]
         if not block_axes and not kv_axes:
             return paged_attention(
-                qx, kc, vc, tables, positions, scale=scale,
+                qx, kc, vc, tables, positions, layer=l, scale=scale,
                 impl=fused_impl, chunk_blocks=chunk_blocks, kv_len=kv_len,
                 partial_out=partial, **scales,
             )
@@ -971,26 +1003,26 @@ def make_paged_decoder(
                 sc = dict(k_scale=ksc, v_scale=vsc)
             else:
                 sc = {}
-            tables, positions, kv_len = rest
+            l, tables, positions, kv_len = rest
             if not block_axes:
                 return paged_attention(
-                    qx, kc, vc, tables, positions, scale=scale,
+                    qx, kc, vc, tables, positions, layer=l, scale=scale,
                     impl=fused_impl, chunk_blocks=chunk_blocks,
                     kv_len=kv_len, partial_out=partial, **sc,
                 )
             # blocks are sharded: remap global table entries to this
             # shard's local ids (others masked dead), attend locally, and
             # log-sum-exp-merge the partial softmax across the block axes
-            nloc = kc.shape[0]
+            nloc = kc.shape[1]
             idx = jnp.int32(0)
             for a in block_axes:
                 idx = idx * dict(mesh.shape)[a] + lax.axis_index(a)
             lo = idx * nloc
             live = (tables > 0) & (tables >= lo) & (tables < lo + nloc)
             ptab = jnp.where(live, tables - lo, -1).astype(jnp.int32)
-            acc, m, l = paged_attention(
-                qx, kc, vc, ptab, positions, scale=scale, impl=fused_impl,
-                signed_tables=True, partial_out=True,
+            acc, m, den = paged_attention(
+                qx, kc, vc, ptab, positions, layer=l, scale=scale,
+                impl=fused_impl, signed_tables=True, partial_out=True,
                 chunk_blocks=chunk_blocks, kv_len=kv_len, **sc,
             )
             if partial:
@@ -1000,23 +1032,24 @@ def make_paged_decoder(
                 m_g = lax.pmax(m, block_axes)
                 e = jnp.exp(m - m_g)
                 num = lax.psum(acc * e[..., None], block_axes)
-                den = lax.psum(l * e, block_axes)
+                den = lax.psum(den * e, block_axes)
                 return num, m_g, den
             return merge_partials(
-                acc, m, l, axis_names=block_axes, out_dtype=qx.dtype
+                acc, m, den, axis_names=block_axes, out_dtype=qx.dtype
             )
 
         bspec = tuple(block_axes) if block_axes else None
         kvspec = tuple(kv_axes) if kv_axes else None
         hspec = tuple(q_axes) if q_axes else None
         qspec = P(None, None, hspec, None)
-        in_specs = [qspec, P(bspec, None, kvspec, None), P(bspec, None, kvspec, None)]
+        # the stacked pool's leading layer dim is never sharded
+        in_specs = [qspec] + [P(None, bspec, None, kvspec, None)] * 2
         args = [qx, kc, vc]
         if quant:
-            in_specs += [P(bspec, kvspec)] * 2
+            in_specs += [P(None, bspec, kvspec)] * 2
             args += [ksc, vsc]
-        in_specs += [P(None, None), P(None), P(None)]
-        args += [tables, positions, kv_len]
+        in_specs += [P(), P(None, None), P(None), P(None)]
+        args += [l, tables, positions, kv_len]
         manual = set(block_axes) | set(kv_axes) | set(q_axes)
         out_specs = (
             (qspec, P(None, None, hspec), P(None, None, hspec))
@@ -1073,14 +1106,15 @@ def make_paged_decoder(
         # query at global position p iff j <= p (ctx + causal in one mask)
         kmask = (jnp.arange(G * bt)[None, :] <= qpos[:, None])[None]
 
-        def _write_suffix_quant(kc, ksc, knew):
-            """Quantized prefill write: rebuild the window in f32 (dequant
-            + suffix insert + stale-tail zeroing), requantize per block,
-            scatter the blocks back. Returns the updated pool leaves plus
-            the DEQUANTIZED window — attention reads what the cache will
-            serve, so int8 prefill and int8 decode agree on every key."""
-            raw = kc[window]  # [G, bt, KV, D] int8
-            s0 = ksc[window]  # [G, KV]
+        def _write_suffix_quant(kc, ksc, l, knew):
+            """Quantized prefill write: rebuild layer `l`'s window in f32
+            (dequant + suffix insert + stale-tail zeroing), requantize per
+            block, scatter the blocks back. Returns the updated pool leaves
+            plus the DEQUANTIZED window — attention reads what the cache
+            will serve, so int8 prefill and int8 decode agree on every
+            key."""
+            raw = kc[l, window]  # [G, bt, KV, D] int8
+            s0 = ksc[l, window]  # [G, KV]
             win = raw.astype(jnp.float32) * s0[:, None, :, None]
             flat = win.reshape(G * bt, *win.shape[2:])
             # padded suffix tokens scatter out of bounds and are dropped
@@ -1106,13 +1140,11 @@ def make_paged_decoder(
             q8 = jnp.where(owned[:, None, None, None], q8, raw)
             s = jnp.where(owned[:, None], s, s0)
             kw = _dequant(q8, s).reshape(1, G * bt, *win.shape[2:])
-            return kc.at[window].set(q8), ksc.at[window].set(s), kw
+            return kc.at[l, window].set(q8), ksc.at[l, window].set(s), kw
 
-        def layer_fn(x, per_layer):
-            if quant:
-                lp, kc, vc, ksc, vsc = per_layer
-            else:
-                lp, kc, vc = per_layer
+        def layer_fn(carry, per_layer):
+            x, kc, vc, ksc, vsc = carry
+            lp, l = per_layer
             h = rms_norm(x, lp["attn_norm"])
             q = jnp.einsum("bse,ehd->bshd", h, lp["wq"])
             k = jnp.einsum("bse,ekd->bskd", h, lp["wk"])
@@ -1123,20 +1155,18 @@ def make_paged_decoder(
             # write the suffix K/V first — suffix keys are then read back
             # from the pool, so cache content is authoritative either way
             if quant:
-                kc, ksc, kw = _write_suffix_quant(kc, ksc, k[0])
-                vc, vsc, vw = _write_suffix_quant(vc, vsc, v[0])
+                kc, ksc, kw = _write_suffix_quant(kc, ksc, l, k[0])
+                vc, vsc, vw = _write_suffix_quant(vc, vsc, l, v[0])
             else:
-                kc = kc.at[w_phys, w_off].set(k[0].astype(kc.dtype))
-                vc = vc.at[w_phys, w_off].set(v[0].astype(vc.dtype))
-                kw = vw = None
+                kc = kc.at[l, w_phys, w_off].set(k[0].astype(kc.dtype))
+                vc = vc.at[l, w_phys, w_off].set(v[0].astype(vc.dtype))
             if attention_impl == "fused":
                 # multi-query fused walk over the window blocks in place:
                 # query i sits at ctx_len + i, kv_len caps recycled-block
                 # positions past the live span (quant kw/vw are unused —
                 # the kernel dequantizes from the pool itself)
                 attn = _fused_attend(
-                    q, kc, vc, ksc if quant else None,
-                    vsc if quant else None, window[None],
+                    q, kc, vc, ksc, vsc, l, window[None],
                     jnp.reshape(jnp.asarray(ctx_len, jnp.int32), (1,)),
                     kv_len=jnp.reshape(
                         jnp.asarray(ctx_len + length, jnp.int32), (1,)
@@ -1144,23 +1174,23 @@ def make_paged_decoder(
                 )
             else:
                 if not quant:
-                    kw = kc[window].reshape(1, G * bt, *kc.shape[2:])
-                    vw = vc[window].reshape(1, G * bt, *vc.shape[2:])
+                    kw = _gather_window(kc, ksc, l, window[None])
+                    vw = _gather_window(vc, vsc, l, window[None])
                 attn = _cached_attend(q, kw, vw, kmask, scale, n_rep)
             x = x + jnp.einsum("bshd,hde->bse", attn, lp["wo"])
             h2 = rms_norm(x, lp["mlp_norm"])
             x = x + _mlp(h2, lp, cfg, _constrain)
             x = _constrain(x, "batch", "seq", "embed")
-            return x, (kc, vc, ksc, vsc) if quant else (kc, vc)
+            return (x, kc, vc, ksc, vsc), None
 
-        x, new_leaves = lax.scan(
-            layer_fn, x, (params["layers"],) + _scan_leaves(pool)
+        (x, *leaves), _ = lax.scan(
+            layer_fn, (x,) + _pool_leaves(pool), (params["layers"], layer_ids)
         )
         x = rms_norm(x, params["final_norm"])
         x_last = x[0, jnp.maximum(length - 1, 0)][None]
         logits = jnp.einsum("be,ev->bv", x_last, _unembed_matrix(cfg, params))
         logits = _constrain(logits, "batch", "vocab")
-        return _sample(logits, key), logits, _pool_dict(new_leaves)
+        return _sample(logits, key), logits, _pool_dict(*leaves)
 
     # jax.jit names a program after its function, and that name is what the
     # profiler's "XLA Modules" line shows: jit_paged_prefill,
@@ -1189,29 +1219,27 @@ def make_paged_decoder(
     def paged_decode(params, pool, tables, tokens, positions, write_phys,
                      write_off, key):
         params = _cast_matmul_params(cfg, params)
-        B, Nmax = tables.shape
-        W = Nmax * bt
+        W = tables.shape[1] * bt
         x = params["embed"].astype(cfg.dtype)[tokens][:, None, :]  # [B,1,E]
         x = _constrain(x, "batch", "seq", "embed")
         pos2 = positions[:, None]
         kmask = (jnp.arange(W)[None, :] <= pos2)[:, None, :]  # [B,1,W]
 
-        def _write_token_quant(kc, ksc, knew):
+        def _write_token_quant(kc, ksc, l, knew):
             """Quantized decode write: read-modify-write each slot's write
-            block (shared math in `_rmw_insert_quant` — recycled blocks
-            carry stale values past the live span that would poison the
-            scale, hence the zero-tail). knew is [B, KV, D]."""
+            block of layer `l` (shared math in `_rmw_insert_quant` —
+            recycled blocks carry stale values past the live span that
+            would poison the scale, hence the zero-tail). knew is
+            [B, KV, D]."""
             q8, s1 = _rmw_insert_quant(
-                kc[write_phys], ksc[write_phys], knew, write_off
+                kc[l, write_phys], ksc[l, write_phys], knew, write_off
             )
-            return kc.at[write_phys].set(q8), ksc.at[write_phys].set(s1)
+            return (kc.at[l, write_phys].set(q8),
+                    ksc.at[l, write_phys].set(s1))
 
-        def layer_fn(x, per_layer):
-            if quant:
-                lp, kc, vc, ksc, vsc = per_layer
-            else:
-                lp, kc, vc = per_layer
-                ksc = vsc = None
+        def layer_fn(carry, per_layer):
+            x, kc, vc, ksc, vsc = carry
+            lp, l = per_layer
             h = rms_norm(x, lp["attn_norm"])
             q = jnp.einsum("bse,ehd->bshd", h, lp["wq"])  # [B,1,H,D]
             k = jnp.einsum("bse,ekd->bskd", h, lp["wk"])  # [B,1,KV,D]
@@ -1219,44 +1247,38 @@ def make_paged_decoder(
             q = apply_rope(q, cos, sin, positions=pos2)
             k = apply_rope(k, cos, sin, positions=pos2)
             if quant:
-                kc, ksc = _write_token_quant(kc, ksc, k[:, 0])
-                vc, vsc = _write_token_quant(vc, vsc, v[:, 0])
+                kc, ksc = _write_token_quant(kc, ksc, l, k[:, 0])
+                vc, vsc = _write_token_quant(vc, vsc, l, v[:, 0])
             else:
-                kc = kc.at[write_phys, write_off].set(k[:, 0].astype(kc.dtype))
-                vc = vc.at[write_phys, write_off].set(v[:, 0].astype(vc.dtype))
+                kc = kc.at[l, write_phys, write_off].set(
+                    k[:, 0].astype(kc.dtype))
+                vc = vc.at[l, write_phys, write_off].set(
+                    v[:, 0].astype(vc.dtype))
             if attention_impl == "fused":
                 # block-in-place attention: no [B, W] gather exists. This
                 # token's K/V was just written, so the live window is
                 # positions + 1 keys deep
                 attn = _fused_attend(
-                    q, kc, vc, ksc, vsc, tables, positions,
+                    q, kc, vc, ksc, vsc, l, tables, positions,
                     kv_len=positions + 1,
                 )
             else:
-                if quant:
-                    kw = _dequant(kc[tables], ksc[tables]).reshape(
-                        B, W, *kc.shape[2:]
-                    )
-                    vw = _dequant(vc[tables], vsc[tables]).reshape(
-                        B, W, *vc.shape[2:]
-                    )
-                else:
-                    kw = kc[tables].reshape(B, W, *kc.shape[2:])
-                    vw = vc[tables].reshape(B, W, *vc.shape[2:])
+                kw = _gather_window(kc, ksc, l, tables)
+                vw = _gather_window(vc, vsc, l, tables)
                 attn = _cached_attend(q, kw, vw, kmask, scale, n_rep)
             x = x + jnp.einsum("bshd,hde->bse", attn, lp["wo"])
             h2 = rms_norm(x, lp["mlp_norm"])
             x = x + _mlp(h2, lp, cfg, _constrain)
             x = _constrain(x, "batch", "seq", "embed")
-            return x, (kc, vc, ksc, vsc) if quant else (kc, vc)
+            return (x, kc, vc, ksc, vsc), None
 
-        x, new_leaves = lax.scan(
-            layer_fn, x, (params["layers"],) + _scan_leaves(pool)
+        (x, *leaves), _ = lax.scan(
+            layer_fn, (x,) + _pool_leaves(pool), (params["layers"], layer_ids)
         )
         x = rms_norm(x, params["final_norm"])
         logits = jnp.einsum("be,ev->bv", x[:, 0], _unembed_matrix(cfg, params))
         logits = _constrain(logits, "batch", "vocab")
-        return _sample(logits, key), logits, _pool_dict(new_leaves)
+        return _sample(logits, key), logits, _pool_dict(*leaves)
 
     def _rmw_commit_quant(kc, ksc, knew, wp_i, wo_i):
         """[L]-batched twin of the decode step's `_write_token_quant`:
@@ -1319,11 +1341,12 @@ def make_paged_decoder(
         )
         mask = jnp.concatenate([cmask, fmask], axis=2)  # [B, K1, W+K1]
 
+        # read-only here: the loop closes over the pool, and the accepted
+        # K/V commit after it
+        kc, vc, ksc, vsc = _pool_leaves(pool)
+
         def layer_fn(x, per_layer):
-            if quant:
-                lp, kc, vc, ksc, vsc = per_layer
-            else:
-                lp, kc, vc = per_layer
+            lp, l = per_layer
             h = rms_norm(x, lp["attn_norm"])
             q = jnp.einsum("bse,ehd->bshd", h, lp["wq"])
             k = jnp.einsum("bse,ekd->bskd", h, lp["wk"])
@@ -1338,22 +1361,13 @@ def make_paged_decoder(
                 # keys fold in as a second online-softmax partial — the
                 # gather-window concat never materializes
                 acc_w, m_w, l_w = _fused_attend(
-                    q, kc, vc, ksc if quant else None,
-                    vsc if quant else None, tables, positions,
+                    q, kc, vc, ksc, vsc, l, tables, positions,
                     kv_len=positions, partial=True,
                 )
                 attn = _merge_inflight(q, acc_w, m_w, l_w, k, v, fmask)
             else:
-                if quant:
-                    kw = _dequant(kc[tables], ksc[tables]).reshape(
-                        B, W, *kc.shape[2:]
-                    )
-                    vw = _dequant(vc[tables], vsc[tables]).reshape(
-                        B, W, *vc.shape[2:]
-                    )
-                else:
-                    kw = kc[tables].reshape(B, W, *kc.shape[2:])
-                    vw = vc[tables].reshape(B, W, *vc.shape[2:])
+                kw = _gather_window(kc, ksc, l, tables)
+                vw = _gather_window(vc, vsc, l, tables)
                 kcat = jnp.concatenate([kw, k.astype(kw.dtype)], axis=1)
                 vcat = jnp.concatenate([vw, v.astype(vw.dtype)], axis=1)
                 attn = _cached_attend(q, kcat, vcat, mask, scale, n_rep)
@@ -1363,9 +1377,7 @@ def make_paged_decoder(
             x = _constrain(x, "batch", "seq", "embed")
             return x, (k, v)
 
-        x, (ks, vs) = lax.scan(
-            layer_fn, x, (params["layers"],) + _scan_leaves(pool)
-        )
+        x, (ks, vs) = lax.scan(layer_fn, x, (params["layers"], layer_ids))
         x = rms_norm(x, params["final_norm"])
         logits = jnp.einsum("bse,ev->bsv", x, _unembed_matrix(cfg, params))
         logits = _constrain(logits, "batch", "seq", "vocab")

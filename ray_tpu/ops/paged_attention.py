@@ -22,6 +22,14 @@ number of queries per slot — one fused implementation serves
                           scalar-prefetch operand) and DMAs physical
                           block `table[b, j]` directly from the pool —
                           no gathered copy ever exists
+  stacked pool + `layer`  the pool may be ONE layer's [N, bt, KV, D] or
+                          the model's stacked [L, N, bt, KV, D] with a
+                          `layer` index (traced under the layer scan). The
+                          index is a fourth scalar-prefetch operand and the
+                          leading coordinate of the k/v index_map, so the
+                          DMA addresses block `table[b, j]` of layer `l` in
+                          the stacked buffer: the caller never slices a
+                          layer out of it. (A 4-D pool is a stack of one.)
   dead entries            table entries < 0 (padding, inactive slots,
                           out-of-shard blocks) clamp to block 0 in the
                           index map — Pallas skips the re-fetch when the
@@ -41,7 +49,10 @@ repeat dim.
 
 int8 pools (per-block, per-kv-head fp32 scales — see
 transformer.init_paged_kv_cache) dequantize INSIDE the kernel: the HBM
-read is half the bytes of bf16, which is the whole point at decode.
+read is half the bytes of bf16, which is the whole point at decode. The
+scales ([N, KV], or stacked [L, N, KV]) are gathered through the block
+table outside the kernel — [B, Nmax, KV], small whatever the pool holds —
+and follow their K/V tile by grid position.
 
 Sharded pools (blocks split across dp/fsdp shards) run the kernel
 per-shard with `partial_out=True`: the kernel returns the unnormalized
@@ -94,8 +105,9 @@ def _group_values(p, v):
     )
 
 
-def _pa_kernel(tables_ref, pos_ref, kvlen_ref, q_ref, k_ref, v_ref, *rest,
-               bt, qb, n_rep, scale, quantized, partial_out, out_dtype):
+def _pa_kernel(tables_ref, pos_ref, kvlen_ref, layer_ref, q_ref, k_ref, v_ref,
+               *rest, bt, qb, n_rep, scale, quantized, partial_out, out_dtype):
+    del layer_ref  # read by the k/v index maps only
     if quantized:
         ks_ref, vs_ref = rest[0], rest[1]
         rest = rest[2:]
@@ -188,10 +200,10 @@ def _pa_kernel(tables_ref, pos_ref, kvlen_ref, q_ref, k_ref, v_ref, *rest,
 
 
 def _paged_attention_pallas(q, k_pool, v_pool, ptable, positions, kv_len,
-                            k_scale, v_scale, scale, partial_out, interpret,
-                            block_q):
+                            layer, k_scale, v_scale, scale, partial_out,
+                            interpret, block_q):
     b, Q, h, d = q.shape
-    _, bt, kv, _ = k_pool.shape
+    _, _, bt, kv, _ = k_pool.shape
     nmax = ptable.shape[1]
     n_rep = h // kv
     quantized = k_scale is not None
@@ -205,24 +217,32 @@ def _paged_attention_pallas(q, k_pool, v_pool, ptable, positions, kv_len,
 
     q_spec = pl.BlockSpec((1, qb, h, d), lambda b_, qt_, j_, *_: (b_, qt_, 0, 0))
     kv_spec = pl.BlockSpec(
-        (1, bt, kv, d),
+        # the layer dim is squeezed: the body sees [1, bt, KV, D], block
+        # `table[b, j]` of layer `lyr[0]`, DMA'd straight from the stacked
+        # pool — no per-layer slice of it ever exists
+        (None, 1, bt, kv, d),
         # dead entries (< 0) clamp to block 0: repeated indices skip the
         # DMA, so a slot's padding tail costs one null-block fetch total
-        lambda b_, qt_, j_, tbl, pos, kvl: (jnp.maximum(tbl[b_, j_], 0), 0, 0, 0),
+        lambda b_, qt_, j_, tbl, pos, kvl, lyr: (
+            lyr[0], jnp.maximum(tbl[b_, j_], 0), 0, 0, 0),
     )
     in_specs = [q_spec, kv_spec, kv_spec]
     operands = [q, k_pool, v_pool]
     if quantized:
-        # a block's scales follow its K/V tile through the same table
-        # lookup. [N, KV] goes in as [N, KV, 1] so the (1, KV, 1) tile
-        # spans the array's last two dims whole — a (1, KV) block of the
-        # 2-D array would break the sublane tiling rule
+        # the scales of the blocks a slot's table names, gathered by that
+        # table outside the kernel: [B, Nmax, KV] is small whatever the
+        # pool holds, where a relayout of the [L, N, KV] leaf would cost a
+        # padded tile per pool block per layer. It goes in as
+        # [B, Nmax, KV, 1] so the (KV, 1) tile spans the array's last two
+        # dims whole — a (1, KV) block of the 3-D array would break the
+        # sublane tiling rule
         sc_spec = pl.BlockSpec(
-            (1, kv, 1),
-            lambda b_, qt_, j_, tbl, pos, kvl: (jnp.maximum(tbl[b_, j_], 0), 0, 0),
+            (None, 1, kv, 1), lambda b_, qt_, j_, *_: (b_, j_, 0, 0)
         )
+        idx = jnp.maximum(ptable, 0)
         in_specs += [sc_spec, sc_spec]
-        operands += [k_scale[:, :, None], v_scale[:, :, None]]
+        operands += [k_scale[layer, idx][..., None],
+                     v_scale[layer, idx][..., None]]
     o_map = lambda b_, qt_, j_, *_: (b_, qt_, 0, 0)
     if partial_out:
         out_specs = [
@@ -246,7 +266,7 @@ def _paged_attention_pallas(q, k_pool, v_pool, ptable, positions, kv_len,
     outs = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=grid,
             in_specs=in_specs,
             out_specs=out_specs,
@@ -269,7 +289,7 @@ def _paged_attention_pallas(q, k_pool, v_pool, ptable, positions, kv_len,
         # %paged_attention.<n> whatever wrapper (closed_call, shard_map) the
         # kernel is called under: the benchmark's reduction finds it by this
         name="paged_attention",
-    )(ptable, positions, kv_len, *operands)
+    )(ptable, positions, kv_len, jnp.reshape(layer, (1,)), *operands)
     if partial_out:
         acc, m, l = outs
         return acc[:, :Q], m[:, :Q, :, 0], l[:, :Q, :, 0]
@@ -277,14 +297,15 @@ def _paged_attention_pallas(q, k_pool, v_pool, ptable, positions, kv_len,
 
 
 def _paged_attention_xla(q, k_pool, v_pool, ptable, positions, kv_len,
-                         k_scale, v_scale, scale, partial_out, chunk_blocks):
+                         layer, k_scale, v_scale, scale, partial_out,
+                         chunk_blocks):
     """The same block walk as the kernel, chunked for XLA: each chunk
     gathers `chunk_blocks` physical blocks and folds them into the online
     softmax. Never materializes the full [B, Nmax*bt] window or repeated
     KV heads — on CPU this beats the gather path on exactly the traffic
     the kernel saves on TPU."""
     b, Q, h, d = q.shape
-    _, bt, kv, _ = k_pool.shape
+    _, _, bt, kv, _ = k_pool.shape
     nmax = ptable.shape[1]
     n_rep = h // kv
     quantized = k_scale is not None
@@ -302,11 +323,13 @@ def _paged_attention_xla(q, k_pool, v_pool, ptable, positions, kv_len,
     for c in range(nch):
         tb = ptable[:, c * cb:(c + 1) * cb]  # [B, cb]
         idx = jnp.maximum(tb, 0)
-        kc = k_pool[idx]  # [B, cb, bt, KV, D]
-        vc = v_pool[idx]
+        kc = k_pool[layer, idx]  # [B, cb, bt, KV, D]
+        vc = v_pool[layer, idx]
         if quantized:
-            kc = kc.astype(jnp.float32) * k_scale[idx][:, :, None, :, None]
-            vc = vc.astype(jnp.float32) * v_scale[idx][:, :, None, :, None]
+            ks = k_scale[layer, idx][:, :, None, :, None]
+            vs = v_scale[layer, idx][:, :, None, :, None]
+            kc = kc.astype(jnp.float32) * ks
+            vc = vc.astype(jnp.float32) * vs
         kc = kc.astype(jnp.float32).reshape(b, cb * bt, kv, d)
         vc = vc.astype(jnp.float32).reshape(b, cb * bt, kv, d)
         s = jnp.einsum(
@@ -340,13 +363,15 @@ def _paged_attention_xla(q, k_pool, v_pool, ptable, positions, kv_len,
 
 def paged_attention(
     q: jnp.ndarray,        # [B, H, D] one query per slot, or [B, Q, H, D]
-    k_pool: jnp.ndarray,   # [N, block_tokens, KV, D] physical blocks
-    v_pool: jnp.ndarray,   # [N, block_tokens, KV, D]
+    k_pool: jnp.ndarray,   # [N, block_tokens, KV, D] physical blocks, or the
+    v_pool: jnp.ndarray,   # stacked [L, N, block_tokens, KV, D] with `layer`
     tables: jnp.ndarray,   # [B, Nmax] int32 block table per slot
     positions: jnp.ndarray,  # [B] int32 global position of query 0
     *,
-    k_scale: Optional[jnp.ndarray] = None,  # [N, KV] f32 (int8 pools)
-    v_scale: Optional[jnp.ndarray] = None,
+    layer=None,            # int32 scalar (traced or not): which layer of a
+                           # stacked 5-D pool to attend; 4-D pools take none
+    k_scale: Optional[jnp.ndarray] = None,  # [N, KV] f32 (int8 pools), or
+    v_scale: Optional[jnp.ndarray] = None,  # stacked [L, N, KV]
     scale: Optional[float] = None,
     impl: str = "auto",            # auto | kernel | xla
     interpret: Optional[bool] = None,
@@ -377,9 +402,23 @@ def paged_attention(
         q = q[:, None]
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale must be passed together")
-    if q.shape[2] % k_pool.shape[2]:
+    if (k_pool.ndim == 5) != (layer is not None):
         raise ValueError(
-            f"q heads {q.shape[2]} not a multiple of kv heads {k_pool.shape[2]}"
+            "a stacked [L, N, bt, KV, D] pool takes `layer`, a per-layer "
+            f"[N, bt, KV, D] pool does not: got a {k_pool.ndim}-D pool with "
+            f"layer={layer!r}"
+        )
+    if layer is None:
+        # one code path below: a per-layer pool is a stack of one (a
+        # leading unit dim is a bitcast, not a copy)
+        layer = 0
+        k_pool, v_pool = k_pool[None], v_pool[None]
+        if k_scale is not None:
+            k_scale, v_scale = k_scale[None], v_scale[None]
+    layer = jnp.asarray(layer, jnp.int32)
+    if q.shape[2] % k_pool.shape[3]:
+        raise ValueError(
+            f"q heads {q.shape[2]} not a multiple of kv heads {k_pool.shape[3]}"
         )
     if impl not in ("auto", "kernel", "xla"):
         raise ValueError(f"impl must be auto|kernel|xla, got {impl!r}")
@@ -399,13 +438,13 @@ def paged_attention(
         if interpret is None:
             interpret = jax.default_backend() != "tpu"
         out = _paged_attention_pallas(
-            q, k_pool, v_pool, ptable, positions, kv_len, k_scale, v_scale,
-            scale, partial_out, interpret, block_q,
+            q, k_pool, v_pool, ptable, positions, kv_len, layer, k_scale,
+            v_scale, scale, partial_out, interpret, block_q,
         )
     else:
         out = _paged_attention_xla(
-            q, k_pool, v_pool, ptable, positions, kv_len, k_scale, v_scale,
-            scale, partial_out, chunk_blocks,
+            q, k_pool, v_pool, ptable, positions, kv_len, layer, k_scale,
+            v_scale, scale, partial_out, chunk_blocks,
         )
     if squeeze:
         if partial_out:

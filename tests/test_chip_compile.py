@@ -12,7 +12,9 @@ library keeps it until exit, so a second test file doing the same could
 land on another xdist worker and skip in silence.
 """
 
+import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -160,3 +162,141 @@ def test_paged_kernel_int8_compiles(one_chip, q_len):
     # the scales used to ride in pl.ANY and be loaded in the body, which
     # Mosaic refuses and interpret mode never noticed
     _paged(one_chip, q_len=q_len, h=16, kv=16, d=128, int8=True)
+
+
+# ---- the whole paged programs: nothing in them scales with the pool ------
+#
+# The pool ([L, N, bt, KV, D] per K/V leaf) goes through the layer loop of
+# make_paged_decoder as carried state, written and read in place. Whether
+# that engaged shows without a chip: compiled for two pool sizes, the
+# program's temporaries must not grow with the pool, and the optimized HLO
+# must hold no copy, dynamic-slice or dynamic-update-slice that produces a
+# K/V leaf or one layer of it. (With the pool as the scan's xs/ys every
+# layer was sliced out of the stack and written back: temporaries grew by
+# a whole leaf and more.)
+
+PAGED_SLOTS = 32
+PAGED_TABLE = 64  # blocks per slot: 4096 tokens
+# both pool sizes lie on the same side of a step the compiler's memory-space
+# assignment takes near 3.0 GB of arguments (other buffers prefetched, +369
+# MB of temporaries whatever the pool holds); 2049 is the benchmark's pool
+PAGED_POOLS = (1036, 4144)
+PAGED_VARIANTS = [
+    pytest.param("fused", False, id="fused-fp"),
+    pytest.param("fused", True, id="fused-int8"),
+    pytest.param("gather", False, id="gather-fp"),
+]
+
+
+def _mistral_block(n_layers=2):
+    from ray_tpu.models.transformer import TransformerConfig
+
+    return TransformerConfig(
+        vocab_size=32768, d_model=4096, n_layers=n_layers, n_heads=32,
+        n_kv_heads=8, d_head=128, d_ff=14336, max_seq_len=4096,
+    )
+
+
+def _compile_paged_program(one_chip, monkeypatch, program, impl, int8,
+                           num_blocks):
+    """One of make_paged_decoder's programs for the described chip, from
+    shapes alone."""
+    from ray_tpu.models.transformer import (
+        init_paged_kv_cache, init_params, make_paged_decoder,
+    )
+
+    # the fused path asks the backend whether to lower through Mosaic
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = _mistral_block()
+    kv_dtype = jnp.int8 if int8 else None
+    prefill, decode, verify, _ = make_paged_decoder(
+        cfg, block_tokens=BLOCK_TOKENS, kv_dtype=kv_dtype,
+        attention_impl=impl, fused_impl="kernel",
+    )
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            tree,
+        )
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = on_chip(
+        jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    pool = on_chip(jax.eval_shape(
+        lambda: init_paged_kv_cache(cfg, num_blocks, BLOCK_TOKENS,
+                                    dtype=kv_dtype)))
+    key = on_chip(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    B, T = PAGED_SLOTS, PAGED_TABLE
+    if program == "decode":
+        lowered = decode.lower(
+            params, pool, i32(B, T), i32(B), i32(B), i32(B), i32(B), key)
+    elif program == "verify":  # k = 4 drafts
+        lowered = verify.lower(
+            params, pool, i32(B, T), i32(B, 5), i32(B), i32(B), i32(B, 5),
+            i32(B, 5), key)
+    else:  # a 128-token user turn behind 512 cached tokens
+        lowered = jax.jit(
+            functools.partial(prefill, ctx_blocks=8), donate_argnums=(1,)
+        ).lower(params, pool, i32(T), i32(1, 128), i32(), i32(), key)
+    compiled = lowered.compile()
+    if impl == "fused":
+        assert "tpu_custom_call" in compiled.as_text()
+    return cfg, compiled
+
+
+def _pool_sized_moves(hlo_text, cfg, num_blocks):
+    """Instructions of the optimized HLO that copy, slice out or write back
+    a whole K/V leaf or one layer of it, by their result shape."""
+    layer = f"{num_blocks},{BLOCK_TOKENS},{cfg.n_kv_heads},{cfg.d_head}]"
+    inst = re.compile(
+        r"^\s*(?:ROOT )?(%\S+) = \w+\[([\d,]*\])\S* ([\w\-]+)\(", re.M)
+    moves = ("copy", "dynamic-slice", "dynamic-update-slice")
+    return [
+        f"{name}: {op} -> [{dims}"
+        for name, dims, op in inst.findall(hlo_text)
+        if dims.endswith(layer) and (
+            op.startswith(moves)
+            or (op == "fusion" and any(m in name for m in moves)))
+    ]
+
+
+def _assert_nothing_scales_with_the_pool(one_chip, monkeypatch, program,
+                                         impl, int8):
+    small, large = PAGED_POOLS
+    temps = []
+    for num_blocks in PAGED_POOLS:
+        cfg, compiled = _compile_paged_program(
+            one_chip, monkeypatch, program, impl, int8, num_blocks)
+        moves = _pool_sized_moves(compiled.as_text(), cfg, num_blocks)
+        assert not moves, f"{num_blocks} blocks: {moves}"
+        mem = compiled.memory_analysis()
+        # the donated pool is the output: no second pool is allocated
+        pool_bytes = 2 * cfg.n_layers * num_blocks * BLOCK_TOKENS * (
+            cfg.n_kv_heads * cfg.d_head * (1 if int8 else 2))
+        assert mem.alias_size_in_bytes >= pool_bytes
+        temps.append(mem.temp_size_in_bytes)
+    block = BLOCK_TOKENS * cfg.n_kv_heads * cfg.d_head * (1 if int8 else 2)
+    allowed = 2 * block  # one block per K/V leaf
+    if int8:
+        # the two [L, N, KV] f32 scale leaves (1/2048 of the pool's bytes)
+        # are relaid once a step, OUTSIDE the layer loop, into the layout
+        # the loop's gathers want and back: at most one lane-padded row per
+        # block and layer each way (2.4 KB a block measured, against the
+        # 256 KB a block that two layers of int8 K and V hold)
+        allowed += 2 * 2 * cfg.n_layers * (large - small) * 128 * 4
+    assert temps[1] - temps[0] < allowed, temps
+
+
+@pytest.mark.parametrize("impl,int8", PAGED_VARIANTS)
+def test_paged_decode_program_moves_no_pool(one_chip, monkeypatch, impl, int8):
+    _assert_nothing_scales_with_the_pool(
+        one_chip, monkeypatch, "decode", impl, int8)
+
+
+@pytest.mark.parametrize("program", ["prefill", "verify"])
+def test_paged_prefill_and_verify_move_no_pool(one_chip, monkeypatch, program):
+    _assert_nothing_scales_with_the_pool(
+        one_chip, monkeypatch, program, "fused", False)

@@ -359,8 +359,50 @@ def test_multiquery_int8_both_impls_agree():
     )
 
 
+@pytest.mark.parametrize("impl,kw", [("xla", {}), ("kernel", {"interpret": True})])
+@pytest.mark.parametrize("int8", [False, True], ids=["fp", "int8"])
+@pytest.mark.parametrize("partial_out", [False, True], ids=["out", "partial"])
+@pytest.mark.parametrize("Q", [1, 5])
+def test_stacked_pool_layer_equals_per_layer_call(impl, kw, int8, partial_out, Q):
+    """The paged programs carry the stacked [L, N, bt, KV, D] pool through
+    their layer loop and hand it over whole with a (traced) `layer` index:
+    that must read exactly what the per-layer call reads from pool[l]."""
+    L = 3
+    q, _, _, tables, positions = _setup_mq(Q=Q)
+    rng = np.random.default_rng(7)
+    shape = (L, 12, 8, 2, 16)
+    kp = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    scales = [{}] * L
+    stacked = {}
+    if int8:
+        k8, ks = zip(*(_quantize_pool(kp[l]) for l in range(L)))
+        v8, vs = zip(*(_quantize_pool(vp[l]) for l in range(L)))
+        kp, vp = jnp.stack(k8), jnp.stack(v8)
+        scales = [dict(k_scale=ks[l], v_scale=vs[l]) for l in range(L)]
+        stacked = dict(k_scale=jnp.stack(ks), v_scale=jnp.stack(vs))
+    common = dict(impl=impl, partial_out=partial_out, **kw)
+
+    @jax.jit
+    def at_layer(l):  # traced, as under the layer scan
+        return paged_attention(
+            q, kp, vp, tables, positions, layer=l, **stacked, **common)
+
+    for l in (0, L - 1):
+        got = at_layer(jnp.int32(l))
+        assert pa_mod._LAST_IMPL == impl
+        want = paged_attention(
+            q, kp[l], vp[l], tables, positions, **scales[l], **common)
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
 def test_validation_errors():
     q, kp, vp, tables, positions = _setup()
+    with pytest.raises(ValueError, match="layer"):
+        paged_attention(q, kp[None], vp[None], tables, positions)
+    with pytest.raises(ValueError, match="layer"):
+        paged_attention(q, kp, vp, tables, positions, layer=0)
     with pytest.raises(ValueError, match="together"):
         paged_attention(q, kp, vp, tables, positions,
                         k_scale=jnp.zeros((12, 2)))
