@@ -1,6 +1,6 @@
 """What every part of the benchmark shares: where its files are, how a
-published configuration becomes the program's TransformerConfig, the
-required-FLOPs count, the percentile, and the table of peaks.
+configuration's block, a cell and a reader are found by name, the
+percentile, and the table of peaks.
 
 Nothing here opens a JAX backend: the driver process imports it."""
 
@@ -40,23 +40,6 @@ def peaks_for(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def transformer_kwargs(conf: dict) -> dict:
-    """The published keys, renamed to the program's TransformerConfig
-    fields. Both families here are the llama-style block the program runs
-    (RMSNorm, RoPE, GQA, gated SiLU MLP, no bias, untied embeddings);
-    anything else in the file is refused, not ignored."""
-    if conf.get("hidden_act") != "silu" or conf.get("bias") \
-            or conf.get("tie_word_embeddings") or conf.get("sliding_window"):
-        raise ValueError(f"{conf.get('name')}: not the block this harness maps")
-    return dict(
-        vocab_size=conf["vocab_size"], d_model=conf["hidden_size"],
-        n_layers=conf["num_hidden_layers"], n_heads=conf["num_attention_heads"],
-        n_kv_heads=conf["num_key_value_heads"], d_head=conf["head_dim"],
-        d_ff=conf["intermediate_size"], rope_theta=float(conf["rope_theta"]),
-        max_seq_len=conf["run"]["max_seq_len"], tie_embeddings=False,
-    )
-
-
 def jax_seed(seed: int) -> int:
     """--seed may pass 2**31; jax.random.PRNGKey takes a signed 32-bit
     value when x64 is off. Fold the high bits in instead of dropping them."""
@@ -65,32 +48,6 @@ def jax_seed(seed: int) -> int:
 
 
 # ------------------------------------------------------------- arithmetic
-
-
-def matmul_params(conf: dict) -> dict:
-    """Parameters that sit in matrix multiplications, per layer and in the
-    output head. The embedding table is a lookup and the norm scales are
-    elementwise: neither is counted."""
-    e, h, kv, d = (conf["hidden_size"], conf["num_attention_heads"],
-                   conf["num_key_value_heads"], conf["head_dim"])
-    attn = e * h * d + 2 * e * kv * d + h * d * e
-    mlp = 3 * e * conf["intermediate_size"]
-    return {"layer": attn + mlp, "head": e * conf["vocab_size"],
-            "layers": conf["num_hidden_layers"]}
-
-
-def required_train_flops_per_token(conf: dict, seq_len: int) -> float:
-    """FLOPs the forward and backward passes REQUIRE for one token of a
-    `seq_len` sequence: 2 per multiply-add, backward = 2 x forward, so
-    3 x forward. Attention is counted causal: token i attends to i+1
-    keys, (seq_len+1)/2 on average, for QK^T and for PV. Recomputation
-    under remat is work the implementation chose, not required work, and is
-    not counted; neither are the embedding lookup, norms, rope, softmax."""
-    p = matmul_params(conf)
-    matmul = 2.0 * (p["layers"] * p["layer"] + p["head"])
-    attn = (p["layers"] * 2 * 2.0 * conf["num_attention_heads"]
-            * conf["head_dim"] * (seq_len + 1) / 2.0)
-    return 3.0 * (matmul + attn)
 
 
 def percentile(values, q: float) -> float:
@@ -120,11 +77,41 @@ def hist_mean_ms(facts: dict, name: str):
     return (a["sum"] - b["sum"]) / (a["count"] - b["count"]) * 1e3
 
 
-def load_reader(metric: str):
-    """benchmark/layer_metrics/<metric>.py, by the metric's name."""
-    path = os.path.join(BENCH_DIR, "layer_metrics", f"{metric}.py")
+def _load_module(folder: str, name: str):
+    """benchmark/<folder>/<name>.py as a module of its own, found by its
+    file's name: what lets a later PR add one without editing a file."""
+    path = os.path.join(BENCH_DIR, folder, f"{name}.py")
     spec = importlib.util.spec_from_file_location(
-        "layer_metric_" + metric.replace(".", "_").replace("-", "_"), path)
+        f"benchmark_{folder}_" + name.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(metric: str):
+    """benchmark/layer_metrics/<metric>.py, by the metric's name."""
+    return _load_module("layer_metrics", metric).read
+
+
+# what a configuration file says about itself, its cut and its deployment,
+# whatever its block: read by the runners (`run`, `engine`) or by nobody
+BOOKKEEPING = ("name", "source", "architectures", "block", "run", "engine",
+               "reduced", "assumed", "departures", "published", "stands_for",
+               "max_position_embeddings")
+BLOCK_NAMES = ("transformer_kwargs", "required_train_flops_per_token",
+               "ref_logits", "ref_loss")
+
+
+def load_block(conf: dict):
+    """benchmark/blocks/<name>.py, by the configuration file's `block` key
+    (`llama` without one): the module that maps the file onto the program's
+    TransformerConfig, counts its required FLOPs and holds its plain
+    float32 reference. A file that lacks one of BLOCK_NAMES fails here,
+    by that name."""
+    name = conf.get("block", "llama")
+    mod = _load_module("blocks", name)
+    missing = [n for n in BLOCK_NAMES if not callable(getattr(mod, n, None))]
+    if missing:
+        raise AttributeError(
+            f"benchmark/blocks/{name}.py lacks {', '.join(missing)}")
+    return mod

@@ -1,7 +1,9 @@
-"""The plain reference: the llama-style decoder block's forward pass and
-loss in straightforward jax.numpy, float32, matmul precision "highest" —
-no kernel, no cache, no remat, no batching. It follows the published
-description of the two families the benchmark runs (InternLM2, Mistral):
+"""The plain reference of the llama block (blocks/llama.py, which imports
+this file when its ref_logits / ref_loss are first called): the decoder
+block's forward pass and loss in straightforward jax.numpy, float32, matmul
+precision "highest" — no kernel, no cache, no remat, no batching. It follows
+the published description of the two families the benchmark runs (InternLM2,
+Mistral):
 
     h   = x + Wo . softmax(causal(q k^T / sqrt(d))) v     q,k with RoPE,
           q,k,v = Wq,Wk,Wv . rmsnorm(x); K/V heads shared by groups (GQA)

@@ -133,7 +133,8 @@ def train_result(cell, conf, facts):
     peaks = common.peaks_for(facts["device_kind"])
     tokens = facts["steps"] * facts["tokens_per_step"]
     rate = tokens / facts["window_s"] / facts["n_devices"]
-    flops = common.required_train_flops_per_token(conf, cell["seq_len"])
+    flops = common.load_block(conf).required_train_flops_per_token(
+        conf, cell["seq_len"])
     delta = abs(facts["first_loss"] - facts["reference_loss"])
     checks = {
         # bf16 compute against a float32 reference, averaged over every

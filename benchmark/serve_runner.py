@@ -185,7 +185,7 @@ def _deploy(cell, conf, seed):
     name = "bench"
     Dep = serve_deployment(name=name, num_replicas=1)(BenchServer)
     app = Dep.bind(
-        common.transformer_kwargs(conf), conf=conf,
+        common.load_block(conf).transformer_kwargs(conf), conf=conf,
         weights_seed=common.jax_seed(seed),
         engine_kwargs=dict(conf["engine"]), deployment=name,
     )

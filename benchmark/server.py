@@ -162,8 +162,9 @@ class BenchServer(KVGenerationServer):
         magnitude and misses it at once."""
         import numpy as np
 
-        from benchmark import reference
+        from benchmark import common
 
+        ref_logits = common.load_block(self._conf).ref_logits
         rows = []
         for p in prompts:
             p = [int(t) for t in p]
@@ -171,7 +172,7 @@ class BenchServer(KVGenerationServer):
                 tokens=p, max_new_tokens=int(new_tokens))]
             seq = p + out[:-1]
             pos = list(range(len(p) - 1, len(p) - 1 + len(out)))
-            logits = np.asarray(reference.ref_logits(
+            logits = np.asarray(ref_logits(
                 self.engine.params, seq, self._conf, positions=pos))
             top = logits.max(axis=-1)
             served = logits[np.arange(len(out)), np.asarray(out)]

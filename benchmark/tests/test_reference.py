@@ -1,5 +1,6 @@
-"""The plain float32 reference against the program's own dense forward and
-loss, at a tiny width on the CPU, grouped-query attention included.
+"""The llama block's plain float32 reference (blocks/llama.py over
+reference.py) against the program's own dense forward and loss, at a tiny
+width on the CPU, grouped-query attention included.
 
     JAX_PLATFORMS=cpu python3 -m pytest benchmark/tests -q -p no:cacheprovider
 """
@@ -31,13 +32,14 @@ CONF = {
     "rms_norm_eps": 1e-6, "hidden_act": "silu", "bias": False,
     "tie_word_embeddings": False, "run": {"max_seq_len": 96},
 }
+BLOCK = common.load_block(CONF)  # no `block` key: the llama block
 
 
 @pytest.fixture(scope="module")
 def setup():
     # the program in float32 with dense attention: the same mathematics as
     # the reference, so what is left is summation order
-    cfg = TransformerConfig(**common.transformer_kwargs(CONF),
+    cfg = TransformerConfig(**BLOCK.transformer_kwargs(CONF),
                             dtype=jnp.float32, attention="dense", remat=False)
     params = init_params(jax.random.PRNGKey(5), cfg)
     tokens = np.random.default_rng(5).integers(0, 257, size=(2, 81))
@@ -49,7 +51,7 @@ def test_reference_logits_match_the_dense_forward(setup):
     with jax.default_matmul_precision("highest"):
         want = np.asarray(make_forward(cfg)(params, jnp.asarray(tokens[:, :-1])))
     for b in range(2):
-        got = np.asarray(reference.ref_logits(params, tokens[b, :-1], CONF))
+        got = np.asarray(BLOCK.ref_logits(params, tokens[b, :-1], CONF))
         # float32 both sides: 3 layers of sums in another order. 2e-5 of
         # the largest logit is ~100 float32 ulps, and 400x tighter than a
         # bfloat16 computation (2^-8 per rounding) could reach
@@ -59,8 +61,8 @@ def test_reference_logits_match_the_dense_forward(setup):
 
 def test_reference_positions_select_rows(setup):
     cfg, params, tokens = setup
-    full = np.asarray(reference.ref_logits(params, tokens[0, :-1], CONF))
-    some = np.asarray(reference.ref_logits(
+    full = np.asarray(BLOCK.ref_logits(params, tokens[0, :-1], CONF))
+    some = np.asarray(BLOCK.ref_logits(
         params, tokens[0, :-1], CONF, positions=[3, 79]))
     np.testing.assert_allclose(some, full[[3, 79]], rtol=0, atol=1e-6)
 
@@ -81,6 +83,8 @@ def test_reference_loss_matches_the_program_loss(setup):
         want = float(make_loss_fn(cfg)(params, batch))
     got = reference.ref_loss(params, tokens, CONF, row_block=32)
     assert abs(got - want) <= 1e-5 * want   # float32 both sides, see above
+    # the block's own name for it, as the train runner calls it
+    assert BLOCK.ref_loss(params, tokens, CONF) == pytest.approx(got, rel=1e-6)
 
 
 def test_eps_departure(setup):
@@ -95,11 +99,11 @@ def test_eps_departure(setup):
     for cuts of depth alone), and the reference follows the files."""
     cfg, params, tokens = setup
     published = dict(CONF, rms_norm_eps=1e-5)
-    a = reference.ref_loss(params, tokens, CONF)
-    b = reference.ref_loss(params, tokens, published)
+    a = BLOCK.ref_loss(params, tokens, CONF)
+    b = BLOCK.ref_loss(params, tokens, published)
     assert 1e-4 < abs(a - b) < 5e-3
-    la = np.asarray(reference.ref_logits(params, tokens[0, :-1], CONF))
-    lb = np.asarray(reference.ref_logits(params, tokens[0, :-1], published))
+    la = np.asarray(BLOCK.ref_logits(params, tokens[0, :-1], CONF))
+    lb = np.asarray(BLOCK.ref_logits(params, tokens[0, :-1], published))
     assert 0.01 < np.max(np.abs(la - lb)) / np.max(np.abs(la)) < 0.2
     for name in ("internlm2-1.8b", "internlm2-1.8b-l12", "mistral-7b-v0.3-l6"):
         conf = common.load_config(name)

@@ -195,9 +195,10 @@ def test_roofline_counts_by_hand():
     assert unit == INTERNLM_FLASH_MATMUL_FLOPS == 68_736_253_952
     # forward 2 + backward 4 matmuls in each of 12 layers are exactly the
     # attention part of the benchmark's required FLOPs per token
-    p = common.matmul_params(internlm)
+    block = common.load_block(internlm)
+    p = block.matmul_params(internlm)
     matmul_part = 3 * 2.0 * (p["layers"] * p["layer"] + p["head"])
-    attention_part = common.required_train_flops_per_token(
+    attention_part = block.required_train_flops_per_token(
         internlm, cell["seq_len"]) - matmul_part
     tokens = cell["seq_len"] * cell["batch_per_chip"]
     assert 6 * 12 * unit / tokens == pytest.approx(attention_part, rel=1e-12)

@@ -16,6 +16,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
 from benchmark import common, serve_runner, trace_reduce, traffic  # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
+# the block every configuration file here resolves to (test_blocks.py)
+LLAMA = common.load_block({})
 
 
 # ------------------------------------------------------------ percentile
@@ -52,16 +54,16 @@ def test_flops_internlm2_by_hand():
     layer, head = 62_914_560, 189_530_112
     for name, layers in (("internlm2-1.8b-l12", 12), ("internlm2-1.8b", 24)):
         conf = common.load_config(name)
-        p = common.matmul_params(conf)
+        p = LLAMA.matmul_params(conf)
         assert (p["layer"], p["head"], p["layers"]) == (layer, head, layers)
         # causal attention at S=4096: QK^T and PV, 2 FLOPs a multiply-add,
         # 16 heads x 128, (4096+1)/2 keys on average
         attn = layers * 2 * 2 * 16 * 128 * 4097 / 2
         want = 3 * (2 * (layers * layer + head) + attn)
-        assert common.required_train_flops_per_token(conf, 4096) == want
+        assert LLAMA.required_train_flops_per_token(conf, 4096) == want
     # the l12 cut: 5.67 GFLOP of matmul + 0.60 of causal attention
     conf = common.load_config("internlm2-1.8b-l12")
-    assert common.required_train_flops_per_token(conf, 4096) / 1e9 == \
+    assert LLAMA.required_train_flops_per_token(conf, 4096) / 1e9 == \
         pytest.approx(6.2712, abs=1e-4)
 
 
@@ -70,16 +72,16 @@ def test_flops_mistral_by_hand():
     32,768. Per layer: 4096x4096 x 2 + 4096x1024 x 2 = 41,943,040; MLP
     3 x 4096 x 14336 = 176,160,768; together 218,103,808."""
     conf = common.load_config("mistral-7b-v0.3-l6")
-    p = common.matmul_params(conf)
+    p = LLAMA.matmul_params(conf)
     assert p == {"layer": 218_103_808, "head": 134_217_728, "layers": 6}
     attn = 6 * 2 * 2 * 32 * 128 * (1024 + 1) / 2
-    assert common.required_train_flops_per_token(conf, 1024) == \
+    assert LLAMA.required_train_flops_per_token(conf, 1024) == \
         3 * (2 * (6 * 218_103_808 + 134_217_728) + attn)
 
 
 def test_causal_attention_is_half_of_full():
     conf = common.load_config("internlm2-1.8b")
-    f = common.required_train_flops_per_token
+    f = LLAMA.required_train_flops_per_token
     matmul = f(conf, 0) - 3 * 24 * 4 * 16 * 128 * 0.5
     full = 3 * 24 * 2 * 2 * 16 * 128 * 4096  # every token sees every key
     assert (f(conf, 4096) - matmul) / full == pytest.approx(0.5, rel=1e-3)
@@ -87,12 +89,12 @@ def test_causal_attention_is_half_of_full():
 
 def test_configs_map_onto_the_program():
     for name in ("internlm2-1.8b", "internlm2-1.8b-l12", "mistral-7b-v0.3-l6"):
-        kw = common.transformer_kwargs(common.load_config(name))
+        kw = LLAMA.transformer_kwargs(common.load_config(name))
         assert kw["n_heads"] * kw["d_head"] == kw["d_model"]
         assert kw["n_heads"] % kw["n_kv_heads"] == 0 and kw["max_seq_len"] == 4096
     bad = dict(common.load_config("internlm2-1.8b"), sliding_window=4096)
     with pytest.raises(ValueError):
-        common.transformer_kwargs(bad)
+        LLAMA.transformer_kwargs(bad)
 
 
 def test_peaks_missing_kind_is_an_error():

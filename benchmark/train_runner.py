@@ -20,7 +20,7 @@ def train_loop(config):
     import jax.numpy as jnp
     import numpy as np
 
-    from benchmark import common, reference, trace_reduce
+    from benchmark import common, trace_reduce
     from ray_tpu.models.transformer import TransformerConfig
     from ray_tpu.parallel import MeshSpec, PRESET_RULES, build_mesh
     from ray_tpu.train import session
@@ -39,7 +39,8 @@ def train_loop(config):
     # init values must not depend on the output sharding
     jax.config.update("jax_threefry_partitionable", True)
     devs = jax.devices()
-    cfg = TransformerConfig(**common.transformer_kwargs(conf), **cell["model"])
+    block = common.load_block(conf)
+    cfg = TransformerConfig(**block.transformer_kwargs(conf), **cell["model"])
     o = cell["optimizer"]
     opt = default_optimizer(lr=o["lr"], warmup=o["warmup"],
                             mu_dtype=getattr(jnp, o["mu_dtype"]))
@@ -68,7 +69,7 @@ def train_loop(config):
     # correctness, outside the window: the plain float32 reference on the
     # system's own parameters and first batch, before the step donates them
     t0 = time.perf_counter()
-    ref_loss = reference.ref_loss(
+    ref_loss = block.ref_loss(
         state.params, np.asarray(batch["tokens"]), conf)
     reference_s = time.perf_counter() - t0
 
