@@ -629,6 +629,9 @@ class PagedDecodeEngine:
         self.prefill_chunks = 0     # paged-prefill dispatches (>= prefills)
         self.chunked_prefills = 0   # admissions that streamed in chunks
         self.decode_steps = 0
+        # sparse-expert models: see the decode step
+        self.moe_pairs = 0
+        self.moe_hottest = 0
         self.prefix_hits = 0
         self.prefix_tokens_reused = 0
         self.preemptions = 0
@@ -1187,7 +1190,7 @@ class PagedDecodeEngine:
         # trace their number; kv_tokens is what the paged kernel must read
         step_span.set(slots=tuple(surviving), kv_tokens=kv_tokens)
         with span("engine.dispatch"):
-            next_toks, logits, self.pool = self._decode_step(
+            next_toks, logits, self.pool, hottest = self._decode_step(
                 self.params, self.pool, self._tables, self._last_tokens,
                 self._positions, write_phys, write_off, key,
             )
@@ -1197,6 +1200,15 @@ class PagedDecodeEngine:
                 np.asarray(self._lp_fn(logits, next_toks))
                 if self.logprobs else None
             )
+            if hottest is not None:
+                # a sparse-expert model: the step's routed (token, expert)
+                # pairs and the load of its fullest expert, both summed
+                # over the layers (pairs * n_experts / hottest = 1: even)
+                pairs = len(surviving) * self.cfg.top_k * self.cfg.n_layers
+                hottest = int(hottest)
+                step_span.set(moe_pairs=pairs, moe_hottest=hottest)
+                self.moe_pairs += pairs
+                self.moe_hottest += hottest
         with span("engine.bookkeep"):
             out: Dict[int, Tuple[Any, bool]] = {}
             for s in surviving:
@@ -1653,6 +1665,10 @@ class PagedDecodeEngine:
                 1 for st in self._chunk_state if st is not None
             ),
             "decode_steps": self.decode_steps,
+            # decode steps of a sparse-expert model: routed (token, expert)
+            # pairs, and the summed load of each step's fullest expert
+            "moe_pairs": self.moe_pairs,
+            "moe_hottest": self.moe_hottest,
             "max_batch_size": self.max_batch_size,
             "block_tokens": self.block_tokens,
             "kv_cache_dtype": self.kv_cache_dtype,

@@ -105,7 +105,15 @@ class TransformerConfig:
     # the real EP path; "dense": every expert computes every token (exact
     # oracle for tests, O(n_experts) FLOPs)
     moe_impl: str = "dispatch"
-    moe_capacity_factor: float = 1.25
+    # slots per expert = ceil(top_k * tokens / n_experts * factor): pairs
+    # past an expert's slots are DROPPED (the `ep`-sharded path needs the
+    # fixed buffer). None = dropless: every routed (token, expert) pair is
+    # computed, whatever the routing — what published sparse-expert layers
+    # (OLMoE, ...) define, and the only exact setting for a decode batch
+    moe_capacity_factor: Optional[float] = 1.25
+    # divide the top-k router weights by their sum (False: use the softmax
+    # probabilities as they are — HF `norm_topk_prob: false`)
+    moe_renormalize: bool = True
     tie_embeddings: bool = False
     # "silu_gate": llama-family gated MLP (w_gate/w_up/w_down, silu) —
     # the default everywhere. "gelu": gpt2-family two-matmul MLP
@@ -132,6 +140,11 @@ class TransformerConfig:
     # jax.checkpoint (ops/losses.py blockwise_softmax_cross_entropy). Frees
     # O(tokens x vocab) residual HBM — worth a batch-size step on 16G chips
     loss_chunk: int = 0
+    # eps of every RMSNorm (published configs: `rms_norm_eps`)
+    rms_norm_eps: float = 1e-6
+    # RMSNorm with a learned scale over a token's WHOLE q and k projection
+    # (all heads x d_head), before the split into heads and before RoPE
+    qk_norm: bool = False
 
     def __post_init__(self):
         if self.mlp_variant not in ("silu_gate", "gelu"):
@@ -170,6 +183,8 @@ class TransformerConfig:
             + self.d_model * self.d_head * (self.n_heads + 2 * self.n_kv_heads)
             + self.n_heads * self.d_head * self.d_model
         )
+        if self.qk_norm:
+            lp += self.d_head * (self.n_heads + self.n_kv_heads)
         if self.n_experts:
             lp += self.d_model * self.n_experts  # router
             lp += self.n_experts * 3 * self.d_model * self.d_ff
@@ -231,6 +246,11 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
         "wo": ("layers", "heads", "head_dim", "embed"),
         "mlp_norm": ("layers", "embed"),
     }
+    if cfg.qk_norm:
+        layer.update(
+            q_norm=("layers", "heads", "head_dim"),
+            k_norm=("layers", "kv_heads", "head_dim"),
+        )
     if cfg.n_experts:
         layer.update(
             router=("layers", "embed", "expert"),
@@ -281,6 +301,8 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
         "wo": dense_init(next(keys), (L, H, D, E), H * D),
         "mlp_norm": norm_init(L, E),
     }
+    if cfg.qk_norm:
+        layer.update(q_norm=norm_init(L, H, D), k_norm=norm_init(L, KV, D))
     if cfg.n_experts:
         X = cfg.n_experts
         layer.update(
@@ -322,44 +344,56 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
 # --------------------------------------------------------------------------
 
 
-def _moe_dense(h, lp, cfg: TransformerConfig):
+def _moe_route(x, lp, cfg: TransformerConfig):
+    """Router of one expert layer. x [N, E] -> (w [N, k] f32, idx [N, k]):
+    softmax in f32 over ALL experts, then the k largest; the weights are
+    divided by their sum only under `cfg.moe_renormalize`. The logits leave
+    the matmul in f32: rounded to bf16 they would reorder near-equal
+    experts."""
+    with jax.named_scope("moe.route"):
+        gate_logits = jnp.einsum(
+            "ne,ex->nx", x, lp["router"].astype(x.dtype),
+            preferred_element_type=jnp.float32,
+        )
+        probs = jax.nn.softmax(gate_logits, axis=-1)
+        w, idx = lax.top_k(probs, cfg.top_k)
+        if cfg.moe_renormalize:
+            w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)
+    return w, idx
+
+
+def _moe_dense(x, w, idx, lp, cfg: TransformerConfig):
     """Dense-dispatch oracle: every expert computes every token; the top-k
     router weights zero out non-selected experts. Exact but O(n_experts)
-    FLOPs — kept as the correctness reference for the dispatch path."""
-    gate_logits = jnp.einsum("bse,ex->bsx", h, lp["router"].astype(h.dtype))
-    probs = jax.nn.softmax(gate_logits.astype(jnp.float32), axis=-1)
-    top_vals, _ = lax.top_k(probs, cfg.top_k)
-    thresh = top_vals[..., -1:]
-    gate = jnp.where(probs >= thresh, probs, 0.0)
-    gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
-    g = jnp.einsum("bse,xef->bsxf", h, lp["w_gate"].astype(h.dtype))
-    u = jnp.einsum("bse,xef->bsxf", h, lp["w_up"].astype(h.dtype))
-    y = jnp.einsum("bsxf,xfe->bsxe", jax.nn.silu(g) * u, lp["w_down"].astype(h.dtype))
-    return jnp.einsum("bsxe,bsx->bse", y, gate.astype(h.dtype))
+    FLOPs — kept as the correctness reference for the other two paths."""
+    gate = jnp.sum(
+        jax.nn.one_hot(idx, cfg.n_experts, dtype=jnp.float32) * w[..., None],
+        axis=1,
+    )  # [N, X]
+    g = jnp.einsum("ne,xef->nxf", x, lp["w_gate"].astype(x.dtype))
+    u = jnp.einsum("ne,xef->nxf", x, lp["w_up"].astype(x.dtype))
+    y = jnp.einsum("nxf,xfe->nxe", jax.nn.silu(g) * u, lp["w_down"].astype(x.dtype))
+    return jnp.einsum("nxe,nx->ne", y, gate.astype(x.dtype))
 
 
-def _moe_dispatch(h, lp, cfg: TransformerConfig, constrain_fn):
-    """Capacity-based top-k MoE (GShard/Switch family, TPU-first):
+def _moe_dispatch(x, w, idx, lp, cfg: TransformerConfig, constrain_fn):
+    """Capacity-based top-k MoE (GShard/Switch family, TPU-first) — the
+    path of a set `cfg.moe_capacity_factor`, and the one that DROPS:
 
     tokens are sorted by destination expert and scattered into a fixed
     [n_experts, capacity, d_model] buffer; the expert FFNs run as ONE
     batched matmul over that buffer; outputs scatter-add back weighted by
-    the (renormalized) router probabilities. FLOPs scale with top_k * N *
-    capacity_factor — independent of n_experts. Under an `ep`-sharded mesh
-    the sharding constraint on the buffer makes GSPMD insert the token
-    all-to-alls (SURVEY §2.4 "mesh expert axis + ragged all-to-all");
-    overflow beyond capacity is dropped (standard capacity-factor trade).
+    the router weights. FLOPs scale with top_k * N * capacity_factor —
+    independent of n_experts. Under an `ep`-sharded mesh the sharding
+    constraint on the buffer makes GSPMD insert the token all-to-alls
+    (SURVEY §2.4 "mesh expert axis + ragged all-to-all"); a (token,
+    expert) pair beyond its expert's capacity is dropped — silently, and
+    at a decode batch's handful of tokens the capacity is 1 or 2.
+    `moe_capacity_factor=None` (`_moe_dropless`) drops nothing.
     Static shapes throughout: sort + gather/scatter, no ragged compute."""
-    B, S, E = h.shape
-    N = B * S
+    N, E = x.shape
     X, k = cfg.n_experts, cfg.top_k
     C = min(N, max(1, math.ceil(k * N / X * cfg.moe_capacity_factor)))
-
-    x = h.reshape(N, E)
-    gate_logits = jnp.einsum("ne,ex->nx", x, lp["router"].astype(h.dtype))
-    probs = jax.nn.softmax(gate_logits.astype(jnp.float32), axis=-1)
-    w, idx = lax.top_k(probs, k)  # [N, k]
-    w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)
 
     flat_e = idx.reshape(-1)                       # [N*k] destination expert
     flat_t = jnp.repeat(jnp.arange(N), k)          # [N*k] source token
@@ -369,20 +403,71 @@ def _moe_dispatch(h, lp, cfg: TransformerConfig, constrain_fn):
     # slot within the expert's capacity window
     group_start = jnp.searchsorted(se, jnp.arange(X))
     pos = jnp.arange(N * k) - group_start[se]
-    valid = (pos < C).astype(h.dtype)              # overflow -> dropped
+    valid = (pos < C).astype(x.dtype)              # overflow -> dropped
     pos_c = jnp.minimum(pos, C - 1)
 
-    buf = jnp.zeros((X, C, E), h.dtype)
+    buf = jnp.zeros((X, C, E), x.dtype)
     buf = buf.at[se, pos_c].add(x[st] * valid[:, None])
     buf = constrain_fn(buf, "expert", None, "embed")
-    g = jnp.einsum("xce,xef->xcf", buf, lp["w_gate"].astype(h.dtype))
-    u = jnp.einsum("xce,xef->xcf", buf, lp["w_up"].astype(h.dtype))
-    y = jnp.einsum("xcf,xfe->xce", jax.nn.silu(g) * u, lp["w_down"].astype(h.dtype))
+    g = jnp.einsum("xce,xef->xcf", buf, lp["w_gate"].astype(x.dtype))
+    u = jnp.einsum("xce,xef->xcf", buf, lp["w_up"].astype(x.dtype))
+    y = jnp.einsum("xcf,xfe->xce", jax.nn.silu(g) * u, lp["w_down"].astype(x.dtype))
     y = constrain_fn(y, "expert", None, "embed")
 
-    contrib = y[se, pos_c] * (sw.astype(h.dtype) * valid)[:, None]  # [N*k, E]
-    out = jnp.zeros((N, E), h.dtype).at[st].add(contrib)
-    return out.reshape(B, S, E)
+    contrib = y[se, pos_c] * (sw.astype(x.dtype) * valid)[:, None]  # [N*k, E]
+    return jnp.zeros((N, E), x.dtype).at[st].add(contrib)
+
+
+def _moe_dropless(x, w, idx, lp, cfg: TransformerConfig):
+    """Dropless top-k MoE (`moe_capacity_factor=None`): EVERY routed
+    (token, expert) pair is computed, at the FLOPs of the N*k pairs.
+
+    The pairs are sorted by expert, so each expert's rows are contiguous
+    in one [N*k, d_model] matrix, and `lax.ragged_dot` multiplies each run
+    of rows by its own expert's weights with `group_sizes` from the router
+    — on TPU one grouped-matmul kernel that reads an expert's weights once
+    and skips experts without rows. Shapes are static for any N >= 1 and
+    any routing (all pairs on one expert included); the output is gathered
+    back per token and the k contributions summed in f32."""
+    N, E = x.shape
+    X, k = cfg.n_experts, cfg.top_k
+    flat_e = idx.reshape(-1)                       # [N*k] destination expert
+    order = jnp.argsort(flat_e, stable=True)       # pairs grouped by expert
+    sizes = jnp.sum(
+        jax.nn.one_hot(flat_e, X, dtype=jnp.int32), axis=0
+    )                                              # [X] rows per expert
+    xs = x[order // k]                             # [N*k, E] sorted rows
+    g = lax.ragged_dot(xs, lp["w_gate"].astype(x.dtype), sizes)
+    u = lax.ragged_dot(xs, lp["w_up"].astype(x.dtype), sizes)
+    y = lax.ragged_dot(jax.nn.silu(g) * u, lp["w_down"].astype(x.dtype), sizes)
+    # back to token order: pair j of token n sits at sorted row inv[n*k+j]
+    inv = jnp.zeros((N * k,), jnp.int32).at[order].set(
+        jnp.arange(N * k, dtype=jnp.int32))
+    y = y[inv].reshape(N, k, E).astype(jnp.float32)
+    return jnp.sum(y * w[..., None], axis=1).astype(x.dtype)
+
+
+def _moe(h, lp, cfg: TransformerConfig, constrain_fn):
+    """The sparse-expert MLP: h [B, S, E] -> (out [B, S, E], idx [B*S, k],
+    the experts each token was routed to)."""
+    B, S, E = h.shape
+    x = h.reshape(B * S, E)
+    w, idx = _moe_route(x, lp, cfg)
+    with jax.named_scope("moe.experts"):
+        if cfg.moe_impl == "dense":
+            out = _moe_dense(x, w, idx, lp, cfg)
+        elif cfg.moe_capacity_factor is None:
+            out = _moe_dropless(x, w, idx, lp, cfg)
+        else:
+            out = _moe_dispatch(x, w, idx, lp, cfg, constrain_fn)
+    return out.reshape(B, S, E), idx
+
+
+def _fullest_expert(idx, live, n_experts: int):
+    """Load of the fullest expert: the most (token, expert) pairs any one
+    expert got from the tokens marked `live` ([N] bool). idx [N, k]."""
+    hits = jax.nn.one_hot(idx, n_experts, dtype=jnp.int32) * live[:, None, None]
+    return jnp.max(jnp.sum(hits, axis=(0, 1)))
 
 
 _MATMUL_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "router")
@@ -402,9 +487,7 @@ def _cast_matmul_params(cfg: TransformerConfig, params):
 
 def _mlp(h, lp, cfg: TransformerConfig, constrain_fn):
     if cfg.n_experts:
-        if cfg.moe_impl == "dense":
-            return _moe_dense(h, lp, cfg)
-        return _moe_dispatch(h, lp, cfg, constrain_fn)
+        return _moe(h, lp, cfg, constrain_fn)[0]
     from jax.ad_checkpoint import checkpoint_name
 
     u = checkpoint_name(
@@ -422,6 +505,57 @@ def _mlp(h, lp, cfg: TransformerConfig, constrain_fn):
     )
     g = constrain_fn(g, "batch", "seq", "mlp")
     return jnp.einsum("bsf,fe->bse", jax.nn.silu(g) * u, lp["w_down"].astype(h.dtype))
+
+
+def _whole_projection_norm(x, scale, eps: float, head_major: bool):
+    """RMSNorm over a token's whole projection — every head and every
+    head_dim entry together — with the learned `scale` [heads, d_head]
+    (QK-norm as OLMoE defines it: before the split into heads matters and
+    before RoPE). x is [B, S, H, D], or [B, H, S, D] when `head_major`."""
+    x32 = x.astype(jnp.float32)
+    axes = (1, 3) if head_major else (2, 3)
+    var = jnp.mean(x32 * x32, axis=axes, keepdims=True)
+    scale = scale.astype(jnp.float32)
+    if head_major:
+        scale = scale[:, None, :]
+    y = x32 * jnp.reciprocal(jnp.sqrt(var + eps))
+    return (y * scale).astype(x.dtype)
+
+
+def _qkv(x, lp, cfg: TransformerConfig, cos, sin, positions=None,
+         head_major: bool = False):
+    """(q, k, v) of one layer from the layer's input x [B, S, E] — the one
+    place every layer body (training forward, paged prefill / decode /
+    verify, dense prefill / decode) gets them from: pre-norm with the
+    config's eps, the three projections, QK-norm over the whole projection
+    when the config has it, RoPE at `positions` ([B, S] or [1, S]; None =
+    0..S-1). Layout [B, S, H, D], or [B, H, S, D] when `head_major` (the
+    training forward's kernel-native layout, which also names q, k, v for
+    the remat policies)."""
+    h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+    out = "bhsd" if head_major else "bshd"
+    q = jnp.einsum(f"bse,ehd->{out}", h, lp["wq"].astype(h.dtype))
+    k = jnp.einsum(f"bse,ehd->{out}", h, lp["wk"].astype(h.dtype))
+    v = jnp.einsum(f"bse,ehd->{out}", h, lp["wv"].astype(h.dtype))
+    if cfg.qk_norm:
+        q = _whole_projection_norm(q, lp["q_norm"], cfg.rms_norm_eps, head_major)
+        k = _whole_projection_norm(k, lp["k_norm"], cfg.rms_norm_eps, head_major)
+    if not head_major:
+        q = apply_rope(q, cos, sin, positions=positions)
+        k = apply_rope(k, cos, sin, positions=positions)
+        return q, k, v
+    assert positions is None, "head-major RoPE runs at positions 0..S-1"
+    from jax.ad_checkpoint import checkpoint_name
+
+    # post-rope q/k and v are named so the flash remat policies can save
+    # exactly these — backward then reads them instead of re-deriving
+    # qkv-matmul + rope per layer (and the "flash_min" policy saves ONLY
+    # named residuals: the pre-rope wq/wk outputs dots_no_batch would keep
+    # are redundant next to rope_q/k)
+    v = checkpoint_name(v, "attn_v")
+    q = checkpoint_name(apply_rope_bhsd(q, cos, sin), "rope_q")
+    k = checkpoint_name(apply_rope_bhsd(k, cos, sin), "rope_k")
+    return q, k, v
 
 
 def make_forward(
@@ -495,34 +629,16 @@ def make_forward(
         return constrain(x, rules, *axes, mesh=mesh)
 
     def layer_step(x, lp):
-        h = rms_norm(x, lp["attn_norm"])
+        q, k, v = _qkv(x, lp, cfg, cos, sin, head_major=head_major)
         if head_major:
-            from jax.ad_checkpoint import checkpoint_name
-
-            q = jnp.einsum("bse,ehd->bhsd", h, lp["wq"].astype(h.dtype))
-            k = jnp.einsum("bse,ekd->bksd", h, lp["wk"].astype(h.dtype))
-            v = jnp.einsum("bse,ekd->bksd", h, lp["wv"].astype(h.dtype))
-            # post-rope q/k and v are named so the flash remat policies can
-            # save exactly these — backward then reads them instead of
-            # re-deriving qkv-matmul + rope per layer (and the "flash_min"
-            # policy saves ONLY named residuals: the pre-rope wq/wk outputs
-            # dots_no_batch would keep are redundant next to rope_q/k)
-            v = checkpoint_name(v, "attn_v")
-            q = checkpoint_name(apply_rope_bhsd(q, cos, sin), "rope_q")
-            k = checkpoint_name(apply_rope_bhsd(k, cos, sin), "rope_k")
             q = _constrain(q, "batch", "heads", "seq", "head_dim")
             attn = attend(q, k, v)
-            x = x + jnp.einsum("bhsd,hde->bse", attn, lp["wo"].astype(h.dtype))
+            x = x + jnp.einsum("bhsd,hde->bse", attn, lp["wo"].astype(x.dtype))
         else:
-            q = jnp.einsum("bse,ehd->bshd", h, lp["wq"].astype(h.dtype))
-            k = jnp.einsum("bse,ekd->bskd", h, lp["wk"].astype(h.dtype))
-            v = jnp.einsum("bse,ekd->bskd", h, lp["wv"].astype(h.dtype))
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
             q = _constrain(q, "batch", "seq", "heads", "head_dim")
             attn = attend(q, k, v)
-            x = x + jnp.einsum("bshd,hde->bse", attn, lp["wo"].astype(h.dtype))
-        h2 = rms_norm(x, lp["mlp_norm"])
+            x = x + jnp.einsum("bshd,hde->bse", attn, lp["wo"].astype(x.dtype))
+        h2 = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
         x = x + _mlp(h2, lp, cfg, _constrain)
         x = _constrain(x, "batch", "seq", "embed")
         return x, None
@@ -601,7 +717,7 @@ def make_forward(
         x = _constrain(x, "batch", "seq", "embed")
         params = _cast_matmul_params(cfg, params)
         x = _apply_layers(params, x)
-        x = rms_norm(x, params["final_norm"])
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
         unembed = params.get("unembed")
         if unembed is None:
             unembed = params["embed"].T
@@ -797,12 +913,14 @@ def make_paged_decoder(
 
     paged_decode_step(params, pool, tables[B,Nmax], tokens[B],
                       positions[B], write_phys[B], write_off[B], key)
-        -> (next_tokens[B], logits[B,V], pool)
+        -> (next_tokens[B], logits[B,V], pool, moe_hottest)
       One cached decode step for every slot: the new K/V is written at the
       host-resolved (physical block, offset) pair — inactive slots route to
       the null block — and attention gathers each slot's logical sequence
       via its block table. ONE compiled shape per (B, Nmax) regardless of
-      live sequence lengths or block-table contents.
+      live sequence lengths or block-table contents. `moe_hottest` is None
+      without experts; with them, the load of the step's fullest expert
+      (pairs routed to it by the live slots), summed over the layers.
 
     paged_verify_step(params, pool, tables[B,Nmax], tokens[B,K1],
                       positions[B], draft_len[B], write_phys[B,K1],
@@ -1145,12 +1263,7 @@ def make_paged_decoder(
         def layer_fn(carry, per_layer):
             x, kc, vc, ksc, vsc = carry
             lp, l = per_layer
-            h = rms_norm(x, lp["attn_norm"])
-            q = jnp.einsum("bse,ehd->bshd", h, lp["wq"])
-            k = jnp.einsum("bse,ekd->bskd", h, lp["wk"])
-            v = jnp.einsum("bse,ekd->bskd", h, lp["wv"])
-            q = apply_rope(q, cos, sin, positions=qpos[None])
-            k = apply_rope(k, cos, sin, positions=qpos[None])
+            q, k, v = _qkv(x, lp, cfg, cos, sin, positions=qpos[None])
             q = _constrain(q, "batch", "seq", "heads", "head_dim")
             # write the suffix K/V first — suffix keys are then read back
             # from the pool, so cache content is authoritative either way
@@ -1178,7 +1291,7 @@ def make_paged_decoder(
                     vw = _gather_window(vc, vsc, l, window[None])
                 attn = _cached_attend(q, kw, vw, kmask, scale, n_rep)
             x = x + jnp.einsum("bshd,hde->bse", attn, lp["wo"])
-            h2 = rms_norm(x, lp["mlp_norm"])
+            h2 = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
             x = x + _mlp(h2, lp, cfg, _constrain)
             x = _constrain(x, "batch", "seq", "embed")
             return (x, kc, vc, ksc, vsc), None
@@ -1186,7 +1299,7 @@ def make_paged_decoder(
         (x, *leaves), _ = lax.scan(
             layer_fn, (x,) + _pool_leaves(pool), (params["layers"], layer_ids)
         )
-        x = rms_norm(x, params["final_norm"])
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
         x_last = x[0, jnp.maximum(length - 1, 0)][None]
         logits = jnp.einsum("be,ev->bv", x_last, _unembed_matrix(cfg, params))
         logits = _constrain(logits, "batch", "vocab")
@@ -1240,12 +1353,8 @@ def make_paged_decoder(
         def layer_fn(carry, per_layer):
             x, kc, vc, ksc, vsc = carry
             lp, l = per_layer
-            h = rms_norm(x, lp["attn_norm"])
-            q = jnp.einsum("bse,ehd->bshd", h, lp["wq"])  # [B,1,H,D]
-            k = jnp.einsum("bse,ekd->bskd", h, lp["wk"])  # [B,1,KV,D]
-            v = jnp.einsum("bse,ekd->bskd", h, lp["wv"])
-            q = apply_rope(q, cos, sin, positions=pos2)
-            k = apply_rope(k, cos, sin, positions=pos2)
+            # q [B,1,H,D]; k, v [B,1,KV,D]
+            q, k, v = _qkv(x, lp, cfg, cos, sin, positions=pos2)
             if quant:
                 kc, ksc = _write_token_quant(kc, ksc, l, k[:, 0])
                 vc, vsc = _write_token_quant(vc, vsc, l, v[:, 0])
@@ -1267,18 +1376,25 @@ def make_paged_decoder(
                 vw = _gather_window(vc, vsc, l, tables)
                 attn = _cached_attend(q, kw, vw, kmask, scale, n_rep)
             x = x + jnp.einsum("bshd,hde->bse", attn, lp["wo"])
-            h2 = rms_norm(x, lp["mlp_norm"])
-            x = x + _mlp(h2, lp, cfg, _constrain)
-            x = _constrain(x, "batch", "seq", "embed")
-            return (x, kc, vc, ksc, vsc), None
+            h2 = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
+            if cfg.n_experts:
+                # the step's fullest expert among the live slots (an
+                # inactive slot writes to the null block, 0)
+                y, idx = _moe(h2, lp, cfg, _constrain)
+                hottest = _fullest_expert(idx, write_phys > 0, cfg.n_experts)
+            else:
+                y, hottest = _mlp(h2, lp, cfg, _constrain), None
+            x = _constrain(x + y, "batch", "seq", "embed")
+            return (x, kc, vc, ksc, vsc), hottest
 
-        (x, *leaves), _ = lax.scan(
+        (x, *leaves), hottest = lax.scan(
             layer_fn, (x,) + _pool_leaves(pool), (params["layers"], layer_ids)
         )
-        x = rms_norm(x, params["final_norm"])
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
         logits = jnp.einsum("be,ev->bv", x[:, 0], _unembed_matrix(cfg, params))
         logits = _constrain(logits, "batch", "vocab")
-        return _sample(logits, key), logits, _pool_dict(*leaves)
+        moe_hottest = None if hottest is None else jnp.sum(hottest)
+        return _sample(logits, key), logits, _pool_dict(*leaves), moe_hottest
 
     def _rmw_commit_quant(kc, ksc, knew, wp_i, wo_i):
         """[L]-batched twin of the decode step's `_write_token_quant`:
@@ -1347,12 +1463,7 @@ def make_paged_decoder(
 
         def layer_fn(x, per_layer):
             lp, l = per_layer
-            h = rms_norm(x, lp["attn_norm"])
-            q = jnp.einsum("bse,ehd->bshd", h, lp["wq"])
-            k = jnp.einsum("bse,ekd->bskd", h, lp["wk"])
-            v = jnp.einsum("bse,ekd->bskd", h, lp["wv"])
-            q = apply_rope(q, cos, sin, positions=rope_pos)
-            k = apply_rope(k, cos, sin, positions=rope_pos)
+            q, k, v = _qkv(x, lp, cfg, cos, sin, positions=rope_pos)
             q = _constrain(q, "batch", "seq", "heads", "head_dim")
             if attention_impl == "fused":
                 # multi-query fused walk over the cached window (kv_len =
@@ -1372,13 +1483,13 @@ def make_paged_decoder(
                 vcat = jnp.concatenate([vw, v.astype(vw.dtype)], axis=1)
                 attn = _cached_attend(q, kcat, vcat, mask, scale, n_rep)
             x = x + jnp.einsum("bshd,hde->bse", attn, lp["wo"])
-            h2 = rms_norm(x, lp["mlp_norm"])
+            h2 = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
             x = x + _mlp(h2, lp, cfg, _constrain)
             x = _constrain(x, "batch", "seq", "embed")
             return x, (k, v)
 
         x, (ks, vs) = lax.scan(layer_fn, x, (params["layers"], layer_ids))
-        x = rms_norm(x, params["final_norm"])
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
         logits = jnp.einsum("bse,ev->bsv", x, _unembed_matrix(cfg, params))
         logits = _constrain(logits, "batch", "seq", "vocab")
         out = _sample(logits, key)  # [B, K1]
@@ -1459,22 +1570,17 @@ def make_decoder(
         x = _constrain(x, "batch", "seq", "embed")
 
         def layer_prefill(x, lp):
-            h = rms_norm(x, lp["attn_norm"])
-            q = jnp.einsum("bse,ehd->bshd", h, lp["wq"])
-            k = jnp.einsum("bse,ekd->bskd", h, lp["wk"])
-            v = jnp.einsum("bse,ekd->bskd", h, lp["wv"])
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
+            q, k, v = _qkv(x, lp, cfg, cos, sin)
             q = _constrain(q, "batch", "seq", "heads", "head_dim")
             attn = causal_attention(q, k, v)
             x = x + jnp.einsum("bshd,hde->bse", attn, lp["wo"])
-            h2 = rms_norm(x, lp["mlp_norm"])
+            h2 = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
             x = x + _mlp(h2, lp, cfg, _constrain)
             x = _constrain(x, "batch", "seq", "embed")
             return x, (k, v)
 
         x, (ks, vs) = lax.scan(layer_prefill, x, params["layers"])
-        x = rms_norm(x, params["final_norm"])
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
         # logits only at each sequence's last real token (padding beyond
         # lengths-1 produces garbage states that are never read)
         B = tokens.shape[0]
@@ -1503,18 +1609,14 @@ def make_decoder(
 
         def layer_decode(x, per_layer):
             lp, kc, vc = per_layer
-            h = rms_norm(x, lp["attn_norm"])
-            q = jnp.einsum("bse,ehd->bshd", h, lp["wq"])  # [B,1,H,D]
-            k = jnp.einsum("bse,ekd->bskd", h, lp["wk"])  # [B,1,KV,D]
-            v = jnp.einsum("bse,ekd->bskd", h, lp["wv"])
-            q = apply_rope(q, cos, sin, positions=pos2)
-            k = apply_rope(k, cos, sin, positions=pos2)
+            # q [B,1,H,D]; k, v [B,1,KV,D]
+            q, k, v = _qkv(x, lp, cfg, cos, sin, positions=pos2)
             # write this token's K/V at each slot's own position
             kc = kc.at[rows, pos2].set(k.astype(kc.dtype))
             vc = vc.at[rows, pos2].set(v.astype(vc.dtype))
             attn = _cached_attend(q, kc, vc, kvalid[:, None, :], scale, n_rep)
             x = x + jnp.einsum("bshd,hde->bse", attn, lp["wo"])
-            h2 = rms_norm(x, lp["mlp_norm"])
+            h2 = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
             x = x + _mlp(h2, lp, cfg, _constrain)
             x = _constrain(x, "batch", "seq", "embed")
             return x, (kc, vc)
@@ -1522,7 +1624,7 @@ def make_decoder(
         x, (k_new, v_new) = lax.scan(
             layer_decode, x, (params["layers"], cache["k"], cache["v"])
         )
-        x = rms_norm(x, params["final_norm"])
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
         logits = jnp.einsum("be,ev->bv", x[:, 0], _unembed_matrix(cfg, params))
         logits = _constrain(logits, "batch", "vocab")
         return _sample(logits, key), logits, {"k": k_new, "v": v_new}

@@ -114,19 +114,20 @@ def test_flash_compiles_per_shard_on_four_chips(topo):
     _compile(per_shard, q, q, q)
 
 
-def _paged(one_chip, *, q_len, h, kv, d, batch=8, int8=False, **kw):
+def _paged(one_chip, *, q_len, h, kv, d, batch=8, int8=False,
+           blocks=POOL_BLOCKS, table=TABLE_BLOCKS, **kw):
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     pool = sds(
-        (POOL_BLOCKS, BLOCK_TOKENS, kv, d), jnp.int8 if int8 else jnp.bfloat16
+        (blocks, BLOCK_TOKENS, kv, d), jnp.int8 if int8 else jnp.bfloat16
     )
     args = [
         sds((batch, q_len, h, d), jnp.bfloat16), pool, pool,
-        sds((batch, TABLE_BLOCKS), jnp.int32), sds((batch,), jnp.int32),
+        sds((batch, table), jnp.int32), sds((batch,), jnp.int32),
     ]
     if int8:
-        scale = sds((POOL_BLOCKS, kv), jnp.float32)
+        scale = sds((blocks, kv), jnp.float32)
         args += [scale, scale]
 
     def fn(q, k_pool, v_pool, tables, positions, k_scale=None, v_scale=None):
@@ -155,6 +156,13 @@ def test_paged_kernel_partial_out_compiles(one_chip):
 
 def test_paged_kernel_gqa_compiles(one_chip):
     _paged(one_chip, q_len=1, h=32, kv=8, d=128)
+
+
+@pytest.mark.parametrize("q_len", [1, 256], ids=["decode", "prefill"])
+def test_paged_kernel_olmoe_heads_compile(one_chip, q_len):
+    # OLMoE: 16 q heads on 16 kv heads (n_rep 1), at its own pool
+    _paged(one_chip, q_len=q_len, h=16, kv=16, d=128, batch=32, blocks=2049,
+           table=64)
 
 
 @pytest.mark.parametrize("q_len", [1, 5, 256], ids=["decode", "verify", "prefill"])
@@ -197,8 +205,20 @@ def _mistral_block(n_layers=2):
     )
 
 
+def _olmoe_block(n_layers=2):
+    """OLMoE-1B-7B's layer as benchmark/blocks/olmoe.py maps it."""
+    from ray_tpu.models.transformer import TransformerConfig
+
+    return TransformerConfig(
+        vocab_size=50304, d_model=2048, n_layers=n_layers, n_heads=16,
+        n_kv_heads=16, d_head=128, d_ff=1024, max_seq_len=4096,
+        n_experts=64, top_k=8, moe_capacity_factor=None,
+        moe_renormalize=False, qk_norm=True, rms_norm_eps=1e-5,
+    )
+
+
 def _compile_paged_program(one_chip, monkeypatch, program, impl, int8,
-                           num_blocks):
+                           num_blocks, cfg=None):
     """One of make_paged_decoder's programs for the described chip, from
     shapes alone."""
     from ray_tpu.models.transformer import (
@@ -207,7 +227,7 @@ def _compile_paged_program(one_chip, monkeypatch, program, impl, int8,
 
     # the fused path asks the backend whether to lower through Mosaic
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg = _mistral_block()
+    cfg = cfg or _mistral_block()
     kv_dtype = jnp.int8 if int8 else None
     prefill, decode, verify, _ = make_paged_decoder(
         cfg, block_tokens=BLOCK_TOKENS, kv_dtype=kv_dtype,
@@ -264,12 +284,13 @@ def _pool_sized_moves(hlo_text, cfg, num_blocks):
 
 
 def _assert_nothing_scales_with_the_pool(one_chip, monkeypatch, program,
-                                         impl, int8):
-    small, large = PAGED_POOLS
+                                         impl, int8, cfg=None,
+                                         pools=PAGED_POOLS):
+    small, large = pools
     temps = []
-    for num_blocks in PAGED_POOLS:
+    for num_blocks in pools:
         cfg, compiled = _compile_paged_program(
-            one_chip, monkeypatch, program, impl, int8, num_blocks)
+            one_chip, monkeypatch, program, impl, int8, num_blocks, cfg)
         moves = _pool_sized_moves(compiled.as_text(), cfg, num_blocks)
         assert not moves, f"{num_blocks} blocks: {moves}"
         mem = compiled.memory_analysis()
@@ -300,3 +321,38 @@ def test_paged_decode_program_moves_no_pool(one_chip, monkeypatch, impl, int8):
 def test_paged_prefill_and_verify_move_no_pool(one_chip, monkeypatch, program):
     _assert_nothing_scales_with_the_pool(
         one_chip, monkeypatch, program, "fused", False)
+
+
+# ---- the OLMoE geometry: dropless experts in the paged programs ----------
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_olmoe_paged_programs_move_no_pool(one_chip, monkeypatch, program):
+    # this block's step in the compiler's memory-space assignment (+537 MB
+    # of temporaries, whatever the pool holds) lies between 1036 and 2049
+    # blocks: the benchmark's pool and twice it are on its far side
+    _assert_nothing_scales_with_the_pool(
+        one_chip, monkeypatch, program, "fused", False, cfg=_olmoe_block(),
+        pools=(2049, 4144))
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_olmoe_experts_are_grouped_matmuls(one_chip, monkeypatch, program):
+    """Dropless routing compiles to the chip's grouped matmul, three a
+    layer, at the FLOPs of the routed pairs: no worst-case buffer of every
+    expert for every token ([64, N, d_model], or the [64, N, d_ff] behind
+    it) exists in either program."""
+    cfg, compiled = _compile_paged_program(
+        one_chip, monkeypatch, program, "fused", False, 2049,
+        cfg=_olmoe_block())
+    text = compiled.as_text()
+    assert len(re.findall(r"= \S+ custom-call\([^\n]*ragged_dot_tiling",
+                          text)) == 3
+    n = 32 if program == "decode" else 128
+    shapes = set(re.findall(r"= \w+\[([\d,]+)\]", text))
+    for width in (cfg.d_model, cfg.d_ff):
+        for dims in (f"{cfg.n_experts},{n},{width}",
+                     f"{n},{cfg.n_experts},{width}"):
+            assert dims not in shapes, dims
+    # every routed pair's row is there: N x top_k sorted rows of d_model
+    assert f"{n * cfg.top_k},{cfg.d_model}" in shapes

@@ -725,7 +725,7 @@ def test_int8_logits_within_tolerance(tiny_f32):
         )
         wp = np.array([table[21 // bt]], np.int32)
         wo = np.array([21 % bt], np.int32)
-        _, lg_d, pool = step(
+        _, lg_d, pool, _ = step(
             params, pool, table[None], toks, positions, wp, wo,
             _jax.random.PRNGKey(1),
         )

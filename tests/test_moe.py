@@ -33,6 +33,35 @@ def test_dispatch_matches_dense_oracle():
     )
 
 
+@pytest.mark.parametrize("renormalize", [True, False])
+def test_dropless_matches_dense_oracle(renormalize):
+    """No capacity set: every routed pair is computed, so the sorted
+    grouped-matmul path equals the oracle with nothing chosen to fit — with
+    the top-k weights renormalised and as the softmax gave them."""
+    kw = dict(dtype=jnp.float32, moe_renormalize=renormalize)
+    cfg_d = _cfg(4, "dense", **kw)
+    cfg_s = _cfg(4, "dispatch", moe_capacity_factor=None, **kw)
+    params = init_params(jax.random.PRNGKey(0), cfg_d)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, cfg_d.vocab_size)
+    np.testing.assert_allclose(
+        np.asarray(make_forward(cfg_d)(params, tokens), np.float32),
+        np.asarray(make_forward(cfg_s)(params, tokens), np.float32),
+        rtol=1e-4, atol=1e-4,
+    )
+
+
+def test_dropless_gradients_reach_router_and_experts():
+    from ray_tpu.models.transformer import make_loss_fn
+
+    cfg = _cfg(4, "dispatch", moe_capacity_factor=None, dtype=jnp.float32)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 17), 0, cfg.vocab_size)
+    grads = jax.grad(make_loss_fn(cfg))(params, {"tokens": tokens})
+    for name in ("router", "w_gate", "w_up", "w_down"):
+        g = np.asarray(grads["layers"][name])
+        assert np.isfinite(g).all() and np.abs(g).max() > 0, name
+
+
 def test_dispatch_flops_scale_with_top_k_not_n_experts():
     """Doubling n_experts at fixed top_k must NOT double MLP FLOPs."""
 

@@ -241,6 +241,46 @@ def test_prefill_span_covers_first_token_fetch(tmp_path):
     assert stats == {"slot": 0, "tokens": 9, "ctx": 0, "last": 1}
 
 
+# --------------------------------- (c2) the expert layer's two attributes
+
+
+def test_decode_span_carries_the_expert_load(tmp_path):
+    """A sparse-expert model: every `engine.decode` span carries
+    `moe_pairs` (live slots x top_k x layers) and `moe_hottest` (the load
+    of the step's fullest expert, summed over the layers), and
+    `engine.stats()` their sums; a dense model's span carries neither."""
+    cfg = dataclasses.replace(CONFIGS["tiny_moe"], max_seq_len=128,
+                              moe_capacity_factor=None)
+    eng = PagedDecodeEngine(cfg, max_batch_size=2, seed=0, block_tokens=8)
+    rng = np.random.default_rng(0)
+    for slot, n in ((0, 11), (1, 5)):
+        eng.admit(slot, {"tokens": rng.integers(1, cfg.vocab_size, size=n),
+                         "max_new_tokens": 8})
+    eng.step([0, 1])  # compiled outside the trace
+    with _Trace(tmp_path) as tr:
+        eng.step([0, 1])
+        eng.step([0])
+    both, one = (st for _, _, st in tr.spans("engine.decode"))
+    per_slot = cfg.top_k * cfg.n_layers
+    assert both["moe_pairs"] == 2 * per_slot and one["moe_pairs"] == per_slot
+    # one token's top_k experts differ: a layer's fullest expert holds one
+    # pair; two tokens share an expert or not: one or two pairs a layer
+    assert one["moe_hottest"] == cfg.n_layers
+    assert cfg.n_layers <= both["moe_hottest"] <= 2 * cfg.n_layers
+    stats = eng.stats()
+    assert stats["moe_pairs"] == 5 * per_slot
+    assert stats["moe_hottest"] >= 3 * cfg.n_layers
+
+    _, dense = _tiny_engine(None)
+    dense.admit(0, {"tokens": np.arange(1, 10), "max_new_tokens": 4})
+    dense.step([0])
+    with _Trace(tmp_path / "dense") as tr:
+        dense.step([0])
+    (_, _, st), = tr.spans("engine.decode")
+    assert "moe_pairs" not in st and "moe_hottest" not in st
+    assert dense.stats()["moe_pairs"] == 0
+
+
 # --------------------------------------------- (d) names on the device
 
 
@@ -272,6 +312,28 @@ def test_serving_programs_lower_under_stable_names(which):
         eng.admit(0, {"tokens": np.arange(1, 10), "max_new_tokens": 2})
     fn, args = _program_args(eng, which)
     assert f"module @jit_{which} " in fn.lower(*args).as_text()[:200]
+
+
+@pytest.mark.parametrize("capacity", [None, 1.25], ids=["dropless", "capacity"])
+@pytest.mark.parametrize("which", ["paged_prefill", "paged_decode"])
+def test_expert_layer_lowers_under_its_two_scopes(which, capacity):
+    """`moe.route` and `moe.experts` reach the operations' names in every
+    program: what benchmark/layer_metrics/moe_device_ms.py finds the expert
+    layer's device time by."""
+    cfg = dataclasses.replace(CONFIGS["tiny_moe"], max_seq_len=128,
+                              moe_capacity_factor=capacity)
+    eng = PagedDecodeEngine(cfg, max_batch_size=2, seed=0, block_tokens=8,
+                            prefix_cache=False, prefill_buckets=(16,))
+    if which == "paged_prefill":
+        eng.admit(0, {"tokens": np.arange(1, 10), "max_new_tokens": 2})
+    fn, args = _program_args(eng, which)
+    text = fn.lower(*args).as_text(debug_info=True)
+    assert "moe.route/" in text and "moe.experts/" in text
+    _, dense = _tiny_engine(False, prefix_cache=False, prefill_buckets=(16,))
+    if which == "paged_prefill":
+        dense.admit(0, {"tokens": np.arange(1, 10), "max_new_tokens": 2})
+    fn, args = _program_args(dense, which)
+    assert "moe." not in fn.lower(*args).as_text(debug_info=True)
 
 
 def _flash_fwd(q, k, v):

@@ -190,3 +190,12 @@ def test_moe_decode_matches_forward():
     cfg = dataclasses.replace(_f32("tiny_moe"), moe_capacity_factor=4.0)
     params = init_params(jax.random.PRNGKey(0), cfg)
     _assert_decode_matches(cfg, params, b=2, prefix=6, total=14, tol=2e-3)
+
+
+def test_moe_decode_matches_forward_dropless():
+    """The same contract with no capacity set: the dropless path computes
+    every routed pair at any N, so prefill (N = B*prefix), each decode step
+    (N = B) and the full forward agree without a factor chosen to fit."""
+    cfg = dataclasses.replace(_f32("tiny_moe"), moe_capacity_factor=None)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    _assert_decode_matches(cfg, params, b=2, prefix=6, total=14, tol=2e-3)
