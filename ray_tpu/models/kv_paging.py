@@ -71,6 +71,7 @@ from .transformer import (
     init_params,
     make_paged_decoder,
     paged_kv_block_bytes,
+    serving_params,
 )
 
 logger = logging.getLogger(__name__)
@@ -269,7 +270,17 @@ class PagedDecodeEngine:
     """Block-granular KV-cache decode engine (module docstring has the
     architecture). Drop-in for `DecodeEngine` under ContinuousBatcher —
     same admit/step/release contract — plus paging APIs the batcher
-    discovers by duck-typing: can_admit, take_preempted."""
+    discovers by duck-typing: can_admit, take_preempted.
+
+    What the engine holds (`self.params`): the matmul weights, `embed` and
+    `unembed` in `cfg.dtype`, the norm scales in float32 —
+    `transformer.serving_params` of whatever tree it is given, at
+    construction and at every `set_params`. A float32 tree (a trainer's
+    masters, `init_params`) is cast ONCE on the device when the engine
+    takes it, not inside every prefill and decode step; the caller's tree
+    is left alone (a tree the engine built itself from `seed` is freed
+    leaf by leaf as it is cast). `stats()` reports `param_bytes` and
+    `param_dtype` of the held tree."""
 
     def __init__(
         self,
@@ -563,9 +574,11 @@ class PagedDecodeEngine:
                 num_blocks = -(-num_blocks // m) * m
         self.num_blocks = int(num_blocks)
 
-        self.params = (
-            params if params is not None
-            else init_params(jax.random.PRNGKey(seed), cfg)
+        # a tree built here is nobody else's: its f32 leaves go as they are cast
+        own = params is None
+        self.params = serving_params(
+            cfg, init_params(jax.random.PRNGKey(seed), cfg) if own else params,
+            consume=own,
         )
         # swap-time device_put (serve/weight_swap.py) re-distributes a
         # pulled host tree by THIS engine's partition rules
@@ -1431,6 +1444,12 @@ class PagedDecodeEngine:
         serve/weight_swap.py routes here via ContinuousBatcher.run_on_loop;
         loop thread only, like admit/step). Returns the new version.
 
+        `params` is cast as at construction (`serving_params`: matmul
+        weights, `embed`, `unembed` to `cfg.dtype`, once, on the device),
+        so a replica swapped to a learner's float32 tree holds — and
+        computes — bit for bit what a fresh engine built from that tree
+        would. The caller's tree is not touched.
+
         Swap semantics are RECOMPUTE, not splice: every live slot is
         preempted (full history parked; the batcher readmits it and
         prompt + generated-so-far prefills under the NEW weights) and the
@@ -1454,7 +1473,7 @@ class PagedDecodeEngine:
         flushed = (
             self.prefix_cache.flush() if self.prefix_cache is not None else 0
         )
-        self.params = params
+        self.params = serving_params(self.cfg, params)
         self.weight_version = (
             int(version) if version is not None else self.weight_version + 1
         )
@@ -1465,7 +1484,7 @@ class PagedDecodeEngine:
             refresh = getattr(drafter, "refresh", None)
             if refresh is not None:
                 try:
-                    refresh(params)
+                    refresh(self.params)
                 except Exception:
                     # drafter faults degrade to 'no draft' (the _propose
                     # contract) — they must never fail the swap
@@ -1645,6 +1664,8 @@ class PagedDecodeEngine:
         return need * bt
 
     def stats(self) -> Dict[str, Any]:
+        import jax
+
         used = self.allocator.num_usable - self.allocator.num_free
         mem = self._device.memory_stats() or {}  # None on the CPU backend
         return {
@@ -1679,6 +1700,12 @@ class PagedDecodeEngine:
             "device_bytes_in_use": mem.get("bytes_in_use"),
             "device_peak_bytes": mem.get("peak_bytes_in_use"),
             "device_bytes_limit": mem.get("bytes_limit"),
+            # the held tree (serving_params): its bytes, and the dtype of
+            # its matmul leaves
+            "param_bytes": sum(
+                int(leaf.nbytes) for leaf in jax.tree_util.tree_leaves(self.params)
+            ),
+            "param_dtype": np.dtype(self.params["layers"]["wq"].dtype).name,
             "attention_chunk_blocks": self.chunk_blocks,
             "kv_block_bytes": self.kv_block_bytes,
             # true pool HBM: counts the reserved null block too, so this

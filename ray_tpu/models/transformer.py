@@ -474,15 +474,52 @@ _MATMUL_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "router")
 
 
 def _cast_matmul_params(cfg: TransformerConfig, params):
-    """Cast the stacked matmul weights to compute dtype ONCE — otherwise
-    XLA re-converts the f32 masters on every scan iteration and again per
-    remat pass (~5% of step time on the 125M bench); norm scales stay f32
-    (rms_norm computes in f32 anyway)."""
+    """Cast the stacked matmul weights to compute dtype ONCE per program —
+    the trainer's case: it keeps f32 masters, and without this XLA
+    re-converts them on every scan iteration and again per remat pass (~5%
+    of step time on the 125M bench); norm scales stay f32 (rms_norm
+    computes in f32 anyway). On a tree that `serving_params` already cast
+    (what a serving replica holds) every `astype` here is the identity and
+    compiles to nothing."""
     layers = dict(params["layers"])
     for key in _MATMUL_KEYS:
         if key in layers:
             layers[key] = layers[key].astype(cfg.dtype)
     return {**params, "layers": layers}
+
+
+def serving_params(cfg: TransformerConfig, params, *, consume: bool = False):
+    """The tree a serving replica holds: exactly the leaves the decode
+    programs cast — the stacked matmul weights under `layers`, `embed` and
+    `unembed` — in `cfg.dtype`, every norm scale as it came (float32). The
+    cast is the convert the programs would run, done ONCE per tree instead
+    of in every prefill and every decode step, on the device and leaf by
+    leaf: a leaf keeps its sharding, and one already in `cfg.dtype` is
+    returned as it is (no copy), so the call is idempotent.
+
+    `consume=True` is for a caller that owns `params` and is done with it:
+    each source leaf is deleted as soon as its copy is ready, so no second
+    whole tree ever exists on the device."""
+    dtype = jnp.dtype(cfg.dtype)
+
+    def held(leaf):
+        if leaf.dtype == dtype:
+            return leaf
+        leaf = jnp.asarray(leaf)
+        out = leaf.astype(dtype)
+        if consume:
+            out.block_until_ready()
+            leaf.delete()
+        return out
+
+    layers = dict(params["layers"])
+    for key in _MATMUL_KEYS:
+        if key in layers:
+            layers[key] = held(layers[key])
+    out = {**params, "layers": layers, "embed": held(params["embed"])}
+    if "unembed" in params:
+        out["unembed"] = held(params["unembed"])
+    return out
 
 
 def _mlp(h, lp, cfg: TransformerConfig, constrain_fn):

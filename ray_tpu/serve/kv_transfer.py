@@ -392,8 +392,11 @@ class KVTransferManager:
 class KVGenerationServer:
     """Deployment-ready paged generation server with the cluster-wide KV
     plane wired in. Builds a PagedDecodeEngine (weights re-derived from
-    `weights_seed`, so every replica holds identical parameters) + a
-    ContinuousBatcher + a KVTransferManager, and exposes:
+    `weights_seed`, so every replica holds identical parameters: the f32
+    draw of `init_params` cast once by `serving_params`, as the engine
+    holds any tree — matmul weights, `embed`, `unembed` in `cfg.dtype`,
+    norm scales float32 — and the f32 leaves freed before the pool is
+    allocated) + a ContinuousBatcher + a KVTransferManager, and exposes:
 
       generate(tokens, max_new_tokens)  greedy generation; pulls the
           prompt's prefix from a peer (monolithic role) or from the
@@ -417,7 +420,7 @@ class KVGenerationServer:
         import jax
 
         from ray_tpu.models.kv_paging import PagedDecodeEngine
-        from ray_tpu.models.transformer import init_params
+        from ray_tpu.models.transformer import init_params, serving_params
 
         from .batching import ContinuousBatcher
 
@@ -425,7 +428,12 @@ class KVGenerationServer:
             raise ValueError(f"unknown role {role!r}")
         self.role = role
         self.deployment = deployment
-        params = init_params(jax.random.PRNGKey(int(weights_seed)), cfg)
+        # the tree is this server's own, so the f32 leaves from the seed go
+        # as they are cast, before the engine allocates its pool
+        params = serving_params(
+            cfg, init_params(jax.random.PRNGKey(int(weights_seed)), cfg),
+            consume=True,
+        )
         kw = dict(engine_kwargs or {})
         self.engine = PagedDecodeEngine(cfg, params, **kw)
         self.batcher = ContinuousBatcher(self.engine)

@@ -218,11 +218,12 @@ def _olmoe_block(n_layers=2):
 
 
 def _compile_paged_program(one_chip, monkeypatch, program, impl, int8,
-                           num_blocks, cfg=None):
+                           num_blocks, cfg=None, tree="f32"):
     """One of make_paged_decoder's programs for the described chip, from
-    shapes alone."""
+    shapes alone. `tree` "f32" hands it init_params' float32 tree, "held"
+    the tree a PagedDecodeEngine holds (serving_params of it)."""
     from ray_tpu.models.transformer import (
-        init_paged_kv_cache, init_params, make_paged_decoder,
+        init_paged_kv_cache, init_params, make_paged_decoder, serving_params,
     )
 
     # the fused path asks the backend whether to lower through Mosaic
@@ -243,8 +244,11 @@ def _compile_paged_program(one_chip, monkeypatch, program, impl, int8,
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
 
-    params = on_chip(
-        jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    def tree_of():
+        params = init_params(jax.random.PRNGKey(0), cfg)
+        return serving_params(cfg, params) if tree == "held" else params
+
+    params = on_chip(jax.eval_shape(tree_of))
     pool = on_chip(jax.eval_shape(
         lambda: init_paged_kv_cache(cfg, num_blocks, BLOCK_TOKENS,
                                     dtype=kv_dtype)))
@@ -267,16 +271,19 @@ def _compile_paged_program(one_chip, monkeypatch, program, impl, int8,
     return cfg, compiled
 
 
+# (ROOT?, name, result dtype, result dims, op, the rest of the line)
+_HLO_INSTRUCTION = re.compile(
+    r"^\s*(ROOT )?%(\S+) = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\(([^\n]*)", re.M)
+
+
 def _pool_sized_moves(hlo_text, cfg, num_blocks):
     """Instructions of the optimized HLO that copy, slice out or write back
     a whole K/V leaf or one layer of it, by their result shape."""
-    layer = f"{num_blocks},{BLOCK_TOKENS},{cfg.n_kv_heads},{cfg.d_head}]"
-    inst = re.compile(
-        r"^\s*(?:ROOT )?(%\S+) = \w+\[([\d,]*\])\S* ([\w\-]+)\(", re.M)
+    layer = f"{num_blocks},{BLOCK_TOKENS},{cfg.n_kv_heads},{cfg.d_head}"
     moves = ("copy", "dynamic-slice", "dynamic-update-slice")
     return [
-        f"{name}: {op} -> [{dims}"
-        for name, dims, op in inst.findall(hlo_text)
+        f"%{name}: {op} -> [{dims}]"
+        for _, name, _, dims, op, _ in _HLO_INSTRUCTION.findall(hlo_text)
         if dims.endswith(layer) and (
             op.startswith(moves)
             or (op == "fusion" and any(m in name for m in moves)))
@@ -285,12 +292,13 @@ def _pool_sized_moves(hlo_text, cfg, num_blocks):
 
 def _assert_nothing_scales_with_the_pool(one_chip, monkeypatch, program,
                                          impl, int8, cfg=None,
-                                         pools=PAGED_POOLS):
+                                         pools=PAGED_POOLS, tree="f32"):
     small, large = pools
     temps = []
     for num_blocks in pools:
         cfg, compiled = _compile_paged_program(
-            one_chip, monkeypatch, program, impl, int8, num_blocks, cfg)
+            one_chip, monkeypatch, program, impl, int8, num_blocks, cfg,
+            tree=tree)
         moves = _pool_sized_moves(compiled.as_text(), cfg, num_blocks)
         assert not moves, f"{num_blocks} blocks: {moves}"
         mem = compiled.memory_analysis()
@@ -311,40 +319,53 @@ def _assert_nothing_scales_with_the_pool(one_chip, monkeypatch, program,
     assert temps[1] - temps[0] < allowed, temps
 
 
+# every counter below holds for the float32 tree a caller may still hand the
+# programs and for the tree an engine holds (serving_params of it)
+TREES = pytest.mark.parametrize("tree", ["f32", "held"])
+
+
+@TREES
 @pytest.mark.parametrize("impl,int8", PAGED_VARIANTS)
-def test_paged_decode_program_moves_no_pool(one_chip, monkeypatch, impl, int8):
+def test_paged_decode_program_moves_no_pool(one_chip, monkeypatch, impl, int8,
+                                            tree):
     _assert_nothing_scales_with_the_pool(
-        one_chip, monkeypatch, "decode", impl, int8)
+        one_chip, monkeypatch, "decode", impl, int8, tree=tree)
 
 
+@TREES
 @pytest.mark.parametrize("program", ["prefill", "verify"])
-def test_paged_prefill_and_verify_move_no_pool(one_chip, monkeypatch, program):
+def test_paged_prefill_and_verify_move_no_pool(one_chip, monkeypatch, program,
+                                               tree):
     _assert_nothing_scales_with_the_pool(
-        one_chip, monkeypatch, program, "fused", False)
+        one_chip, monkeypatch, program, "fused", False, tree=tree)
 
 
 # ---- the OLMoE geometry: dropless experts in the paged programs ----------
 
 
+@TREES
 @pytest.mark.parametrize("program", ["decode", "prefill"])
-def test_olmoe_paged_programs_move_no_pool(one_chip, monkeypatch, program):
+def test_olmoe_paged_programs_move_no_pool(one_chip, monkeypatch, program,
+                                           tree):
     # this block's step in the compiler's memory-space assignment (+537 MB
     # of temporaries, whatever the pool holds) lies between 1036 and 2049
     # blocks: the benchmark's pool and twice it are on its far side
     _assert_nothing_scales_with_the_pool(
         one_chip, monkeypatch, program, "fused", False, cfg=_olmoe_block(),
-        pools=(2049, 4144))
+        pools=(2049, 4144), tree=tree)
 
 
+@TREES
 @pytest.mark.parametrize("program", ["decode", "prefill"])
-def test_olmoe_experts_are_grouped_matmuls(one_chip, monkeypatch, program):
+def test_olmoe_experts_are_grouped_matmuls(one_chip, monkeypatch, program,
+                                           tree):
     """Dropless routing compiles to the chip's grouped matmul, three a
     layer, at the FLOPs of the routed pairs: no worst-case buffer of every
     expert for every token ([64, N, d_model], or the [64, N, d_ff] behind
     it) exists in either program."""
     cfg, compiled = _compile_paged_program(
         one_chip, monkeypatch, program, "fused", False, 2049,
-        cfg=_olmoe_block())
+        cfg=_olmoe_block(), tree=tree)
     text = compiled.as_text()
     assert len(re.findall(r"= \S+ custom-call\([^\n]*ragged_dot_tiling",
                           text)) == 3
@@ -356,3 +377,89 @@ def test_olmoe_experts_are_grouped_matmuls(one_chip, monkeypatch, program):
             assert dims not in shapes, dims
     # every routed pair's row is there: N x top_k sorted rows of d_model
     assert f"{n * cfg.top_k},{cfg.d_model}" in shapes
+
+
+# ---- the held tree: no weight is cast inside a paged program -------------
+#
+# A PagedDecodeEngine holds serving_params of its tree: matmul weights,
+# embed and unembed already in the compute dtype. Handed that tree, the
+# programs' own astype calls compile to nothing; handed init_params'
+# float32 tree they cast every weight in every call — as a `convert`, as a
+# fusion whose root is one, or as the chip's `copy` that changes type and
+# layout at once (wq / wk / wv) — and keep the bfloat16 copies as
+# temporaries.
+
+_HLO_COMPUTATION = re.compile(
+    r"^(?:ENTRY )?%(\S+) \([^\n]*\{\n(.*?)^\}", re.M | re.S)
+
+
+def _weight_casts(hlo_text, params):
+    """Instructions of the optimized HLO that write a whole weight leaf
+    (a stacked matmul leaf, embed or unembed, by its dims) in bfloat16
+    from float32. What sits inside a fusion's body is not written
+    anywhere: there only the root counts, as the fusion's own result."""
+    from ray_tpu.models.transformer import _MATMUL_KEYS
+
+    leaves = [params["layers"][k] for k in _MATMUL_KEYS
+              if k in params["layers"]]
+    leaves += [params["embed"], params["unembed"]]
+    whole = {",".join(map(str, leaf.shape)) for leaf in leaves}
+    bodies = {name: list(_HLO_INSTRUCTION.finditer(body))
+              for name, body in _HLO_COMPUTATION.findall(hlo_text)}
+    root_op = {name: next((m.group(5) for m in insts if m.group(1)), None)
+               for name, insts in bodies.items()}
+    dtype_of = {m.group(2): m.group(3)
+                for insts in bodies.values() for m in insts}
+    fused = set(re.findall(r" fusion\([^\n]*calls=%([^\s,]+)", hlo_text))
+    found = []
+    for name, insts in bodies.items():
+        if name in fused:
+            continue
+        for m in insts:
+            _, inst, dtype, dims, op, rest = m.groups()
+            if dtype != "bf16" or dims not in whole:
+                continue
+            operand = re.match(r"%([^\s,)]+)", rest)
+            source = dtype_of.get(operand.group(1)) if operand else None
+            callee = re.search(r"calls=%([^\s,]+)", rest)
+            if (op == "convert"
+                    or (op == "copy" and source == "f32")
+                    or (op == "fusion" and callee
+                        and root_op.get(callee.group(1)) == "convert")):
+                found.append(f"%{inst}: {op} -> {dtype}[{dims}]")
+    return found
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("block", ["mistral", "olmoe"])
+def test_held_tree_is_cast_in_no_paged_program(one_chip, monkeypatch, block,
+                                               program):
+    from ray_tpu.models.transformer import _MATMUL_KEYS, init_params
+
+    cfg = _mistral_block() if block == "mistral" else _olmoe_block()
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    casts, temps = {}, {}
+    for tree in ("f32", "held"):
+        _, compiled = _compile_paged_program(
+            one_chip, monkeypatch, program, "fused", False, 2049, cfg,
+            tree=tree)
+        casts[tree] = _weight_casts(compiled.as_text(), params)
+        temps[tree] = compiled.memory_analysis().temp_size_in_bytes
+    # the control: on the float32 tree every stacked leaf and embed are
+    # cast and written (unembed alone is read as float32 inside the head
+    # matmul's own fusion, and written nowhere)
+    written = {c[c.index("[") + 1:-1] for c in casts["f32"]}
+    assert written == {
+        ",".join(map(str, leaf.shape))
+        for leaf in [params["embed"]] + [
+            params["layers"][k] for k in _MATMUL_KEYS if k in params["layers"]]
+    }, casts["f32"]
+    assert not casts["held"], casts["held"]
+    # and the bfloat16 copies were temporaries of every call. (The OLMoE
+    # programs give back 0.03 % less than the stacked leaves' size: what
+    # remains — each layer's experts copied out of their stack — shares
+    # half a megabyte less with nothing.)
+    stacked = sum(
+        2 * params["layers"][k].size for k in _MATMUL_KEYS
+        if k in params["layers"])
+    assert temps["f32"] - temps["held"] >= 0.999 * stacked, (temps, stacked)

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _held_tree import check_held_tree
 from ray_tpu.models import CONFIGS, DecodeEngine, init_params
 from ray_tpu.models.kv_paging import (
     BlockAllocator,
@@ -447,6 +448,55 @@ def test_paged_engine_stats_surface(tiny_f32):
                 "preemptions", "prefix_hits", "cow_copies", "block_tokens"):
         assert key in s, key
     assert s["kv_blocks_total"] == s["kv_blocks_free"] == eng.num_blocks - 1
+
+
+# ------------------------------------------------- the tree a replica holds
+
+
+@pytest.mark.parametrize("impl", ["gather", "fused"])
+@pytest.mark.parametrize("case", ["fresh", "swap", "float32"])
+def test_engine_holds_its_weights_in_the_compute_dtype(case, impl):
+    """A float32 tree is cast once, when the engine takes it (constructor
+    and set_params alike), and tokens and logits are bit for bit those of
+    the same programs handed the float32 tree, as they were before."""
+    cfg = dataclasses.replace(CONFIGS["tiny"], dtype=jnp.bfloat16)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    other = init_params(jax.random.PRNGKey(1), cfg)
+    shared, = _prompts(cfg, (16,), seed=3)
+    prompts = [np.concatenate([shared, p]) for p in _prompts(cfg, (7, 5))]
+    check_held_tree(
+        case, cfg, params, other, prompts, max_batch_size=2, block_tokens=8,
+        max_seq_len=64, prefix_cache=True, attention_impl=impl)
+
+
+def test_engine_owned_tree_is_held_cast_and_sharding_is_kept(tiny_f32):
+    """A tree the engine builds itself is the one init_params would give,
+    cast; a sharded tree keeps each leaf's sharding through the cast."""
+    from ray_tpu.models.transformer import param_specs, serving_params
+    from ray_tpu.parallel.sharding import tree_shardings
+
+    cfg = dataclasses.replace(tiny_f32[0], dtype=jnp.bfloat16)
+    params = init_params(jax.random.PRNGKey(3), cfg)
+    own = PagedDecodeEngine(cfg, seed=3, max_batch_size=1, block_tokens=8)
+    given = PagedDecodeEngine(cfg, params, max_batch_size=1, block_tokens=8)
+    for a, b in zip(jax.tree.leaves(own.params), jax.tree.leaves(given.params)):
+        assert a.dtype == b.dtype and np.array_equal(a, b)
+    assert own.stats()["param_bytes"] == given.stats()["param_bytes"]
+    assert own.stats()["param_dtype"] == "bfloat16"
+
+    mesh = build_mesh(MeshSpec(dp=2, fsdp=2, tp=2))
+    sharded = jax.device_put(params, tree_shardings(
+        mesh, PRESET_RULES["fsdp_tp"], param_specs(cfg)))
+    held = serving_params(cfg, sharded)
+    for a, b in zip(jax.tree.leaves(held), jax.tree.leaves(sharded)):
+        assert a.sharding == b.sharding, (a.sharding, b.sharding)
+    assert held["layers"]["wq"].dtype == jnp.bfloat16
+    assert len(held["layers"]["wq"].sharding.device_set) == 8
+    # consumed: the source leaves that were cast are gone, the rest shared
+    taken = serving_params(cfg, sharded, consume=True)
+    assert sharded["layers"]["wq"].is_deleted()
+    assert taken["final_norm"] is sharded["final_norm"]
+    assert np.array_equal(taken["layers"]["wq"], held["layers"]["wq"])
 
 
 def test_preemption_sse_streams_survive():

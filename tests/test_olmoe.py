@@ -16,6 +16,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from _held_tree import capture as _capture, check_held_tree, serve as _serve
 from benchmark import common
 from ray_tpu.models.kv_paging import PagedDecodeEngine
 from ray_tpu.models.transformer import (
@@ -88,39 +89,6 @@ def test_dense_oracle_matches_reference():
 
 
 # (b): the paged engine, prefill then decode through the cache
-def _capture(eng):
-    """Keep every logits row the engine's two programs produce."""
-    rows = {"prefill": [], "decode": []}
-    prefill, decode = eng._prefill, eng._decode_step
-
-    def prefill_spy(*a, **kw):
-        out = prefill(*a, **kw)
-        rows["prefill"].append(np.asarray(out[1], np.float32))
-        return out
-
-    def decode_spy(*a, **kw):
-        out = decode(*a, **kw)
-        rows["decode"].append(np.asarray(out[1], np.float32))
-        return out
-
-    eng._prefill, eng._decode_step = prefill_spy, decode_spy
-    return rows
-
-
-def _serve(eng, rows, slot, prompt, new_tokens):
-    """Admit, decode greedily; -> (tokens, the logits row behind each)."""
-    n_prefill, n_decode = len(rows["prefill"]), len(rows["decode"])
-    tok, done = eng.admit(slot, {"tokens": prompt, "max_new_tokens": new_tokens})
-    out = [int(tok)]
-    while not done:
-        (tok, done), = eng.step([slot]).values()
-        out.append(int(tok))
-    logits = [rows["prefill"][-1][0]]
-    logits += [r[slot] for r in rows["decode"][n_decode:]]
-    assert len(rows["prefill"]) == n_prefill + 1 and len(logits) == len(out)
-    return out, np.stack(logits)
-
-
 @pytest.mark.parametrize("impl", ["gather", "fused"])
 def test_engine_prefill_and_decode_match_reference(impl):
     cfg, params = _model()
@@ -142,6 +110,19 @@ def test_engine_prefill_and_decode_match_reference(impl):
     assert stats["moe_pairs"] == 2 * 5 * 1 * 2 * 2
     # one token's two experts differ: each layer's fullest expert holds one
     assert stats["moe_hottest"] == 2 * 5 * 2
+
+
+# the held tree: expert stacks, router and QK-norm scales included
+@pytest.mark.parametrize("case", ["fresh", "swap", "float32"])
+def test_engine_holds_its_weights_in_the_compute_dtype(case):
+    cfg, params = _model(dtype=jnp.bfloat16)
+    _, other = _model(dtype=jnp.bfloat16, seed=5)
+    assert params["layers"]["q_norm"].dtype == jnp.float32
+    shared = _tokens(16, seed=3)
+    prompts = [np.concatenate([shared, _tokens(n, seed=n)]) for n in (7, 5)]
+    check_held_tree(
+        case, cfg, params, other, prompts, max_batch_size=2, block_tokens=8,
+        max_seq_len=64, prefix_cache=True)
 
 
 # (c): a router rigged so that every token picks the same two experts
