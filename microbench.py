@@ -477,7 +477,7 @@ def _spec_verify_longctx(
                 )
         return outs
 
-    engines = {"gather": build("gather"), "fused": build("fused:xla")}
+    engines = {"gather": build("gather"), "fused": build("fused")}
     identical = True
     for eng, first in engines.values():  # warm + identity
         o = run(eng, first)

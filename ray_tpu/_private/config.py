@@ -241,23 +241,11 @@ D("serve_kv_cache_blocks", int, 0,
   "reuse and preemption keep oversubscription safe")
 D("serve_kv_cache_dtype", str, "fp",
   "paged KV-pool storage: 'fp' stores model dtype (the exact reference "
-  "path, bit-identical to dense decode); 'int8' stores int8 blocks with "
+  "path, held to the plain forward); 'int8' stores int8 blocks with "
   "per-block per-kv-head f32 scales — half the HBM per resident token, "
   "~2x concurrent sequences per chip, quantize at cache write / dequant "
   "at the attention read (greedy decode stays token-identical on the "
   "parity suite; logits drift within the quantization tolerance)")
-D("serve_paged_attention", str, "auto",
-  "paged decode-step attention: 'gather' materializes each slot's "
-  "[Nmax*block] window through its block table (exact reference); "
-  "'fused' walks the table block-in-place (Pallas kernel on TPU, chunked "
-  "online softmax elsewhere — ops/paged_attention.py), so the gather "
-  "never exists; 'auto' = fused on TPU, gather on CPU; "
-  "'fused:kernel'/'fused:xla' force one fused backend (tests)")
-D("serve_paged_attention_chunk_blocks", int, 8,
-  "fused-XLA paged attention only: physical blocks folded per "
-  "online-softmax chunk in the block-table walk — larger chunks amortize "
-  "gather dispatch, smaller ones cap the transient [B, chunk*block_tokens] "
-  "window; the Pallas kernel walks block-by-block and ignores this")
 D("serve_kv_pool_mb", int, 0,
   "size the paged KV pool by HBM budget instead of block count: "
   "num_blocks = budget // block_bytes, so int8 pools hold ~2x the blocks "

@@ -4,17 +4,14 @@ apply fns, logical-axis sharding annotations)."""
 from .transformer import (  # noqa: F401
     TransformerConfig,
     init_params,
-    init_kv_cache,
     init_paged_kv_cache,
     param_specs,
-    make_decoder,
     make_paged_decoder,
     make_forward,
     make_loss_fn,
     CONFIGS,
     KV_CACHE_AXES,
 )
-from .decoding import DecodeEngine  # noqa: F401
 from .kv_paging import (  # noqa: F401
     BlockAllocator,
     InsufficientBlocksError,

@@ -21,8 +21,8 @@ pieces that together make the engine a believable product:
                   host never materializes the full model twice.
 
 `load_model(path)` ties them together into a ModelBundle (config, params,
-tokenizer, eos id, model id) ready to drop into DecodeEngine /
-PagedDecodeEngine; `ray_tpu.serve.openai_api` serves such a bundle behind
+tokenizer, eos id, model id) ready to drop into PagedDecodeEngine;
+`ray_tpu.serve.openai_api` serves such a bundle behind
 an OpenAI-compatible `/v1/completions` endpoint.
 """
 

@@ -1,10 +1,10 @@
 """Paged KV-cache subsystem: block allocator, prefix reuse, preemption.
 
-The dense `DecodeEngine` allocates one max-length cache slab per slot, so
-HBM — not compute — caps concurrency and every request pays for its
-worst-case length up front. This module replaces the slab with a POOL of
-fixed-size token blocks (the vLLM PagedAttention memory model) plus the
-host-side machinery that makes the pool safe to oversubscribe:
+A max-length cache slab per slot would let HBM — not compute — cap
+concurrency, every request paying for its worst-case length up front. The
+decode engine here keeps a POOL of fixed-size token blocks instead (the
+vLLM PagedAttention memory model) plus the host-side machinery that makes
+the pool safe to oversubscribe:
 
   BlockAllocator    refcounted free-list over the physical blocks; block 0
                     is the reserved null block (padding writes and padded
@@ -63,7 +63,6 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..util.profiling import span
-from .decoding import default_prefill_buckets
 from .transformer import (
     NEG_INF,
     TransformerConfig,
@@ -75,6 +74,18 @@ from .transformer import (
 )
 
 logger = logging.getLogger(__name__)
+
+
+def default_prefill_buckets(max_seq_len: int) -> Tuple[int, ...]:
+    """Powers of two up to max_seq_len (always including it): each bucket
+    costs one prefill compile, padding within a bucket costs only FLOPs."""
+    buckets = []
+    b = 16
+    while b < max_seq_len:
+        buckets.append(b)
+        b *= 2
+    buckets.append(max_seq_len)
+    return tuple(buckets)
 
 
 class InsufficientBlocksError(RuntimeError):
@@ -268,9 +279,15 @@ class PrefixCache:
 
 class PagedDecodeEngine:
     """Block-granular KV-cache decode engine (module docstring has the
-    architecture). Drop-in for `DecodeEngine` under ContinuousBatcher —
-    same admit/step/release contract — plus paging APIs the batcher
-    discovers by duck-typing: can_admit, take_preempted.
+    architecture): the decode engine, ContinuousBatcher's admit / step /
+    release / stats contract plus the paging APIs the batcher discovers
+    by duck-typing: can_admit, take_preempted.
+
+    `attention_impl` is decided here, once: None (every caller but the
+    tests) is "fused" on a TPU and "gather" elsewhere — the block walk of
+    ops/paged_attention.py (Pallas kernel or XLA twin, by backend) against
+    the gather-window step that is the tests' exact reference. `stats()`
+    reports the choice (`attention_impl`, `attention_kernel`, `platform`).
 
     What the engine holds (`self.params`): the matmul weights, `embed` and
     `unembed` in `cfg.dtype`, the norm scales in float32 —
@@ -302,7 +319,6 @@ class PagedDecodeEngine:
         kv_cache_dtype: Optional[str] = None,
         attention_impl: Optional[str] = None,
         pool_bytes: Optional[int] = None,
-        chunk_blocks: Optional[int] = None,
         speculative_k: Optional[int] = None,
         drafter=None,
         prefill_chunk_tokens: Optional[int] = None,
@@ -374,10 +390,6 @@ class PagedDecodeEngine:
         self.weight_version = 0
         self.transfer_sig = self._compute_transfer_sig()
 
-        attention_impl = attention_impl or gcfg.serve_paged_attention
-        fused_impl = "auto"
-        if attention_impl.startswith("fused:"):
-            attention_impl, fused_impl = "fused", attention_impl[6:]
         # the device this engine computes on, resolved once and reported in
         # stats(): nothing below may choose a path from the backend again
         self._device = jax.devices()[0]
@@ -395,46 +407,24 @@ class PagedDecodeEngine:
                     "so); a replica meant for the chip must hold a TPU "
                     "resource", self.platform,
                 )
-        if attention_impl == "auto":
-            # the fused kernel is the TPU fast path; the gather step stays
+        if attention_impl is None:
+            # the fused walk is the TPU fast path; the gather step stays
             # the exact (and cheapest-to-dispatch) path on CPU CI hosts
             attention_impl = "fused" if self.platform == "tpu" else "gather"
-        if attention_impl not in ("gather", "fused") or fused_impl not in (
-            "auto", "kernel", "xla"
-        ):
-            # fail at construction, not at the first decode step's trace —
-            # a serve replica must reject a typo'd flag before admitting
+        if attention_impl not in ("gather", "fused"):
+            # fail at construction, not at the first decode step's trace
             raise ValueError(
-                "attention_impl must be auto|gather|fused[:kernel|:xla], "
-                f"got {attention_impl!r}"
-                + (f" with backend {fused_impl!r}" if fused_impl != "auto"
-                   else "")
-            )
-        if fused_impl == "auto":
-            fused_impl = "kernel" if self.platform == "tpu" else "xla"
-        # what actually attends: the Pallas kernel compiled by Mosaic, the
-        # same kernel under the Pallas interpreter (CPU tests only), its
-        # chunked XLA twin, or the gather step
-        if attention_impl == "gather":
-            self.attention_kernel = "gather"
-        elif fused_impl == "xla":
-            self.attention_kernel = "xla"
-        else:
-            self.attention_kernel = (
-                "pallas" if self.platform == "tpu" else "pallas-interpret"
+                "attention_impl must be None (by platform), 'gather' or "
+                f"'fused', got {attention_impl!r}"
             )
         self.attention_impl = attention_impl
-        chunk_blocks = int(
-            chunk_blocks if chunk_blocks is not None
-            else gcfg.serve_paged_attention_chunk_blocks
+        # what actually attends: the gather step, or under "fused" what
+        # ops.paged_attention picks from the backend — the Pallas kernel
+        # compiled by Mosaic on a TPU, its chunked XLA twin elsewhere
+        self.attention_kernel = (
+            "gather" if attention_impl == "gather"
+            else "pallas" if self.platform == "tpu" else "xla"
         )
-        if chunk_blocks <= 0:
-            # same contract as the impl flags: a bad tuning knob fails at
-            # replica construction, not at the first decode step's trace
-            raise ValueError(
-                f"chunk_blocks must be positive, got {chunk_blocks}"
-            )
-        self.chunk_blocks = chunk_blocks
 
         prefill_chunk_tokens = int(
             gcfg.serve_prefill_chunk_tokens if prefill_chunk_tokens is None
@@ -597,8 +587,7 @@ class PagedDecodeEngine:
             make_paged_decoder(
                 cfg, rules=rules, mesh=mesh, temperature=temperature,
                 block_tokens=bt, kv_dtype=kv_dtype,
-                attention_impl=attention_impl, fused_impl=fused_impl,
-                chunk_blocks=chunk_blocks,
+                attention_impl=attention_impl,
             )
         )
         buckets = sorted(set(
@@ -818,10 +807,10 @@ class PagedDecodeEngine:
         if prompt.ndim != 1 or prompt.size == 0:
             raise ValueError("request['tokens'] must be a non-empty 1-D seq")
         length = int(prompt.size)
-        # length == max_seq_len is admittable (unlike the dense engine): it
-        # emits exactly ONE token and finishes without a cache write —
-        # which is also what makes a generation preempted at its very last
-        # position readmittable (its parked history fills the window)
+        # length == max_seq_len is admittable: it emits exactly ONE token
+        # and finishes without a cache write — which is also what makes a
+        # generation preempted at its very last position readmittable (its
+        # parked history fills the window)
         if length > self.max_seq_len:
             raise ValueError(
                 f"prompt of {length} tokens exceeds max_seq_len "
@@ -1706,7 +1695,6 @@ class PagedDecodeEngine:
                 int(leaf.nbytes) for leaf in jax.tree_util.tree_leaves(self.params)
             ),
             "param_dtype": np.dtype(self.params["layers"]["wq"].dtype).name,
-            "attention_chunk_blocks": self.chunk_blocks,
             "kv_block_bytes": self.kv_block_bytes,
             # true pool HBM: counts the reserved null block too, so this
             # reconciles exactly with a serve_kv_pool_mb budget

@@ -17,24 +17,17 @@ model-parallel story is external Alpa, release/alpa_tests/):
     over `sp` via ppermute ring), ulysses (all-to-all head scatter).
   - bf16 compute, f32 params/accumulators.
 
-Decode fast path (serving): `make_decoder` builds prefill + cached
-single-token decode — a per-layer KV cache allocated at `max_seq_len`,
-written at each sequence's current position and sharded by the same
-partition rules as activations, so every generated token pays O(L)
-attention reads instead of the O(L^2) full-sequence forward. The decode
-step is jit-compiled once (per cache batch size) and reused; see
-`ray_tpu/models/decoding.py` for the slot-based engine continuous
-batching drives.
-
-Paged variant (serving at scale): `init_paged_kv_cache` + `make_paged_decoder`
-swap the per-slot slab for a pool of fixed-size token blocks addressed
-through per-slot block tables (gathered inside the jitted step — one
-compiled shape regardless of live lengths). The pool's leaves stay stacked
-[L, N, ...] through every program's layer loop: writes scatter into
-`leaf[l, block, offset]` of the donated buffer and reads fetch
-`leaf[l, block]`, so a step moves the tokens it writes and the blocks it
-attends, never the pool. Host-side allocation, prefix reuse and preemption
-live in `ray_tpu/models/kv_paging.py`.
+Decode fast path (serving): `init_paged_kv_cache` + `make_paged_decoder`
+build prefill, cached single-token decode and speculative verify over a
+pool of fixed-size token blocks addressed through per-slot block tables —
+one compiled shape regardless of live lengths, and every generated token
+pays O(L) attention reads instead of the O(L^2) full-sequence forward. The
+pool's leaves stay stacked [L, N, ...] through every program's layer loop:
+writes scatter into `leaf[l, block, offset]` of the donated buffer and
+reads fetch `leaf[l, block]`, so a step moves the tokens it writes and the
+blocks it attends, never the pool. The engine that drives the programs,
+with host-side allocation, prefix reuse and preemption, is
+`ray_tpu/models/kv_paging.py`.
 """
 
 from __future__ import annotations
@@ -523,8 +516,7 @@ def serving_params(cfg: TransformerConfig, params, *, consume: bool = False):
 
 
 def _mlp(h, lp, cfg: TransformerConfig, constrain_fn):
-    if cfg.n_experts:
-        return _moe(h, lp, cfg, constrain_fn)[0]
+    """The dense MLP (gated SiLU, or the gpt2-family two-matmul gelu)."""
     from jax.ad_checkpoint import checkpoint_name
 
     u = checkpoint_name(
@@ -561,14 +553,13 @@ def _whole_projection_norm(x, scale, eps: float, head_major: bool):
 
 def _qkv(x, lp, cfg: TransformerConfig, cos, sin, positions=None,
          head_major: bool = False):
-    """(q, k, v) of one layer from the layer's input x [B, S, E] — the one
-    place every layer body (training forward, paged prefill / decode /
-    verify, dense prefill / decode) gets them from: pre-norm with the
-    config's eps, the three projections, QK-norm over the whole projection
-    when the config has it, RoPE at `positions` ([B, S] or [1, S]; None =
-    0..S-1). Layout [B, S, H, D], or [B, H, S, D] when `head_major` (the
-    training forward's kernel-native layout, which also names q, k, v for
-    the remat policies)."""
+    """(q, k, v) of one layer from the layer's input x [B, S, E] — the
+    front half of `_block`: pre-norm with the config's eps, the three
+    projections, QK-norm over the whole projection when the config has it,
+    RoPE at `positions` ([B, S] or [1, S]; None = 0..S-1). Layout
+    [B, S, H, D], or [B, H, S, D] when `head_major` (the training forward's
+    kernel-native layout, which also names q, k, v for the remat
+    policies)."""
     h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
     out = "bhsd" if head_major else "bshd"
     q = jnp.einsum(f"bse,ehd->{out}", h, lp["wq"].astype(h.dtype))
@@ -593,6 +584,39 @@ def _qkv(x, lp, cfg: TransformerConfig, cos, sin, positions=None,
     q = checkpoint_name(apply_rope_bhsd(q, cos, sin), "rope_q")
     k = checkpoint_name(apply_rope_bhsd(k, cos, sin), "rope_k")
     return q, k, v
+
+
+def _block(x, lp, cfg: TransformerConfig, cos, sin, attend, constrain_fn, *,
+           positions=None, head_major: bool = False, routed=None):
+    """One decoder layer, written once for every program: `_qkv`, the
+    program's own attention, the output projection and its residual, then
+    the post-norm MLP (dense or sparse experts) and its residual.
+
+    `attend(q, k, v) -> (attn, kept)` is all that differs between the
+    programs: causal / flash over the sequence itself (the trainer's
+    forward); write the new K/V into the pool, then gather or fused-walk
+    the block window (paged prefill and decode, `kept` = the pool's new
+    leaves); the cached window plus the in-flight tail (verify, `kept` =
+    this layer's k, v, committed after acceptance). `kept` is handed back
+    as it came.
+
+    `routed(idx)`, where the layer has experts, gets the router's choices
+    idx [B*S, k] as soon as they exist — before the residual add, where
+    decode's expert-load count has always been traced — and its result is
+    returned. Returns (x, kept, routed's result or None)."""
+    q, k, v = _qkv(x, lp, cfg, cos, sin, positions, head_major)
+    attn, kept = attend(q, k, v)
+    wo_eq = "bhsd,hde->bse" if head_major else "bshd,hde->bse"
+    x = x + jnp.einsum(wo_eq, attn, lp["wo"].astype(x.dtype))
+    h2 = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
+    stat = None
+    if cfg.n_experts:
+        y, idx = _moe(h2, lp, cfg, constrain_fn)
+        if routed is not None:
+            stat = routed(idx)
+    else:
+        y = _mlp(h2, lp, cfg, constrain_fn)
+    return constrain_fn(x + y, "batch", "seq", "embed"), kept, stat
 
 
 def make_forward(
@@ -665,19 +689,16 @@ def make_forward(
             return x
         return constrain(x, rules, *axes, mesh=mesh)
 
+    q_axes = (("batch", "heads", "seq", "head_dim") if head_major
+              else ("batch", "seq", "heads", "head_dim"))
+
+    def attend_seq(q, k, v):
+        # the sequence attends itself: nothing is kept for later
+        return attend(_constrain(q, *q_axes), k, v), None
+
     def layer_step(x, lp):
-        q, k, v = _qkv(x, lp, cfg, cos, sin, head_major=head_major)
-        if head_major:
-            q = _constrain(q, "batch", "heads", "seq", "head_dim")
-            attn = attend(q, k, v)
-            x = x + jnp.einsum("bhsd,hde->bse", attn, lp["wo"].astype(x.dtype))
-        else:
-            q = _constrain(q, "batch", "seq", "heads", "head_dim")
-            attn = attend(q, k, v)
-            x = x + jnp.einsum("bshd,hde->bse", attn, lp["wo"].astype(x.dtype))
-        h2 = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-        x = x + _mlp(h2, lp, cfg, _constrain)
-        x = _constrain(x, "batch", "seq", "embed")
+        x, _, _ = _block(x, lp, cfg, cos, sin, attend_seq, _constrain,
+                         head_major=head_major)
         return x, None
 
     if cfg.remat:
@@ -775,41 +796,20 @@ def make_forward(
 # autoregressive decode (KV cache)
 # --------------------------------------------------------------------------
 
-# cache leaves are [n_layers, batch, max_seq_len, kv_heads, head_dim]; the
-# logical axes reuse the activation rules, so the cache shards exactly like
-# activations under every existing mesh preset (dp/fsdp shard the slot dim,
+# pool leaves are [n_layers, num_blocks, block_tokens, kv_heads, head_dim];
+# the logical axes reuse the activation rules, so the pool shards like
+# activations under every existing mesh preset (dp/fsdp shard the block dim,
 # tp shards kv_heads; kv_seq stays unsharded outside sp presets — decode
 # scatters at dynamic positions, which sp sharding would turn into
 # collectives per token)
 KV_CACHE_AXES = ("layers", "batch", "kv_seq", "kv_heads", "head_dim")
 
 
-def init_kv_cache(
-    cfg: TransformerConfig,
-    batch_size: int,
-    mesh=None,
-    rules: Optional[ShardingRules] = None,
-    max_seq_len: Optional[int] = None,
-):
-    """Allocate the per-layer KV cache for `batch_size` decode slots."""
-    S = max_seq_len or cfg.max_seq_len
-    shape = (cfg.n_layers, batch_size, S, cfg.n_kv_heads, cfg.d_head)
-    k = jnp.zeros(shape, cfg.dtype)
-    v = jnp.zeros(shape, cfg.dtype)
-    if mesh is not None and rules is not None:
-        from ..parallel.sharding import logical_sharding
-
-        sh = logical_sharding(mesh, rules, *KV_CACHE_AXES)
-        k, v = jax.device_put(k, sh), jax.device_put(v, sh)
-    return {"k": k, "v": v}
-
-
 def _make_sampler(temperature: float, vocab_pad: int = 0):
-    """Greedy argmax (temperature 0) or categorical sampling — ONE
-    implementation shared by the dense and paged decoders, so their
-    token-for-token parity cannot drift. `vocab_pad` masks the trailing
-    alignment-only vocab entries (see TransformerConfig.vocab_pad) to
-    -inf so a padded id can never win the argmax / be sampled."""
+    """Greedy argmax (temperature 0) or categorical sampling, shared by
+    the paged programs. `vocab_pad` masks the trailing alignment-only vocab
+    entries (see TransformerConfig.vocab_pad) to -inf so a padded id can
+    never win the argmax / be sampled."""
 
     def _sample(logits, key):
         if vocab_pad:
@@ -834,8 +834,7 @@ def _unembed_matrix(cfg: TransformerConfig, params):
 
 def _cached_attend(q, kc, vc, mask, scale, n_rep):
     """Attention over cache-layout K/V — the single softmax formulation
-    both the dense decode step and the paged prefill/decode steps use
-    (shared so paged == dense stays bit-identical by construction).
+    of the "gather" implementation's prefill, decode and verify.
 
     q [B,Sq,H,D]; kc/vc [B,W,KV,D]; mask [B,Sq,W] (True = attend)."""
     kr = _repeat_kv(kc, n_rep)
@@ -880,10 +879,9 @@ def init_paged_kv_cache(
 ):
     """Allocate the pooled (paged) per-layer KV cache: `num_blocks` physical
     blocks of `block_tokens` tokens each, shared by every decode slot via
-    per-slot block tables. The logical axes are the same KV_CACHE_AXES as
-    the dense cache — the block dim takes the "batch" axis (dp/fsdp), so
-    the pool shards exactly like the dense slot dim under every existing
-    mesh preset. Block 0 is reserved as the null block: padded table
+    per-slot block tables. The logical axes are KV_CACHE_AXES — the block
+    dim takes the "batch" axis (dp/fsdp), so the pool shards under every
+    existing mesh preset. Block 0 is reserved as the null block: padded table
     entries and masked-token writes route there (see kv_paging.py).
 
     `dtype=jnp.int8` stores the pool quantized with per-block, per-kv-head
@@ -916,8 +914,6 @@ def make_paged_decoder(
     block_tokens: int = 64,
     kv_dtype=None,
     attention_impl: str = "gather",
-    fused_impl: str = "auto",
-    chunk_blocks: int = 8,
 ):
     """Build the paged fast path: (paged_prefill, paged_decode_step,
     paged_verify_step, copy_blocks) over a block pool from
@@ -925,14 +921,13 @@ def make_paged_decoder(
 
     One loop shape for all three model programs, every implementation and
     every pool dtype: `lax.scan` over (stacked layer weights, layer index),
-    with the pool's stacked [L, N, ...] leaves as loop-carried state
-    (prefill, decode) or closed over read-only (verify, which commits
-    after the loop). A layer writes `leaf.at[l, block, offset]` and reads
-    `leaf[l, block]` — under "fused" the kernel takes the stacked leaf and
-    `layer=l` and DMAs block `table[b, j]` of layer `l` itself. The pool
-    argument is donated, so the scatter lands in the caller's buffer: no
-    instruction of a compiled program scales with the pool
-    (tests/test_chip_compile.py holds the programs to that).
+    each layer one `_block`, with the pool's stacked [L, N, ...] leaves as
+    loop-carried state (prefill, decode) or closed over read-only (verify,
+    which commits after the loop). A layer writes `leaf.at[l, block, offset]`
+    and reads `leaf[l, block]`. The pool argument is donated, so the
+    scatter lands in the caller's buffer: no instruction of a compiled
+    program scales with the pool (tests/test_chip_compile.py holds the
+    programs to that).
 
     paged_prefill(params, pool, table[Nmax], tokens[1,Sb], length, ctx_len,
                   key, ctx_blocks) -> (next_token[1], logits[1,V], pool)
@@ -942,9 +937,8 @@ def make_paged_decoder(
       admission calls this once per chunk), or 0 for a cold prompt.
       Suffix K/V is scattered into the slot's table blocks — a chunk
       boundary may land mid-block; the straddled block is slot-owned —
-      and attention runs over the block window (gathered under "gather",
-      walked in place under "fused"), so the committed span is never
-      recomputed. `ctx_blocks` is STATIC (bucketed by the caller —
+      and attention runs over the block window, so the committed span is
+      never recomputed. `ctx_blocks` is STATIC (bucketed by the caller —
       kv_paging pads block counts to the same bucket boundaries as prompt
       lengths) and keys the compile cache together with the suffix bucket.
 
@@ -953,7 +947,7 @@ def make_paged_decoder(
         -> (next_tokens[B], logits[B,V], pool, moe_hottest)
       One cached decode step for every slot: the new K/V is written at the
       host-resolved (physical block, offset) pair — inactive slots route to
-      the null block — and attention gathers each slot's logical sequence
+      the null block — and attention reads each slot's logical sequence
       via its block table. ONE compiled shape per (B, Nmax) regardless of
       live sequence lengths or block-table contents. `moe_hottest` is None
       without experts; with them, the load of the step's fullest expert
@@ -970,20 +964,16 @@ def make_paged_decoder(
       position i-1 and every earlier draft survived), and ONLY the
       accepted inputs' K/V commit to the pool — rejected entries route to
       the null block, so there is nothing in the pool to roll back.
-      Attention never writes before acceptance: under
-      `attention_impl="gather"` the slot's cached window is gathered
-      through its table and the K1 in-flight K/V are appended past it
-      with a causal tail mask; under `attention_impl="fused"` the cached
-      window runs the multi-query fused walk (kv_len = positions keeps
-      the unwritten span invisible) and the K1 x K1 in-flight tail folds
-      in as a second online-softmax partial via the log-sum-exp merge —
-      so long-context speculation keeps the fused win instead of
-      re-paying the gather cost.
-      Compiled once per (B, K1, Nmax) — the engine
-      buckets K1 (kv_paging) so draft-length jitter cannot churn the jit
-      cache. Greedy-only: with temperature > 0 the per-position samples
-      would not preserve the sampling distribution (the engine refuses to
-      enable speculation off greedy).
+      Attention never writes before acceptance: the slot's cached window
+      is attended through its table (kv_len = positions keeps the
+      unwritten span invisible) and the K1 in-flight K/V join it with a
+      causal tail mask — appended past the gathered window under
+      "gather", folded in as a second online-softmax partial via the
+      log-sum-exp merge under "fused".
+      Compiled once per (B, K1, Nmax) — the engine buckets K1 (kv_paging).
+      Greedy-only: with temperature > 0 the per-position samples would not
+      preserve the sampling distribution (the engine refuses to enable
+      speculation off greedy).
 
       fp pools commit with one masked scatter; int8 pools REPLAY the
       single-token RMW sequence (a K1-step in-graph scan of the same
@@ -1009,18 +999,14 @@ def make_paged_decoder(
     prefill (q=suffix chunk) and speculative verify (q=k+1):
       "gather"  gather each slot's window [B, Nmax*bt] through its block
                 table, then dense masked softmax — the exact reference
-                path (bit-identical to the dense engine in fp).
+                path the tests hold to the plain forward.
       "fused"   ops/paged_attention.py walks the block table and attends
-                block-in-place with a q-tile grid axis (Pallas kernel on
-                TPU, chunked online softmax under XLA elsewhere;
-                `fused_impl` forces one). Composes with KV_CACHE_AXES
-                sharding via shard_map: block-sharded pools run per-shard
-                with a log-sum-exp merge across the block axes;
-                tp-sharded kv_heads need no merge.
-
-    `chunk_blocks` tunes the fused-XLA walk only (blocks folded per
-    online-softmax chunk — larger amortizes gather dispatch, smaller caps
-    the transient window); the Pallas kernel walks block-by-block.
+                block-in-place with a q-tile grid axis; the op picks the
+                Pallas kernel on a TPU backend and its chunked XLA twin
+                elsewhere. Composes with KV_CACHE_AXES sharding via
+                shard_map: block-sharded pools run per-shard with a
+                log-sum-exp merge across the block axes; tp-sharded
+                kv_heads need no merge.
     """
     if cfg.pp_stages > 1:
         raise NotImplementedError("decode does not support pp_stages > 1")
@@ -1031,9 +1017,6 @@ def make_paged_decoder(
         raise ValueError(
             f"attention_impl must be 'gather' or 'fused', got {attention_impl!r}"
         )
-    chunk_blocks = int(chunk_blocks)
-    if chunk_blocks <= 0:
-        raise ValueError(f"chunk_blocks must be positive, got {chunk_blocks}")
     kv_dtype = kv_dtype or cfg.dtype
     quant = kv_dtype == jnp.int8
     cos, sin = rope_frequencies(cfg.d_head, cfg.max_seq_len, cfg.rope_theta)
@@ -1148,8 +1131,7 @@ def make_paged_decoder(
         if not block_axes and not kv_axes:
             return paged_attention(
                 qx, kc, vc, tables, positions, layer=l, scale=scale,
-                impl=fused_impl, chunk_blocks=chunk_blocks, kv_len=kv_len,
-                partial_out=partial, **scales,
+                kv_len=kv_len, partial_out=partial, **scales,
             )
 
         def inner(qx, kc, vc, *rest):
@@ -1162,7 +1144,6 @@ def make_paged_decoder(
             if not block_axes:
                 return paged_attention(
                     qx, kc, vc, tables, positions, layer=l, scale=scale,
-                    impl=fused_impl, chunk_blocks=chunk_blocks,
                     kv_len=kv_len, partial_out=partial, **sc,
                 )
             # blocks are sharded: remap global table entries to this
@@ -1177,8 +1158,7 @@ def make_paged_decoder(
             ptab = jnp.where(live, tables - lo, -1).astype(jnp.int32)
             acc, m, den = paged_attention(
                 qx, kc, vc, ptab, positions, layer=l, scale=scale,
-                impl=fused_impl, signed_tables=True, partial_out=True,
-                chunk_blocks=chunk_blocks, kv_len=kv_len, **sc,
+                signed_tables=True, partial_out=True, kv_len=kv_len, **sc,
             )
             if partial:
                 # fold the shards into ONE globally-valid partial triple
@@ -1298,40 +1278,44 @@ def make_paged_decoder(
             return kc.at[l, window].set(q8), ksc.at[l, window].set(s), kw
 
         def layer_fn(carry, per_layer):
-            x, kc, vc, ksc, vsc = carry
+            x, *leaves = carry
             lp, l = per_layer
-            q, k, v = _qkv(x, lp, cfg, cos, sin, positions=qpos[None])
-            q = _constrain(q, "batch", "seq", "heads", "head_dim")
-            # write the suffix K/V first — suffix keys are then read back
-            # from the pool, so cache content is authoritative either way
-            if quant:
-                kc, ksc, kw = _write_suffix_quant(kc, ksc, l, k[0])
-                vc, vsc, vw = _write_suffix_quant(vc, vsc, l, v[0])
-            else:
-                kc = kc.at[l, w_phys, w_off].set(k[0].astype(kc.dtype))
-                vc = vc.at[l, w_phys, w_off].set(v[0].astype(vc.dtype))
-            if attention_impl == "fused":
-                # multi-query fused walk over the window blocks in place:
-                # query i sits at ctx_len + i, kv_len caps recycled-block
-                # positions past the live span (quant kw/vw are unused —
-                # the kernel dequantizes from the pool itself)
-                attn = _fused_attend(
-                    q, kc, vc, ksc, vsc, l, window[None],
-                    jnp.reshape(jnp.asarray(ctx_len, jnp.int32), (1,)),
-                    kv_len=jnp.reshape(
-                        jnp.asarray(ctx_len + length, jnp.int32), (1,)
-                    ),
-                )
-            else:
-                if not quant:
-                    kw = _gather_window(kc, ksc, l, window[None])
-                    vw = _gather_window(vc, vsc, l, window[None])
-                attn = _cached_attend(q, kw, vw, kmask, scale, n_rep)
-            x = x + jnp.einsum("bshd,hde->bse", attn, lp["wo"])
-            h2 = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-            x = x + _mlp(h2, lp, cfg, _constrain)
-            x = _constrain(x, "batch", "seq", "embed")
-            return (x, kc, vc, ksc, vsc), None
+
+            def attend(q, k, v):
+                kc, vc, ksc, vsc = leaves
+                q = _constrain(q, "batch", "seq", "heads", "head_dim")
+                # write the suffix K/V first — suffix keys are then read
+                # back from the pool, so cache content is authoritative
+                # either way
+                if quant:
+                    kc, ksc, kw = _write_suffix_quant(kc, ksc, l, k[0])
+                    vc, vsc, vw = _write_suffix_quant(vc, vsc, l, v[0])
+                else:
+                    kc = kc.at[l, w_phys, w_off].set(k[0].astype(kc.dtype))
+                    vc = vc.at[l, w_phys, w_off].set(v[0].astype(vc.dtype))
+                if attention_impl == "fused":
+                    # multi-query fused walk over the window blocks in
+                    # place: query i sits at ctx_len + i, kv_len caps
+                    # recycled-block positions past the live span (quant
+                    # kw/vw are unused — the kernel dequantizes from the
+                    # pool itself)
+                    attn = _fused_attend(
+                        q, kc, vc, ksc, vsc, l, window[None],
+                        jnp.reshape(jnp.asarray(ctx_len, jnp.int32), (1,)),
+                        kv_len=jnp.reshape(
+                            jnp.asarray(ctx_len + length, jnp.int32), (1,)
+                        ),
+                    )
+                else:
+                    if not quant:
+                        kw = _gather_window(kc, ksc, l, window[None])
+                        vw = _gather_window(vc, vsc, l, window[None])
+                    attn = _cached_attend(q, kw, vw, kmask, scale, n_rep)
+                return attn, (kc, vc, ksc, vsc)
+
+            x, leaves, _ = _block(x, lp, cfg, cos, sin, attend, _constrain,
+                                  positions=qpos[None])
+            return (x, *leaves), None
 
         (x, *leaves), _ = lax.scan(
             layer_fn, (x,) + _pool_leaves(pool), (params["layers"], layer_ids)
@@ -1387,42 +1371,44 @@ def make_paged_decoder(
             return (kc.at[l, write_phys].set(q8),
                     ksc.at[l, write_phys].set(s1))
 
+        def fullest(idx):
+            # the step's fullest expert among the live slots (an inactive
+            # slot writes to the null block, 0)
+            return _fullest_expert(idx, write_phys > 0, cfg.n_experts)
+
         def layer_fn(carry, per_layer):
-            x, kc, vc, ksc, vsc = carry
+            x, *leaves = carry
             lp, l = per_layer
-            # q [B,1,H,D]; k, v [B,1,KV,D]
-            q, k, v = _qkv(x, lp, cfg, cos, sin, positions=pos2)
-            if quant:
-                kc, ksc = _write_token_quant(kc, ksc, l, k[:, 0])
-                vc, vsc = _write_token_quant(vc, vsc, l, v[:, 0])
-            else:
-                kc = kc.at[l, write_phys, write_off].set(
-                    k[:, 0].astype(kc.dtype))
-                vc = vc.at[l, write_phys, write_off].set(
-                    v[:, 0].astype(vc.dtype))
-            if attention_impl == "fused":
-                # block-in-place attention: no [B, W] gather exists. This
-                # token's K/V was just written, so the live window is
-                # positions + 1 keys deep
-                attn = _fused_attend(
-                    q, kc, vc, ksc, vsc, l, tables, positions,
-                    kv_len=positions + 1,
-                )
-            else:
-                kw = _gather_window(kc, ksc, l, tables)
-                vw = _gather_window(vc, vsc, l, tables)
-                attn = _cached_attend(q, kw, vw, kmask, scale, n_rep)
-            x = x + jnp.einsum("bshd,hde->bse", attn, lp["wo"])
-            h2 = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-            if cfg.n_experts:
-                # the step's fullest expert among the live slots (an
-                # inactive slot writes to the null block, 0)
-                y, idx = _moe(h2, lp, cfg, _constrain)
-                hottest = _fullest_expert(idx, write_phys > 0, cfg.n_experts)
-            else:
-                y, hottest = _mlp(h2, lp, cfg, _constrain), None
-            x = _constrain(x + y, "batch", "seq", "embed")
-            return (x, kc, vc, ksc, vsc), hottest
+
+            def attend(q, k, v):
+                # q [B,1,H,D]; k, v [B,1,KV,D]
+                kc, vc, ksc, vsc = leaves
+                if quant:
+                    kc, ksc = _write_token_quant(kc, ksc, l, k[:, 0])
+                    vc, vsc = _write_token_quant(vc, vsc, l, v[:, 0])
+                else:
+                    kc = kc.at[l, write_phys, write_off].set(
+                        k[:, 0].astype(kc.dtype))
+                    vc = vc.at[l, write_phys, write_off].set(
+                        v[:, 0].astype(vc.dtype))
+                if attention_impl == "fused":
+                    # block-in-place attention: no [B, W] gather exists.
+                    # This token's K/V was just written, so the live
+                    # window is positions + 1 keys deep
+                    attn = _fused_attend(
+                        q, kc, vc, ksc, vsc, l, tables, positions,
+                        kv_len=positions + 1,
+                    )
+                else:
+                    kw = _gather_window(kc, ksc, l, tables)
+                    vw = _gather_window(vc, vsc, l, tables)
+                    attn = _cached_attend(q, kw, vw, kmask, scale, n_rep)
+                return attn, (kc, vc, ksc, vsc)
+
+            x, leaves, hottest = _block(
+                x, lp, cfg, cos, sin, attend, _constrain, positions=pos2,
+                routed=fullest)
+            return (x, *leaves), hottest
 
         (x, *leaves), hottest = lax.scan(
             layer_fn, (x,) + _pool_leaves(pool), (params["layers"], layer_ids)
@@ -1500,30 +1486,32 @@ def make_paged_decoder(
 
         def layer_fn(x, per_layer):
             lp, l = per_layer
-            q, k, v = _qkv(x, lp, cfg, cos, sin, positions=rope_pos)
-            q = _constrain(q, "batch", "seq", "heads", "head_dim")
-            if attention_impl == "fused":
-                # multi-query fused walk over the cached window (kv_len =
-                # positions keeps the not-yet-written span invisible and
-                # masks recycled-block staleness), then the K1 in-flight
-                # keys fold in as a second online-softmax partial — the
-                # gather-window concat never materializes
-                acc_w, m_w, l_w = _fused_attend(
-                    q, kc, vc, ksc, vsc, l, tables, positions,
-                    kv_len=positions, partial=True,
-                )
-                attn = _merge_inflight(q, acc_w, m_w, l_w, k, v, fmask)
-            else:
-                kw = _gather_window(kc, ksc, l, tables)
-                vw = _gather_window(vc, vsc, l, tables)
-                kcat = jnp.concatenate([kw, k.astype(kw.dtype)], axis=1)
-                vcat = jnp.concatenate([vw, v.astype(vw.dtype)], axis=1)
-                attn = _cached_attend(q, kcat, vcat, mask, scale, n_rep)
-            x = x + jnp.einsum("bshd,hde->bse", attn, lp["wo"])
-            h2 = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-            x = x + _mlp(h2, lp, cfg, _constrain)
-            x = _constrain(x, "batch", "seq", "embed")
-            return x, (k, v)
+
+            def attend(q, k, v):
+                q = _constrain(q, "batch", "seq", "heads", "head_dim")
+                if attention_impl == "fused":
+                    # multi-query fused walk over the cached window
+                    # (kv_len = positions keeps the not-yet-written span
+                    # invisible and masks recycled-block staleness), then
+                    # the K1 in-flight keys fold in as a second
+                    # online-softmax partial — the gather-window concat
+                    # never materializes
+                    acc_w, m_w, l_w = _fused_attend(
+                        q, kc, vc, ksc, vsc, l, tables, positions,
+                        kv_len=positions, partial=True,
+                    )
+                    attn = _merge_inflight(q, acc_w, m_w, l_w, k, v, fmask)
+                else:
+                    kw = _gather_window(kc, ksc, l, tables)
+                    vw = _gather_window(vc, vsc, l, tables)
+                    kcat = jnp.concatenate([kw, k.astype(kw.dtype)], axis=1)
+                    vcat = jnp.concatenate([vw, v.astype(vw.dtype)], axis=1)
+                    attn = _cached_attend(q, kcat, vcat, mask, scale, n_rep)
+                return attn, (k, v)
+
+            x, kv, _ = _block(x, lp, cfg, cos, sin, attend, _constrain,
+                              positions=rope_pos)
+            return x, kv
 
         x, (ks, vs) = lax.scan(layer_fn, x, (params["layers"], layer_ids))
         x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
@@ -1556,120 +1544,6 @@ def make_paged_decoder(
         jax.jit(paged_verify, donate_argnums=(1,)),
         jax.jit(copy_blocks, donate_argnums=(0,)),
     )
-
-
-def make_decoder(
-    cfg: TransformerConfig,
-    rules: Optional[ShardingRules] = None,
-    mesh=None,
-    temperature: float = 0.0,
-):
-    """Build the autoregressive fast path: (prefill, write_cache, decode_step).
-
-    prefill(params, tokens[B,Sp], lengths[B], key)
-        -> (next_tokens[B], logits[B,V], ks, vs)
-      Full forward over the (padded) prompt; logits are read at position
-      lengths-1 and ks/vs are the per-layer K/V stacks [L,B,Sp,KV,D] ready
-      to be written into a cache. Compiled per (B, Sp) shape — callers pad
-      prompts to a small set of buckets.
-
-    write_cache(cache, ks, vs, slot) -> cache
-      Scatter a prefill's K/V stack into cache rows [slot, slot+B).
-
-    decode_step(params, cache, tokens[B], positions[B], key)
-        -> (next_tokens[B], logits[B,V], cache)
-      One cached decode step for every slot: the new K/V is written at each
-      slot's own position, attention reads kpos <= position, so slots at
-      different sequence lengths decode together in one batch (the
-      continuous-batching contract). Jit-compiled once per cache batch
-      size, cache donated.
-
-    temperature=0 is greedy argmax; >0 samples categorically with `key`.
-    Decode is dense-attention only (the cache read is one [B,S] row per
-    slot); ring/ulysses and pp_stages>1 configs must decode with a
-    non-sp/pp rules table.
-    """
-    if cfg.pp_stages > 1:
-        raise NotImplementedError("decode does not support pp_stages > 1")
-    cos, sin = rope_frequencies(cfg.d_head, cfg.max_seq_len, cfg.rope_theta)
-    scale = cfg.d_head**-0.5
-
-    def _constrain(x, *axes):
-        if rules is None or mesh is None:
-            return x
-        return constrain(x, rules, *axes, mesh=mesh)
-
-    _sample = _make_sampler(temperature, cfg.vocab_pad)
-
-    def _prefill(params, tokens, lengths, key):
-        params = _cast_matmul_params(cfg, params)
-        x = params["embed"].astype(cfg.dtype)[tokens]
-        x = _constrain(x, "batch", "seq", "embed")
-
-        def layer_prefill(x, lp):
-            q, k, v = _qkv(x, lp, cfg, cos, sin)
-            q = _constrain(q, "batch", "seq", "heads", "head_dim")
-            attn = causal_attention(q, k, v)
-            x = x + jnp.einsum("bshd,hde->bse", attn, lp["wo"])
-            h2 = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-            x = x + _mlp(h2, lp, cfg, _constrain)
-            x = _constrain(x, "batch", "seq", "embed")
-            return x, (k, v)
-
-        x, (ks, vs) = lax.scan(layer_prefill, x, params["layers"])
-        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-        # logits only at each sequence's last real token (padding beyond
-        # lengths-1 produces garbage states that are never read)
-        B = tokens.shape[0]
-        x_last = x[jnp.arange(B), jnp.maximum(lengths - 1, 0)]
-        logits = jnp.einsum("be,ev->bv", x_last, _unembed_matrix(cfg, params))
-        logits = _constrain(logits, "batch", "vocab")
-        return _sample(logits, key), logits, ks, vs
-
-    def _write_cache(cache, ks, vs, slot):
-        k = lax.dynamic_update_slice(cache["k"], ks.astype(cache["k"].dtype),
-                                     (0, slot, 0, 0, 0))
-        v = lax.dynamic_update_slice(cache["v"], vs.astype(cache["v"].dtype),
-                                     (0, slot, 0, 0, 0))
-        return {"k": k, "v": v}
-
-    def _decode_step(params, cache, tokens, positions, key):
-        params = _cast_matmul_params(cfg, params)
-        B = tokens.shape[0]
-        S = cache["k"].shape[2]
-        n_rep = cfg.n_heads // cfg.n_kv_heads
-        x = params["embed"].astype(cfg.dtype)[tokens][:, None, :]  # [B,1,E]
-        x = _constrain(x, "batch", "seq", "embed")
-        pos2 = positions[:, None]  # [B,1]
-        rows = jnp.arange(B)[:, None]
-        kvalid = jnp.arange(S)[None, :] <= pos2  # [B,S] incl. this token
-
-        def layer_decode(x, per_layer):
-            lp, kc, vc = per_layer
-            # q [B,1,H,D]; k, v [B,1,KV,D]
-            q, k, v = _qkv(x, lp, cfg, cos, sin, positions=pos2)
-            # write this token's K/V at each slot's own position
-            kc = kc.at[rows, pos2].set(k.astype(kc.dtype))
-            vc = vc.at[rows, pos2].set(v.astype(vc.dtype))
-            attn = _cached_attend(q, kc, vc, kvalid[:, None, :], scale, n_rep)
-            x = x + jnp.einsum("bshd,hde->bse", attn, lp["wo"])
-            h2 = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-            x = x + _mlp(h2, lp, cfg, _constrain)
-            x = _constrain(x, "batch", "seq", "embed")
-            return x, (kc, vc)
-
-        x, (k_new, v_new) = lax.scan(
-            layer_decode, x, (params["layers"], cache["k"], cache["v"])
-        )
-        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-        logits = jnp.einsum("be,ev->bv", x[:, 0], _unembed_matrix(cfg, params))
-        logits = _constrain(logits, "batch", "vocab")
-        return _sample(logits, key), logits, {"k": k_new, "v": v_new}
-
-    prefill = jax.jit(_prefill)
-    write_cache = jax.jit(_write_cache, donate_argnums=(0,))
-    decode_step = jax.jit(_decode_step, donate_argnums=(1,))
-    return prefill, write_cache, decode_step
 
 
 def make_loss_fn(cfg: TransformerConfig, rules=None, mesh=None):

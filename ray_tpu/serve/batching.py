@@ -311,10 +311,16 @@ class GenerationStream:
 class ContinuousBatcher:
     """Token-granularity continuous batching over a slot-based engine.
 
-    engine contract (see ray_tpu.models.decoding.DecodeEngine):
-      admit(slot, request) -> (token, done)
-      step(slots)          -> {slot: (token, done)}
-      release(slot)          optional
+    engine contract (ray_tpu.models.kv_paging.PagedDecodeEngine is the
+    implementation; a test may hand any object with these):
+      admit(slot, request) -> (token, done)   prefill `request["tokens"]`
+        into the free slot; the first sampled token, and whether the
+        generation is already over (eos, or `max_new_tokens` of 1)
+      step(slots)          -> {slot: (token, done)}   ONE decode step for
+        every listed slot together, whatever their sequence lengths
+      release(slot)          optional: the slot's generation has ended or
+        been cut; free what it holds
+      stats() -> dict        optional: merged into the batcher's stats()
 
     A step result may also carry a token LIST per slot (speculative
     decoding: PagedDecodeEngine with speculative_k > 0 emits 1..k+1

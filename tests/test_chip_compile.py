@@ -232,7 +232,7 @@ def _compile_paged_program(one_chip, monkeypatch, program, impl, int8,
     kv_dtype = jnp.int8 if int8 else None
     prefill, decode, verify, _ = make_paged_decoder(
         cfg, block_tokens=BLOCK_TOKENS, kv_dtype=kv_dtype,
-        attention_impl=impl, fused_impl="kernel",
+        attention_impl=impl,
     )
 
     def on_chip(tree):

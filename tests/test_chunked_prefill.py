@@ -76,7 +76,7 @@ def test_chunked_equals_whole_prompt_token_for_token(tiny_f32):
     cfg, params = tiny_f32
     prompt = _prompt(cfg, 90)
     ref = _gen(_build(cfg, params), 0, prompt, 10)
-    for impl in ("gather", "fused:xla"):
+    for impl in ("gather", "fused"):
         for chunk in (16, 12, 1):
             eng = _build(cfg, params, chunk=chunk, impl=impl)
             got = _gen(eng, 0, prompt, 10)
@@ -92,7 +92,7 @@ def test_chunked_int8_matches_whole_prompt_int8(tiny_f32):
     cfg, params = tiny_f32
     prompt = _prompt(cfg, 70, seed=3)
     ref = _gen(_build(cfg, params, dtype="int8"), 0, prompt, 10)
-    for impl in ("gather", "fused:xla"):
+    for impl in ("gather", "fused"):
         got = _gen(
             _build(cfg, params, chunk=12, impl=impl, dtype="int8"),
             0, prompt, 10,
@@ -120,7 +120,7 @@ def test_chunked_fused_matches_under_sharded_mesh(tiny_f32):
             assert got == ref
         else:  # int8 vs its own solo int8 engine
             solo = _gen(
-                _build(cfg, params, chunk=12, impl="fused:xla",
+                _build(cfg, params, chunk=12, impl="fused",
                        dtype="int8"),
                 0, prompt, 8,
             )
@@ -133,7 +133,7 @@ def test_chunked_prefill_prefix_cache_interaction(tiny_f32):
     only the remainder chunks in — tokens identical, prefill work cut."""
     cfg, params = tiny_f32
     prompt = _prompt(cfg, 50, seed=5)
-    eng = _build(cfg, params, chunk=12, impl="fused:xla")
+    eng = _build(cfg, params, chunk=12, impl="fused")
     cold = _gen(eng, 0, prompt, 6)
     cold_tokens = eng.prefill_tokens
     hit = _gen(eng, 0, prompt, 6)
@@ -156,7 +156,7 @@ def test_fused_verify_matches_gather_long_context(tiny_f32):
     for dtype in ("fp", "int8"):
         base = _gen(_build(cfg, params, dtype=dtype), 0, prompt, 24)
         outs = {}
-        for impl in ("gather", "fused:xla"):
+        for impl in ("gather", "fused"):
             eng = _build(
                 cfg, params, impl=impl, dtype=dtype, speculative_k=4,
                 drafter=ReplayDrafter([list(prompt) + base]),
@@ -164,7 +164,7 @@ def test_fused_verify_matches_gather_long_context(tiny_f32):
             outs[impl] = _gen(eng, 0, prompt, 24)
             assert eng.spec_steps > 0, (impl, dtype)  # verify path ran
             assert outs[impl] == base, (impl, dtype)
-        assert outs["gather"] == outs["fused:xla"], dtype
+        assert outs["gather"] == outs["fused"], dtype
 
 
 def test_fused_verify_matches_gather_under_sharded_mesh(tiny_f32):
@@ -195,7 +195,7 @@ def test_decode_never_stalls_during_chunked_prefill(tiny_f32):
     chunks. EVERY shared step must advance slot 0 by a token — zero
     stalled steps — and slot 1 reports [] until its prompt is consumed."""
     cfg, params = tiny_f32
-    eng = _build(cfg, params, chunk=12, impl="fused:xla", B=2)
+    eng = _build(cfg, params, chunk=12, impl="fused", B=2)
     short = _prompt(cfg, 10, seed=8)
     long = _prompt(cfg, 120, seed=9)
     ref_short = _gen(_build(cfg, params), 0, short, 40)
@@ -244,7 +244,7 @@ def test_batcher_streams_complete_with_chunked_prefill(tiny_f32):
     ref_short = _gen(_build(cfg, params), 0, short, 30)
     ref_long = _gen(_build(cfg, params), 0, long, 10)
 
-    eng = _build(cfg, params, chunk=12, impl="fused:xla", B=2)
+    eng = _build(cfg, params, chunk=12, impl="fused", B=2)
     b = ContinuousBatcher(eng, max_batch_size=2, batch_wait_timeout_s=0.0)
     try:
         s1 = b.submit(tokens=short, max_new_tokens=30)
@@ -273,7 +273,7 @@ def test_chunked_prefill_composes_with_speculation(tiny_f32):
     prompt = _prompt(cfg, 80, seed=12)
     ref = _gen(_build(cfg, params), 0, prompt, 16)
     eng = _build(
-        cfg, params, chunk=12, impl="fused:xla", speculative_k=4,
+        cfg, params, chunk=12, impl="fused", speculative_k=4,
         drafter=ReplayDrafter([list(prompt) + ref]),
     )
     got = _gen(eng, 0, prompt, 16)

@@ -7,7 +7,7 @@ loud drops), sharded load places leaves by the existing partition
 rules, and — the acceptance gate — the fixture checkpoint loaded
 through the hub produces token-for-token identical greedy output to an
 independent dense reference forward, for fp and int8-KV engines, gather
-and fused:xla attention. Everything offline against tests/fixtures."""
+and fused attention. Everything offline against tests/fixtures."""
 
 import dataclasses
 import json
@@ -324,9 +324,9 @@ def fixture_prompts(bundle):
 
 @pytest.mark.parametrize("kv_dtype,attn", [
     ("fp", "gather"),
-    ("fp", "fused:xla"),
+    ("fp", "fused"),
     ("int8", "gather"),
-    ("int8", "fused:xla"),
+    ("int8", "fused"),
 ])
 def test_greedy_parity_vs_dense_reference(bundle, fixture_prompts,
                                           kv_dtype, attn):

@@ -1,7 +1,8 @@
 """Paged KV-cache correctness + chaos (ISSUE 5 acceptance).
 
-The paged subsystem must be INVISIBLE to the tokens: paged decode ==
-dense decode token-for-token (solo and under the dp x fsdp x tp dryrun),
+The paged subsystem must be INVISIBLE to the tokens: paged decode == a
+greedy roll-out of the plain forward, token for token (solo and under the
+dp x fsdp x tp dryrun), the fused block walk == the gather programs,
 prefix hits skip prefill without changing output, copy-on-write isolates
 forked generations, and a preemption storm — admitting past the block
 pool's capacity — never crashes and every generation still completes
@@ -9,6 +10,7 @@ exactly as an unconstrained run would (recompute-on-readmit, greedy).
 """
 
 import dataclasses
+import functools
 import threading
 import time
 
@@ -18,7 +20,7 @@ import numpy as np
 import pytest
 
 from _held_tree import check_held_tree
-from ray_tpu.models import CONFIGS, DecodeEngine, init_params
+from ray_tpu.models import CONFIGS, init_params, make_forward
 from ray_tpu.models.kv_paging import (
     BlockAllocator,
     InsufficientBlocksError,
@@ -50,6 +52,32 @@ def _gen(eng, slot, prompt, n):
         out.append(tok)
     eng.release(slot)
     return out
+
+
+@functools.lru_cache(maxsize=None)
+def _forward(cfg):
+    return jax.jit(make_forward(cfg))
+
+
+def _assert_follows_forward(cfg, params, prompt, out, tol=1e-3, width=64):
+    """`out` is the greedy roll-out of the plain forward, the reference every
+    engine is held to: the WHOLE sequence is re-run through `make_forward`
+    for each token (padded to one width so it compiles once; causal
+    attention never sees the padding). Tokens are exact wherever the
+    forward's top-two gap exceeds `tol`; past a nearer tie the streams may
+    part."""
+    forward = _forward(cfg)
+    seq = np.zeros(width, np.int32)
+    seq[:len(prompt)] = prompt
+    for i, have in enumerate(out):
+        at = len(prompt) + i
+        logits = np.asarray(forward(params, seq[None]))[0, at - 1]
+        want = int(np.argmax(logits))
+        if want != have:
+            top2 = np.sort(logits.astype(np.float32))[-2:]
+            assert top2[1] - top2[0] < tol, (i, want, have, top2)
+            return
+        seq[at] = want
 
 
 # ------------------------------------------------------------- allocator
@@ -87,60 +115,69 @@ def test_prefix_cache_eviction_is_leaf_first():
     assert cache.match_count(prompt, 3) == 2
 
 
-# ------------------------------------------------- paged == dense parity
+# ----------------------------------------------- paged == forward parity
 
 
-def test_paged_equals_dense_token_for_token(tiny_f32):
-    """The acceptance contract: the paged engine's greedy output is
-    IDENTICAL to the dense engine's, across interleaved multi-slot decode
+def _interleaved(eng, prompts, lens):
+    """Greedy generation of every prompt at once, one slot each, through
+    the engine contract: {slot: tokens}."""
+    outs, active = {}, []
+    for s, p in enumerate(prompts):
+        tok, done = eng.admit(s, {"tokens": p, "max_new_tokens": lens[s]})
+        outs[s] = [tok]
+        if not done:
+            active.append(s)
+    while active:
+        for s, (tok, done) in eng.step(list(active)).items():
+            outs[s].append(tok)
+            if done:
+                active.remove(s)
+                eng.release(s)
+    return outs
+
+
+def test_paged_equals_forward_token_for_token(tiny_f32):
+    """The acceptance contract: the paged engine's greedy output is the
+    plain forward's greedy roll-out, across interleaved multi-slot decode
     with different prompt lengths (block boundaries land mid-generation)."""
     cfg, params = tiny_f32
     prompts = _prompts(cfg, (5, 9, 17, 30))
-    dense = DecodeEngine(cfg, params, max_batch_size=4)
-    paged = PagedDecodeEngine(cfg, params, max_batch_size=4, block_tokens=8)
-
-    for eng in (dense, paged):
-        outs = {}
-        lens = {0: 12, 1: 9, 2: 20, 3: 5}
-        active = []
-        for s, p in enumerate(prompts):
-            tok, done = eng.admit(s, {"tokens": p, "max_new_tokens": lens[s]})
-            outs[s] = [tok]
-            if not done:
-                active.append(s)
-        while active:
-            for s, (tok, done) in eng.step(list(active)).items():
-                outs[s].append(tok)
-                if done:
-                    active.remove(s)
-                    eng.release(s)
-        if eng is dense:
-            expect = outs
-    assert outs == expect
+    paged = PagedDecodeEngine(
+        cfg, params, max_batch_size=4, block_tokens=8, attention_impl="gather"
+    )
+    lens = {0: 12, 1: 9, 2: 20, 3: 5}
+    outs = _interleaved(paged, prompts, lens)
+    for s, p in enumerate(prompts):
+        assert len(outs[s]) == lens[s]
+        _assert_follows_forward(cfg, params, p, outs[s])
 
 
-def test_paged_matches_dense_under_sharded_mesh(tiny_f32):
+def test_paged_matches_forward_under_sharded_mesh(tiny_f32):
     """dp x fsdp x tp dryrun: the pool shards by KV_CACHE_AXES (blocks on
     the batch axes, kv_heads on tp) and the tokens still match the
-    unsharded dense engine exactly."""
+    unsharded forward's roll-out."""
     cfg, params = tiny_f32
     mesh = build_mesh(MeshSpec(dp=2, fsdp=2, tp=2))
     rules = PRESET_RULES["fsdp_tp"]
     paged = PagedDecodeEngine(
-        cfg, params, max_batch_size=4, block_tokens=8, rules=rules, mesh=mesh
+        cfg, params, max_batch_size=4, block_tokens=8, rules=rules, mesh=mesh,
+        attention_impl="gather",
     )
     spec = paged.pool["k"].sharding.spec
     assert spec[1] == ("dp", "fsdp") and spec[3] == "tp", spec
     assert paged.num_blocks % 4 == 0  # whole shards on dp x fsdp
 
-    dense = DecodeEngine(cfg, params, max_batch_size=4)
     for i, p in enumerate(_prompts(cfg, (7, 19))):
-        assert _gen(paged, i, p, 8) == _gen(dense, i, p, 8), i
+        _assert_follows_forward(cfg, params, p, _gen(paged, i, p, 8))
 
 
-def test_paged_prefill_buckets_do_not_change_output(tiny_f32):
+@pytest.mark.parametrize("length,buckets", [(11, ((16,), (64,))),
+                                            (30, ((32,), (128,)))])
+def test_paged_prefill_buckets_do_not_change_output(tiny_f32, length, buckets):
+    """Prompt padding to a larger bucket must be invisible: only positions
+    < length are ever attended."""
     cfg, params = tiny_f32
-    prompt = _prompts(cfg, (11,))[0]
+    prompt = _prompts(cfg, (length,))[0]
 
     def run(buckets):
         eng = PagedDecodeEngine(
@@ -149,7 +186,7 @@ def test_paged_prefill_buckets_do_not_change_output(tiny_f32):
         )
         return _gen(eng, 0, prompt, 6)
 
-    assert run((16,)) == run((64,))
+    assert run(buckets[0]) == run(buckets[1])
 
 
 # ------------------------------------------------------------ prefix reuse
@@ -606,8 +643,6 @@ def test_paged_int8_greedy_matches_fp(tiny_f32):
     flip). Free-running identity on a random tiny model hinges on exactly
     those near-ties: one flip re-seeds everything downstream. The two int8
     implementations — gather and the fused block walk — agree exactly."""
-    from ray_tpu.models import make_forward
-
     cfg, params = tiny_f32
     n_new = 12
     prompts = _prompts(cfg, (5, 9, 17, 30))
@@ -632,7 +667,7 @@ def test_paged_int8_greedy_matches_fp(tiny_f32):
         assert eng.stats()["kv_cache_dtype"] == "int8"
     assert got["fused"] == got["gather"]
 
-    forward = jax.jit(make_forward(cfg))
+    forward = _forward(cfg)
     for p, r, out in zip(prompts, ref, got["gather"]):
         seq = np.concatenate([p, r[:-1]]).astype(np.int32)
         logits = np.asarray(forward(params, seq[None]))[0, len(p) - 1:]
@@ -643,79 +678,78 @@ def test_paged_int8_greedy_matches_fp(tiny_f32):
             assert want == have or gap[i] < 0.2, (i, want, have, gap[i])
 
 
-def test_fused_paged_matches_dense(tiny_f32):
+def test_fused_paged_matches_gather(tiny_f32, monkeypatch):
     """The fused decode step (block-in-place attention, no [B, W] gather)
-    against the DENSE engine, interleaved multi-slot — including the
-    interpret-mode Pallas kernel for a couple of steps so tier-1 proves
-    the kernel inside the real decode loop, not just standalone."""
+    against the gather engine — the exact reference, itself held to the
+    plain forward above — interleaved multi-slot; then the interpret-mode
+    Pallas kernel for a couple of steps, so tier-1 proves the kernel
+    inside the real decode loop, not just standalone."""
+    import importlib
+
     cfg, params = tiny_f32
     prompts = _prompts(cfg, (5, 9, 17, 30))
-    dense = DecodeEngine(cfg, params, max_batch_size=4)
-    fused = PagedDecodeEngine(
-        cfg, params, max_batch_size=4, block_tokens=8,
-        attention_impl="fused",
+    lens = dict.fromkeys(range(4), 10)
+    gather = PagedDecodeEngine(
+        cfg, params, max_batch_size=4, block_tokens=8, attention_impl="gather"
     )
-    for eng in (dense, fused):
-        outs = {}
-        active = []
-        for s, p in enumerate(prompts):
-            tok, done = eng.admit(s, {"tokens": p, "max_new_tokens": 10})
-            outs[s] = [tok]
-            if not done:
-                active.append(s)
-        while active:
-            for s, (tok, done) in eng.step(list(active)).items():
-                outs[s].append(tok)
-                if done:
-                    active.remove(s)
-                    eng.release(s)
-        if eng is dense:
-            expect = outs
-    assert outs == expect
+    fused = PagedDecodeEngine(
+        cfg, params, max_batch_size=4, block_tokens=8, attention_impl="fused"
+    )
+    expect = _interleaved(gather, prompts, lens)
+    assert _interleaved(fused, prompts, lens) == expect
     assert fused.stats()["attention_impl"] == "fused"
+    assert fused.stats()["attention_kernel"] == "xla"  # no chip here
 
-    # the Pallas kernel (interpret mode) through the engine contract
+    # the Pallas kernel (interpret mode) through the engine contract: off
+    # the chip the op picks its XLA twin, so the op the programs call is
+    # swapped for the kernel here, in the test
+    op = importlib.import_module("ray_tpu.ops.paged_attention")
+    monkeypatch.setattr(
+        op, "paged_attention",
+        functools.partial(op.paged_attention, impl="kernel"),
+    )
     kern = PagedDecodeEngine(
-        cfg, params, max_batch_size=1, block_tokens=8,
-        attention_impl="fused:kernel",
+        cfg, params, max_batch_size=1, block_tokens=8, attention_impl="fused"
     )
     assert _gen(kern, 0, prompts[0], 4) == expect[0][:4]
+    assert op._LAST_IMPL == "kernel"
 
 
-def test_fused_chunk_blocks_tuning_is_invisible_to_tokens(tiny_f32):
-    """`chunk_blocks` tunes the fused-XLA walk's gather granularity only —
-    any value (including one that doesn't divide the block-table length)
-    must produce the same greedy tokens as the gather reference."""
+def test_attention_path_is_picked_once_from_the_platform(tiny_f32):
+    """One decision, made at construction: `attention_impl=None` is "fused"
+    on a TPU and "gather" anywhere else, by the platform the engine
+    reports; "gather" and "fused" are the two seams the tests hold to each
+    other, and anything else — the old `auto`, a backend's name — is
+    refused before a request is admitted."""
     cfg, params = tiny_f32
-    prompts = _prompts(cfg, (5, 17, 30))
-    ref_eng = PagedDecodeEngine(cfg, params, max_batch_size=2, block_tokens=8)
-    ref = [_gen(ref_eng, i % 2, p, 10) for i, p in enumerate(prompts)]
-    for cb in (1, 3):
-        eng = PagedDecodeEngine(
-            cfg, params, max_batch_size=2, block_tokens=8,
-            attention_impl="fused:xla", chunk_blocks=cb,
-        )
-        got = [_gen(eng, i % 2, p, 10) for i, p in enumerate(prompts)]
-        assert got == ref, cb
-        assert eng.stats()["attention_chunk_blocks"] == cb
-    # a typo'd knob fails at replica construction, not first-step trace
-    with pytest.raises(ValueError, match="chunk_blocks"):
-        PagedDecodeEngine(
-            cfg, params, max_batch_size=2, block_tokens=8, chunk_blocks=0
-        )
+    eng = PagedDecodeEngine(cfg, params, max_batch_size=1, block_tokens=8)
+    stats = eng.stats()
+    assert stats["platform"] == jax.devices()[0].platform
+    want = "fused" if stats["platform"] == "tpu" else "gather"
+    assert stats["attention_impl"] == want
+    assert stats["attention_kernel"] == {"fused": "pallas",
+                                         "gather": "gather"}[want]
+    for bad in ("auto", "kernel", "xla", "fused:pallas", ""):
+        with pytest.raises(ValueError, match="attention_impl"):
+            PagedDecodeEngine(
+                cfg, params, max_batch_size=1, block_tokens=8,
+                attention_impl=bad,
+            )
 
 
-def test_fused_matches_dense_under_sharded_mesh(tiny_f32):
+def test_fused_matches_gather_under_sharded_mesh(tiny_f32):
     """dp x fsdp x tp dryrun of the FUSED path: blocks sharded across
     dp/fsdp mean each shard sees a slice of the pool — the shard_map
     wrapper remaps global block ids, attends locally, and log-sum-exp
     merges the partial softmax. Tokens must still match the unsharded
-    dense engine exactly (fp) and the int8 run must agree with solo
+    gather engine exactly (fp) and the int8 run must agree with solo
     int8."""
     cfg, params = tiny_f32
     mesh = build_mesh(MeshSpec(dp=2, fsdp=2, tp=2))
     rules = PRESET_RULES["fsdp_tp"]
-    dense = DecodeEngine(cfg, params, max_batch_size=4)
+    gather = PagedDecodeEngine(
+        cfg, params, max_batch_size=4, block_tokens=8, attention_impl="gather"
+    )
     fused = PagedDecodeEngine(
         cfg, params, max_batch_size=4, block_tokens=8, rules=rules,
         mesh=mesh, attention_impl="fused",
@@ -723,7 +757,7 @@ def test_fused_matches_dense_under_sharded_mesh(tiny_f32):
     spec = fused.pool["k"].sharding.spec
     assert spec[1] == ("dp", "fsdp") and spec[3] == "tp", spec
     for i, p in enumerate(_prompts(cfg, (7, 19))):
-        assert _gen(fused, i, p, 8) == _gen(dense, i, p, 8), i
+        assert _gen(fused, i, p, 8) == _gen(gather, i, p, 8), i
 
     solo8 = PagedDecodeEngine(
         cfg, params, max_batch_size=4, block_tokens=8,

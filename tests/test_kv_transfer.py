@@ -3,7 +3,7 @@
 The transfer path must be INVISIBLE to the tokens: a replica that imports
 a peer's prefix blocks continues greedy generation token-for-token
 identically to a cold monolithic replica — fp and int8 pools, gather and
-fused:xla attention — while its prefill counters prove the prefix was
+fused attention — while its prefill counters prove the prefix was
 imported, not recomputed. Content-addressed keys are deterministic across
 processes and disjoint across engine geometry (a poisoned int8 payload
 must never enter an fp pool). Disaggregated prefill/decode is greedy-
@@ -122,8 +122,8 @@ def test_poison_int8_block_never_imports_into_fp_pool(tiny_params):
 
 @pytest.mark.parametrize(
     "kv_dtype,attn",
-    [("fp", "gather"), ("fp", "fused:xla"),
-     ("int8", "gather"), ("int8", "fused:xla")],
+    [("fp", "gather"), ("fp", "fused"),
+     ("int8", "gather"), ("int8", "fused")],
     ids=["fp-gather", "fp-fusedxla", "int8-gather", "int8-fusedxla"],
 )
 def test_import_resumes_token_identical(tiny_params, kv_dtype, attn):
@@ -297,7 +297,7 @@ def _reference_tokens(kv_dtype="fp", attn="gather", n=8):
 
 
 @pytest.mark.parametrize(
-    "kv_dtype,attn", [("fp", "gather"), ("int8", "fused:xla")],
+    "kv_dtype,attn", [("fp", "gather"), ("int8", "fused")],
     ids=["fp-gather", "int8-fusedxla"],
 )
 def test_cross_replica_prefix_hit_e2e(serve_cluster, kv_dtype, attn):

@@ -4,7 +4,7 @@ Acceptance (ISSUE 20):
   - learning gate: PPO and GRPO mean reward improves in trend on a toy
     token task, pinned seeds;
   - logprob parity: the engine's streamed behavior logprobs match a dense
-    teacher-forced re-forward on the sampled ids (gather and fused:xla
+    teacher-forced re-forward on the sampled ids (gather and fused
     attention);
   - swap gate: >= 4 in-flight SSE streams survive a live weight swap — no
     stream drops, the post-swap continuation is greedy-identical to a
@@ -82,7 +82,7 @@ def _dense_reward(prompt, resp):
 # ----------------------------------------------------------- logprob parity
 
 
-@pytest.mark.parametrize("attn", ["gather", "fused:xla"])
+@pytest.mark.parametrize("attn", ["gather", "fused"])
 def test_engine_logprobs_match_dense_reforward(attn):
     """The (token, logprob) pairs the engine streams are the logprobs of
     the ACTUAL sampling distribution: a dense teacher-forced re-forward

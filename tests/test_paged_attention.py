@@ -88,7 +88,7 @@ def _quantize_pool(kp):
     return q8, sc
 
 
-@pytest.mark.parametrize("chunk_blocks", [1, 2, 8])
+@pytest.mark.parametrize("chunk_blocks", [1, 2, 3, 8])
 def test_xla_matches_reference(chunk_blocks):
     q, kp, vp, tables, positions = _setup()
     ref = _dense_reference(q, kp, vp, tables, positions)

@@ -162,16 +162,16 @@ def _sse_client(host, port, body_obj, out, key):
 
 
 def test_generation_e2e_interleaved_sse(serve_cluster):
-    """4 concurrent clients against the REAL DecodeEngine (tiny model):
+    """4 concurrent clients against the REAL PagedDecodeEngine (tiny model):
     generations share one running batch (occupancy counters prove it) and
     every token arrives as its own SSE event over chunked transfer."""
 
     @serve.deployment
     class Gen:
         def __init__(self):
-            from ray_tpu.models import CONFIGS, DecodeEngine
+            from ray_tpu.models import CONFIGS, PagedDecodeEngine
 
-            self.engine = DecodeEngine(
+            self.engine = PagedDecodeEngine(
                 CONFIGS["tiny"], max_batch_size=4, seed=0,
                 prefill_buckets=(16,),
             )

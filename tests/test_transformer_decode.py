@@ -1,6 +1,8 @@
-"""KV-cache decode correctness: cached single-token decode must reproduce
-the full-context forward exactly (same prefix -> same logits), solo and
-under a sharded mesh dryrun — the contract the serving fast path rests on.
+"""KV-cache decode correctness: paged prefill + cached single-token decode
+(the exact "gather" programs) must reproduce the full-context forward (same
+prefix -> same logits), solo and under a sharded mesh dryrun — the contract
+the serving fast path rests on — and the engine that drives them keeps its
+slots isolated.
 """
 
 import dataclasses
@@ -12,13 +14,15 @@ import pytest
 
 from ray_tpu.models import (
     CONFIGS,
-    DecodeEngine,
-    init_kv_cache,
+    PagedDecodeEngine,
+    init_paged_kv_cache,
     init_params,
-    make_decoder,
     make_forward,
+    make_paged_decoder,
 )
 from ray_tpu.parallel import MeshSpec, PRESET_RULES, build_mesh
+
+BT = 8  # block_tokens: block boundaries land inside prefix and decode
 
 
 def _f32(name):
@@ -39,25 +43,35 @@ def _tokens(cfg, b, t, seed=0):
 
 def _assert_decode_matches(cfg, params, rules=None, mesh=None,
                            b=2, prefix=8, total=20, tol=1e-3):
-    """Prefill `prefix` tokens, then teacher-force decode steps; every
-    step's logits must match the full forward at the same position."""
+    """Prefill `prefix` tokens of each sequence into its own blocks, then
+    teacher-force batched decode steps; every step's logits must match the
+    full forward at the same position."""
     tokens = _tokens(cfg, b, total)
     full = np.asarray(make_forward(cfg)(params, jnp.asarray(tokens)))
 
-    prefill, write_cache, decode_step = make_decoder(cfg, rules, mesh)
-    cache = init_kv_cache(cfg, b, mesh=mesh, rules=rules)
+    prefill, decode_step, _, _ = make_paged_decoder(
+        cfg, rules, mesh, block_tokens=BT, attention_impl="gather")
+    per_slot = -(-total // BT)
+    # a pool of 1 + b * per_slot blocks, rounded up to whole (dp, fsdp) shards
+    num_blocks = -(-(1 + b * per_slot) // 4) * 4
+    pool = init_paged_kv_cache(cfg, num_blocks, BT, mesh=mesh, rules=rules)
+    # slot i owns blocks 1 + i*per_slot ...; block 0 is the null block
+    tables = 1 + np.arange(b * per_slot, dtype=np.int32).reshape(b, per_slot)
     key = jax.random.PRNGKey(1)
-    _, logits, ks, vs = prefill(
-        params, tokens[:, :prefix], np.full(b, prefix, np.int32), key
-    )
-    cache = write_cache(cache, ks, vs, 0)
-    np.testing.assert_allclose(
-        np.asarray(logits), full[:, prefix - 1], rtol=tol, atol=tol
-    )
+    for i in range(b):
+        _, logits, pool = prefill(
+            params, pool, tables[i], tokens[i:i + 1, :prefix],
+            np.int32(prefix), np.int32(0), key, ctx_blocks=0,
+        )
+        np.testing.assert_allclose(
+            np.asarray(logits)[0], full[i, prefix - 1], rtol=tol, atol=tol
+        )
     positions = np.full(b, prefix, np.int32)
+    rows = np.arange(b)
     for t in range(prefix, total - 1):
-        _, logits, cache = decode_step(
-            params, cache, tokens[:, t], positions, key
+        _, logits, pool, _ = decode_step(
+            params, pool, tables, tokens[:, t], positions,
+            tables[rows, positions // BT], positions % BT, key,
         )
         np.testing.assert_allclose(
             np.asarray(logits), full[:, t], rtol=tol, atol=tol
@@ -81,15 +95,20 @@ def test_decode_matches_forward_bf16(tiny_f32):
 
 def test_decode_matches_under_sharded_mesh(tiny_f32):
     """The acceptance dryrun: decode under a dp x fsdp x tp mesh matches
-    the unsharded forward, and the cache carries the activation sharding
-    (batch on dp/fsdp slots, kv_heads on tp)."""
+    the unsharded forward, and the pool carries the activation sharding
+    (blocks on dp/fsdp, kv_heads on tp)."""
     cfg, params = tiny_f32
     mesh = build_mesh(MeshSpec(dp=2, fsdp=2, tp=2))
     rules = PRESET_RULES["fsdp_tp"]
-    cache = init_kv_cache(cfg, 4, mesh=mesh, rules=rules)
-    spec = cache["k"].sharding.spec
+    pool = init_paged_kv_cache(cfg, 8, BT, mesh=mesh, rules=rules)
+    spec = pool["k"].sharding.spec
     assert spec[1] == ("dp", "fsdp") and spec[3] == "tp", spec
     _assert_decode_matches(cfg, params, rules=rules, mesh=mesh, b=4)
+
+
+def _engine(cfg, params, **kw):
+    return PagedDecodeEngine(
+        cfg, params, block_tokens=BT, attention_impl="gather", **kw)
 
 
 def test_engine_batched_equals_solo_greedy(tiny_f32):
@@ -97,7 +116,7 @@ def test_engine_batched_equals_solo_greedy(tiny_f32):
     fresh single-slot engine: slots are fully isolated."""
     cfg, params = tiny_f32
     tokens = _tokens(cfg, 2, 12)
-    eng = DecodeEngine(cfg, params, max_batch_size=4)
+    eng = _engine(cfg, params, max_batch_size=4)
     t0, _ = eng.admit(0, {"tokens": tokens[0, :5], "max_new_tokens": 6})
     t1, _ = eng.admit(2, {"tokens": tokens[1, :9], "max_new_tokens": 4})
     outs = {0: [t0], 2: [t1]}
@@ -110,7 +129,7 @@ def test_engine_batched_equals_solo_greedy(tiny_f32):
                 eng.release(slot)
     assert len(outs[0]) == 6 and len(outs[2]) == 4
 
-    solo = DecodeEngine(cfg, params, max_batch_size=1)
+    solo = _engine(cfg, params, max_batch_size=1)
     tok, done = solo.admit(0, {"tokens": tokens[0, :5], "max_new_tokens": 6})
     got = [tok]
     while not done:
@@ -120,8 +139,9 @@ def test_engine_batched_equals_solo_greedy(tiny_f32):
 
 
 def test_engine_slot_reuse_is_clean(tiny_f32):
-    """A retired slot's cache residue must not leak into the next sequence
-    admitted to the same slot."""
+    """A retired slot's cache residue (its blocks go back to the pool
+    unwiped, and the prefix cache may keep some) must not leak into the
+    next sequence admitted to the same slot."""
     cfg, params = tiny_f32
     tokens = _tokens(cfg, 2, 12)
 
@@ -134,38 +154,18 @@ def test_engine_slot_reuse_is_clean(tiny_f32):
         eng.release(slot)
         return out
 
-    eng = DecodeEngine(cfg, params, max_batch_size=2)
+    eng = _engine(cfg, params, max_batch_size=2)
     first = _gen(eng, 0, tokens[0, :7], 5)
     second = _gen(eng, 0, tokens[1, :4], 5)  # same slot, new sequence
-    fresh = DecodeEngine(cfg, params, max_batch_size=2)
+    fresh = _engine(cfg, params, max_batch_size=2)
     assert _gen(fresh, 0, tokens[1, :4], 5) == second
     assert _gen(fresh, 1, tokens[0, :7], 5) == first
-
-
-def test_prefill_buckets_do_not_change_output(tiny_f32):
-    """Prompt padding to a larger bucket must be invisible: only positions
-    < length are ever attended."""
-    cfg, params = tiny_f32
-    prompt = _tokens(cfg, 1, 11)[0]
-
-    def _gen(buckets):
-        eng = DecodeEngine(
-            cfg, params, max_batch_size=1, prefill_buckets=buckets
-        )
-        tok, done = eng.admit(0, {"tokens": prompt, "max_new_tokens": 6})
-        out = [tok]
-        while not done:
-            tok, done = eng.step([0])[0]
-            out.append(tok)
-        return out
-
-    assert _gen((16,)) == _gen((64,))
 
 
 def test_engine_eos_and_cap(tiny_f32):
     cfg, params = tiny_f32
     prompt = _tokens(cfg, 1, 6)[0]
-    eng = DecodeEngine(cfg, params, max_batch_size=1)
+    eng = _engine(cfg, params, max_batch_size=1)
     tok, done = eng.admit(0, {"tokens": prompt, "max_new_tokens": 3})
     n = 1
     while not done:
@@ -174,9 +174,9 @@ def test_engine_eos_and_cap(tiny_f32):
     assert n == 3  # max_new_tokens cap honored
 
     # eos cut: make the first generated token the eos
-    solo = DecodeEngine(cfg, params, max_batch_size=1, eos_id=None)
+    solo = _engine(cfg, params, max_batch_size=1, eos_id=None)
     first, _ = solo.admit(0, {"tokens": prompt, "max_new_tokens": 50})
-    eng2 = DecodeEngine(cfg, params, max_batch_size=1, eos_id=first)
+    eng2 = _engine(cfg, params, max_batch_size=1, eos_id=first)
     _, done2 = eng2.admit(0, {"tokens": prompt, "max_new_tokens": 50})
     assert done2  # stopped at eos immediately
 
