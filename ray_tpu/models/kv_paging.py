@@ -70,6 +70,7 @@ from .transformer import (
     init_params,
     make_paged_decoder,
     paged_kv_block_bytes,
+    refuse_on_latent_pool,
     serving_params,
 )
 
@@ -444,6 +445,9 @@ class PagedDecodeEngine:
         if speculative_k < 0:
             raise ValueError(f"speculative_k must be >= 0, got {speculative_k}")
         self.speculative_k = speculative_k
+        # a latent (MLA) pool: what it does not support fails here, by name
+        refuse_on_latent_pool(cfg, kv_dtype=kv_dtype, mesh=mesh,
+                              speculative_k=speculative_k)
         self.drafter = None
         if speculative_k:
             if temperature > 0.0:
@@ -564,11 +568,10 @@ class PagedDecodeEngine:
                 num_blocks = -(-num_blocks // m) * m
         self.num_blocks = int(num_blocks)
 
-        # a tree built here is nobody else's: its f32 leaves go as they are cast
-        own = params is None
-        self.params = serving_params(
-            cfg, init_params(jax.random.PRNGKey(seed), cfg) if own else params,
-            consume=own,
+        # a tree built here is nobody else's: each leaf is cast as it is drawn
+        self.params = (
+            init_params(jax.random.PRNGKey(seed), cfg, held=True)
+            if params is None else serving_params(cfg, params)
         )
         # swap-time device_put (serve/weight_swap.py) re-distributes a
         # pulled host tree by THIS engine's partition rules
@@ -668,6 +671,12 @@ class PagedDecodeEngine:
             f"|L={self.cfg.n_layers}|H={self.cfg.n_kv_heads}"
             f"|D={self.cfg.d_head}".encode()
         )
+        if self.cfg.kv_lora_rank:
+            # a latent pool: the row's geometry joins the key space, so a
+            # per-head and a latent replica of one model_id refuse each other
+            sig.update(
+                f"|latent={self.cfg.kv_lora_rank}+{self.cfg.qk_rope_head_dim}"
+                f"/{self.cfg.latent_row}".encode())
         # version 0 (never swapped) keeps the original byte layout, so
         # engines that never hot-swap interoperate with older peers; any
         # swap moves the whole key space
@@ -1206,7 +1215,8 @@ class PagedDecodeEngine:
                 # a sparse-expert model: the step's routed (token, expert)
                 # pairs and the load of its fullest expert, both summed
                 # over the layers (pairs * n_experts / hottest = 1: even)
-                pairs = len(surviving) * self.cfg.top_k * self.cfg.n_layers
+                pairs = (len(surviving) * self.cfg.top_k
+                         * self.cfg.n_expert_layers)
                 hottest = int(hottest)
                 step_span.set(moe_pairs=pairs, moe_hottest=hottest)
                 self.moe_pairs += pairs
@@ -1694,8 +1704,11 @@ class PagedDecodeEngine:
             "param_bytes": sum(
                 int(leaf.nbytes) for leaf in jax.tree_util.tree_leaves(self.params)
             ),
-            "param_dtype": np.dtype(self.params["layers"]["wq"].dtype).name,
+            "param_dtype": np.dtype(self.params["embed"].dtype).name,
             "kv_block_bytes": self.kv_block_bytes,
+            # what one resident token costs over all layers, by the pool's
+            # own leaves (a latent pool: one row a layer)
+            "kv_bytes_per_token": self.kv_block_bytes // self.block_tokens,
             # true pool HBM: counts the reserved null block too, so this
             # reconciles exactly with a serve_kv_pool_mb budget
             "kv_pool_bytes": self.kv_block_bytes * self.num_blocks,

@@ -34,11 +34,13 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import zlib
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from ..ops.attention import (
@@ -138,12 +140,75 @@ class TransformerConfig:
     # RMSNorm with a learned scale over a token's WHOLE q and k projection
     # (all heads x d_head), before the split into heads and before RoPE
     qk_norm: bool = False
+    # latent attention (MLA, DeepSeek-V2 §2.1): kv_lora_rank > 0 replaces
+    # wq/wk/wv by the low-rank pairs wq_a/wq_b and wkv_a/wkv_b. A token
+    # caches ONE row per layer — its normed c_kv (kv_lora_rank) and the
+    # roped key every head shares (qk_rope_head_dim) — instead of per-head
+    # K and V; d_head and n_kv_heads are then unused
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN (rope_factor > 1): inv_freq blended between theta^(-2i/d) and
+    # the same over rope_factor, ramped between the correction dims of
+    # beta_fast / beta_slow over rope_original_max positions; latent
+    # attention also scales its scores by (0.1 mscale_all_dim ln factor + 1)^2
+    rope_factor: float = 0.0
+    rope_original_max: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    # the first `first_k_dense` layers keep a dense gated MLP of width
+    # d_ff_dense (params["dense_layers"]); the rest are expert layers
+    first_k_dense: int = 0
+    d_ff_dense: int = 0
+    # router scores: "softmax" over all experts, or "sigmoid" per expert —
+    # then the choice is made on score + router_bias, the weights are the
+    # UNBIASED scores of the chosen (divided by their sum under
+    # moe_renormalize) times moe_route_scale
+    moe_scoring: str = "softmax"
+    moe_route_scale: float = 1.0
+    # experts every token goes through, beside the routed ones: ONE gated
+    # MLP of width n_shared_experts * d_ff
+    n_shared_experts: int = 0
+    # hyper-connections (mHC, arXiv:2512.24880): hc_mult residual streams
+    # per token, mixed around each sublayer by H_pre / H_post / H_res
+    # (`_hc_mix`); 0 is the plain residual add
+    hc_mult: int = 0
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: float = 30.0
 
     def __post_init__(self):
         if self.mlp_variant not in ("silu_gate", "gelu"):
             raise ValueError(
                 f"mlp_variant must be 'silu_gate' or 'gelu', "
                 f"got {self.mlp_variant!r}"
+            )
+        if self.moe_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"moe_scoring must be 'softmax' or 'sigmoid', "
+                f"got {self.moe_scoring!r}"
+            )
+        if not 0 <= self.first_k_dense <= self.n_layers:
+            raise ValueError(
+                f"first_k_dense {self.first_k_dense} outside 0..n_layers "
+                f"{self.n_layers}"
+            )
+        if self.first_k_dense and not (self.n_experts and self.d_ff_dense):
+            raise ValueError(
+                "first_k_dense needs n_experts (the layers after it) and "
+                "d_ff_dense (the width of the dense ones)"
+            )
+        if self.kv_lora_rank and not (
+            self.q_lora_rank and self.qk_nope_head_dim
+            and self.qk_rope_head_dim and self.v_head_dim
+        ):
+            raise ValueError(
+                "latent attention (kv_lora_rank > 0) needs q_lora_rank, "
+                "qk_nope_head_dim, qk_rope_head_dim and v_head_dim"
             )
         if self.pp_interleave < 1:
             raise ValueError(
@@ -154,6 +219,24 @@ class TransformerConfig:
                 f"pp_stages {self.pp_stages} not divisible by "
                 f"pp_interleave {self.pp_interleave}"
             )
+
+    @property
+    def latent_width(self) -> int:
+        """Values a token caches per layer under latent attention."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_row(self) -> int:
+        """Width of the latent pool's rows: `latent_width` rounded up to
+        the TPU's 128 lanes. A 576-wide minor dim gets a device layout with
+        the BLOCK dim minor (padding-free, but every block access becomes a
+        strided walk and the kernel call a whole-pool relayout); 640 keeps
+        rows contiguous. The tail columns stay zero."""
+        return -(-self.latent_width // 128) * 128
+
+    @property
+    def n_expert_layers(self) -> int:
+        return self.n_layers - self.first_k_dense if self.n_experts else 0
 
     def flops_per_token(self) -> float:
         """Approximate training FLOPs/token (fwd+bwd ≈ 6 * params-matmul)."""
@@ -228,22 +311,96 @@ CONFIGS: Dict[str, TransformerConfig] = {
 # --------------------------------------------------------------------------
 
 
+def _hc_width(cfg: TransformerConfig) -> int:
+    """Outputs of one sublayer's hyper-connection projection: n for H_pre,
+    n for H_post, n*n for H_res."""
+    n = cfg.hc_mult
+    return 2 * n + n * n
+
+
+def _extra_leaves(cfg: TransformerConfig, stack: str):
+    """(name, shape without the layer dim, logical axes, kind) of the
+    leaves one layer of `stack` ("layers" | "dense_layers") has BESIDE the
+    llama / expert leaves `init_params` has always drawn: latent attention,
+    router bias, shared expert, hyper-connections. `kind` is the fan-in of
+    a matmul weight, or "ones" / "bias" / "alpha" / "hc_bias". The dense
+    stack's own attention and MLP leaves are all here too."""
+    E, H = cfg.d_model, cfg.n_heads
+    out = []
+    if stack == "dense_layers":
+        # the main stack's attention and MLP leaves come from `init_params`
+        # itself, in the order (and with the keys) they always have
+        Fd, KV, D = cfg.d_ff_dense, cfg.n_kv_heads, cfg.d_head
+        out += [
+            ("w_gate", (E, Fd), ("embed", "mlp"), E),
+            ("w_up", (E, Fd), ("embed", "mlp"), E),
+            ("w_down", (Fd, E), ("mlp", "embed"), Fd),
+        ]
+        if not cfg.kv_lora_rank:
+            out += [
+                ("wq", (E, H, D), ("embed", "heads", "head_dim"), E),
+                ("wk", (E, KV, D), ("embed", "kv_heads", "head_dim"), E),
+                ("wv", (E, KV, D), ("embed", "kv_heads", "head_dim"), E),
+                ("wo", (H, D, E), ("heads", "head_dim", "embed"), H * D),
+            ]
+            if cfg.qk_norm:
+                out += [("q_norm", (H, D), ("heads", "head_dim"), "ones"),
+                        ("k_norm", (KV, D), ("kv_heads", "head_dim"), "ones")]
+    if cfg.kv_lora_rank:
+        R, Q = cfg.kv_lora_rank, cfg.q_lora_rank
+        dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        out += [
+            ("wq_a", (E, Q), ("embed", None), E),
+            ("q_a_norm", (Q,), (None,), "ones"),
+            ("wq_b", (Q, H, dn + dr), (None, "heads", "head_dim"), Q),
+            ("wkv_a", (E, R + dr), ("embed", None), E),
+            ("kv_a_norm", (R,), (None,), "ones"),
+            ("wkv_b", (R, H, dn + dv), (None, "heads", "head_dim"), R),
+            ("wo", (H, dv, E), ("heads", "head_dim", "embed"), H * dv),
+        ]
+    if stack == "layers" and cfg.n_experts:
+        if cfg.moe_scoring == "sigmoid":
+            out.append(("router_bias", (cfg.n_experts,), ("expert",), "bias"))
+        if cfg.n_shared_experts:
+            Fs = cfg.n_shared_experts * cfg.d_ff
+            out += [
+                ("ws_gate", (E, Fs), ("embed", "mlp"), E),
+                ("ws_up", (E, Fs), ("embed", "mlp"), E),
+                ("ws_down", (Fs, E), ("mlp", "embed"), Fs),
+            ]
+    if cfg.hc_mult:
+        nC, W = cfg.hc_mult * E, _hc_width(cfg)
+        for sub in ("attn", "mlp"):
+            out += [
+                (f"hc_{sub}_phi", (nC, W), (None, None), nC),
+                (f"hc_{sub}_alpha", (3,), (None,), "alpha"),
+                (f"hc_{sub}_bias", (W,), (None,), "hc_bias"),
+            ]
+    return out
+
+
 def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
     """Logical-axis tuples mirroring the param pytree. With pp_stages>1 the
     layer leaves carry a leading ("stage",) dim sharded on the pp axis."""
     layer = {
         "attn_norm": ("layers", "embed"),
-        "wq": ("layers", "embed", "heads", "head_dim"),
-        "wk": ("layers", "embed", "kv_heads", "head_dim"),
-        "wv": ("layers", "embed", "kv_heads", "head_dim"),
-        "wo": ("layers", "heads", "head_dim", "embed"),
         "mlp_norm": ("layers", "embed"),
     }
+    if not cfg.kv_lora_rank:
+        layer.update(
+            wq=("layers", "embed", "heads", "head_dim"),
+            wk=("layers", "embed", "kv_heads", "head_dim"),
+            wv=("layers", "embed", "kv_heads", "head_dim"),
+            wo=("layers", "heads", "head_dim", "embed"),
+        )
     if cfg.qk_norm:
         layer.update(
             q_norm=("layers", "heads", "head_dim"),
             k_norm=("layers", "kv_heads", "head_dim"),
         )
+    def extras(stack):
+        return {n: ("layers",) + ax for n, _, ax, _ in _extra_leaves(cfg, stack)}
+
     if cfg.n_experts:
         layer.update(
             router=("layers", "embed", "expert"),
@@ -262,6 +419,7 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
             w_up=("layers", "embed", "mlp"),
             w_down=("layers", "mlp", "embed"),
         )
+    layer.update(extras("layers"))
     if cfg.pp_stages > 1:
         layer = {k: ("stage",) + v for k, v in layer.items()}
     specs = {
@@ -269,52 +427,115 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
         "layers": layer,
         "final_norm": ("embed",),
     }
+    if cfg.first_k_dense:
+        specs["dense_layers"] = {
+            "attn_norm": ("layers", "embed"), "mlp_norm": ("layers", "embed"),
+            **extras("dense_layers")}
     if not cfg.tie_embeddings:
         specs["unembed"] = ("embed", "vocab")
     return specs
 
 
-def init_params(rng: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
+def init_params(rng: jax.Array, cfg: TransformerConfig, *,
+                held: bool = False) -> Dict[str, Any]:
+    """The model's parameter tree from `rng`, float32.
+
+    `held=True` gives the tree a serving replica holds instead
+    (`serving_params` of this one): each matmul leaf is drawn and cast to
+    `cfg.dtype` by one program (`_draw_held`), so the device never holds
+    more than the cast tree and that program's temporaries — a float32
+    tree of a large configuration need not fit the chip at all. The draws
+    are the same either way.
+
+    Leaves every llama / expert configuration has take their keys in the
+    order they always have (a seed's weights do not change); the leaves of
+    `_extra_leaves` and the whole `dense_layers` stack take keys folded from
+    their names."""
     L, E, H, KV, D, F = (
         cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.d_head, cfg.d_ff,
     )
+    Ld = cfg.first_k_dense
+    L = L - Ld  # layers of the main stack
     keys = iter(jax.random.split(rng, 16))
+    dtype = jnp.dtype(cfg.dtype)
 
     def norm_init(*shape):
         return jnp.ones(shape, jnp.float32)
 
-    def dense_init(key, shape, fan_in):
-        return (jax.random.normal(key, shape, jnp.float32) / math.sqrt(fan_in))
+    def dense_init(name, key, shape, fan_in, gain=()):
+        """N(0, 1/fan_in) times `gain`; a leaf the replica holds in
+        `cfg.dtype` (`name`) is drawn and cast by ONE program, so its
+        float32 draw is never a buffer of its own."""
+        factors = tuple(np.float32(g) for g in gain)
+        if held and name in _HELD_KEYS:
+            return _draw_held(key, np.float32(math.sqrt(fan_in)), factors,
+                              shape=shape, dtype=dtype)
+        leaf = jax.random.normal(key, shape, jnp.float32) / math.sqrt(fan_in)
+        for g in gain:
+            leaf = leaf * g
+        return leaf
 
-    layer: Dict[str, Any] = {
-        "attn_norm": norm_init(L, E),
-        "wq": dense_init(next(keys), (L, E, H, D), E),
-        "wk": dense_init(next(keys), (L, E, KV, D), E),
-        "wv": dense_init(next(keys), (L, E, KV, D), E),
-        "wo": dense_init(next(keys), (L, H, D, E), H * D),
-        "mlp_norm": norm_init(L, E),
-    }
+    def named_key(stack, name):
+        return jax.random.fold_in(rng, zlib.crc32(f"{stack}/{name}".encode()))
+
+    def extras(stack, n):
+        out = {}
+        for name, shape, _, kind in _extra_leaves(cfg, stack):
+            key, shape = named_key(stack, name), (n,) + shape
+            if kind == "ones":
+                leaf = norm_init(*shape)
+            elif kind == "bias":
+                # moves the choice between near-equal experts, not all of it
+                leaf = 0.05 * jax.random.normal(key, shape, jnp.float32)
+            elif kind == "alpha":
+                # the three gains of (H_pre, H_post, H_res): 0.1 .. 1, never
+                # the 0 a trained-from-identity init would start at
+                leaf = 0.1 + 0.9 * jax.random.uniform(key, shape, jnp.float32)
+            elif kind == "hc_bias":
+                # H_pre / H_post biases at std 2 (reads and writes that
+                # prefer some streams: the streams drift apart, so H_res,
+                # which only re-deals what they hold — their sum is
+                # invariant under a doubly stochastic mix — decides what a
+                # sublayer reads), H_res logits at std 1
+                m = cfg.hc_mult
+                leaf = jax.random.normal(key, shape, jnp.float32) * np.repeat(
+                    np.float32([2.0, 2.0, 1.0]), [m, m, m * m])
+            else:
+                leaf = dense_init(name, key, shape, kind)
+            out[name] = leaf
+        return out
+
+    layer: Dict[str, Any] = {"attn_norm": norm_init(L, E)}
+    if not cfg.kv_lora_rank:
+        layer.update(
+            wq=dense_init("wq", next(keys), (L, E, H, D), E),
+            wk=dense_init("wk", next(keys), (L, E, KV, D), E),
+            wv=dense_init("wv", next(keys), (L, E, KV, D), E),
+            wo=dense_init("wo", next(keys), (L, H, D, E), H * D),
+        )
+    layer["mlp_norm"] = norm_init(L, E)
     if cfg.qk_norm:
         layer.update(q_norm=norm_init(L, H, D), k_norm=norm_init(L, KV, D))
     if cfg.n_experts:
         X = cfg.n_experts
         layer.update(
-            router=dense_init(next(keys), (L, E, X), E),
-            w_gate=dense_init(next(keys), (L, X, E, F), E),
-            w_up=dense_init(next(keys), (L, X, E, F), E),
-            w_down=dense_init(next(keys), (L, X, F, E), F),
+            router=dense_init("router", next(keys), (L, E, X), E),
+            w_gate=dense_init("w_gate", next(keys), (L, X, E, F), E),
+            w_up=dense_init("w_up", next(keys), (L, X, E, F), E),
+            w_down=dense_init("w_down", next(keys), (L, X, F, E), F),
         )
     elif cfg.mlp_variant == "gelu":
         layer.update(
-            w_up=dense_init(next(keys), (L, E, F), E),
-            w_down=dense_init(next(keys), (L, F, E), F),
+            w_up=dense_init("w_up", next(keys), (L, E, F), E),
+            w_down=dense_init("w_down", next(keys), (L, F, E), F),
         )
     else:
         layer.update(
-            w_gate=dense_init(next(keys), (L, E, F), E),
-            w_up=dense_init(next(keys), (L, E, F), E),
-            w_down=dense_init(next(keys), (L, F, E), F),
+            w_gate=dense_init("w_gate", next(keys), (L, E, F), E),
+            w_up=dense_init("w_up", next(keys), (L, E, F), E),
+            w_down=dense_init("w_down", next(keys), (L, F, E), F),
         )
+    layer.update(extras("layers", L))
     if cfg.pp_stages > 1:
         if L % cfg.pp_stages:
             raise ValueError(f"n_layers {L} not divisible by pp_stages {cfg.pp_stages}")
@@ -323,12 +544,18 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
             k: v.reshape((cfg.pp_stages, lps) + v.shape[1:]) for k, v in layer.items()
         }
     params = {
-        "embed": dense_init(next(keys), (cfg.vocab_size, E), E) * math.sqrt(E) * 0.02,
+        "embed": dense_init("embed", next(keys), (cfg.vocab_size, E), E,
+                            gain=(math.sqrt(E), 0.02)),
         "layers": layer,
         "final_norm": norm_init(E),
     }
     if not cfg.tie_embeddings:
-        params["unembed"] = dense_init(next(keys), (E, cfg.vocab_size), E)
+        params["unembed"] = dense_init(
+            "unembed", next(keys), (E, cfg.vocab_size), E)
+    if Ld:
+        params["dense_layers"] = {
+            "attn_norm": norm_init(Ld, E), "mlp_norm": norm_init(Ld, E),
+            **extras("dense_layers", Ld)}
     return params
 
 
@@ -338,16 +565,28 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
 
 
 def _moe_route(x, lp, cfg: TransformerConfig):
-    """Router of one expert layer. x [N, E] -> (w [N, k] f32, idx [N, k]):
-    softmax in f32 over ALL experts, then the k largest; the weights are
-    divided by their sum only under `cfg.moe_renormalize`. The logits leave
-    the matmul in f32: rounded to bf16 they would reorder near-equal
-    experts."""
+    """Router of one expert layer. x [N, E] -> (w [N, k] f32, idx [N, k]).
+
+    "softmax": softmax in f32 over ALL experts, then the k largest; the
+    weights are divided by their sum only under `cfg.moe_renormalize`.
+    "sigmoid" (DeepSeek-V3's `noaux_tc` with one group): a score per
+    expert, the k largest of score + `router_bias` — the bias steers the
+    CHOICE only — and the weights are the chosen experts' unbiased scores,
+    over their sum under `moe_renormalize`, times `moe_route_scale`.
+    The logits leave the matmul in f32: rounded to bf16 they would reorder
+    near-equal experts."""
     with jax.named_scope("moe.route"):
         gate_logits = jnp.einsum(
             "ne,ex->nx", x, lp["router"].astype(x.dtype),
             preferred_element_type=jnp.float32,
         )
+        if cfg.moe_scoring == "sigmoid":
+            scores = jax.nn.sigmoid(gate_logits)
+            _, idx = lax.top_k(scores + lp["router_bias"], cfg.top_k)
+            w = jnp.take_along_axis(scores, idx, axis=-1)
+            if cfg.moe_renormalize:
+                w = w / (w.sum(-1, keepdims=True) + 1e-20)
+            return w * cfg.moe_route_scale, idx
         probs = jax.nn.softmax(gate_logits, axis=-1)
         w, idx = lax.top_k(probs, cfg.top_k)
         if cfg.moe_renormalize:
@@ -442,7 +681,8 @@ def _moe_dropless(x, w, idx, lp, cfg: TransformerConfig):
 
 def _moe(h, lp, cfg: TransformerConfig, constrain_fn):
     """The sparse-expert MLP: h [B, S, E] -> (out [B, S, E], idx [B*S, k],
-    the experts each token was routed to)."""
+    the experts each token was routed to). A shared expert (`ws_*`), where
+    the layer has one, is added once, unweighted."""
     B, S, E = h.shape
     x = h.reshape(B * S, E)
     w, idx = _moe_route(x, lp, cfg)
@@ -453,6 +693,13 @@ def _moe(h, lp, cfg: TransformerConfig, constrain_fn):
             out = _moe_dropless(x, w, idx, lp, cfg)
         else:
             out = _moe_dispatch(x, w, idx, lp, cfg, constrain_fn)
+        if cfg.n_shared_experts:
+            with jax.named_scope("moe.shared"):
+                g = jnp.einsum("ne,ef->nf", x, lp["ws_gate"].astype(x.dtype))
+                u = jnp.einsum("ne,ef->nf", x, lp["ws_up"].astype(x.dtype))
+                out = out + jnp.einsum(
+                    "nf,fe->ne", jax.nn.silu(g) * u,
+                    lp["ws_down"].astype(x.dtype))
     return out.reshape(B, S, E), idx
 
 
@@ -463,7 +710,42 @@ def _fullest_expert(idx, live, n_experts: int):
     return jnp.max(jnp.sum(hits, axis=(0, 1)))
 
 
-_MATMUL_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "router")
+_MATMUL_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "router",
+                "wq_a", "wq_b", "wkv_a", "wkv_b", "ws_gate", "ws_up", "ws_down")
+# what a serving replica holds in cfg.dtype (`serving_params`)
+_HELD_KEYS = _MATMUL_KEYS + ("embed", "unembed")
+_STACKS = ("dense_layers", "layers")
+
+
+# The compiler's constant folding walks the counters of the random bits, an
+# array the leaf's size: 13.8 s of a 14.9 s compile for an expert stack
+# [4, 64, 3584, 1024] on a v5e, a second without it (PERF.md, PR 33), and it
+# has nothing to fold here (the key and the scalars are arguments).
+@partial(jax.jit, static_argnames=("shape", "dtype"),
+         compiler_options={"xla_disable_hlo_passes": "constant_folding"})
+def _draw_held(key, divisor, factors, *, shape, dtype):
+    """`init_params`' draw of one matmul leaf — normal / divisor, times each
+    of `factors`, the operations and their order as the float32 tree's —
+    cast to `dtype` in the same program. The scalars are arguments, not
+    constants: a constant divisor may be compiled as a multiplication."""
+    leaf = jax.random.normal(key, shape, jnp.float32) / divisor
+    for g in factors:
+        leaf = leaf * g
+    return leaf.astype(dtype)
+
+
+def _map_matmul_leaves(params, fn):
+    """`params` with `fn` applied to the stacked matmul weights of every
+    layer stack it has."""
+    out = dict(params)
+    for stack in _STACKS:
+        if stack in params:
+            layers = dict(params[stack])
+            for key in _MATMUL_KEYS:
+                if key in layers:
+                    layers[key] = fn(layers[key])
+            out[stack] = layers
+    return out
 
 
 def _cast_matmul_params(cfg: TransformerConfig, params):
@@ -474,42 +756,27 @@ def _cast_matmul_params(cfg: TransformerConfig, params):
     computes in f32 anyway). On a tree that `serving_params` already cast
     (what a serving replica holds) every `astype` here is the identity and
     compiles to nothing."""
-    layers = dict(params["layers"])
-    for key in _MATMUL_KEYS:
-        if key in layers:
-            layers[key] = layers[key].astype(cfg.dtype)
-    return {**params, "layers": layers}
+    return _map_matmul_leaves(params, lambda a: a.astype(cfg.dtype))
 
 
-def serving_params(cfg: TransformerConfig, params, *, consume: bool = False):
+def serving_params(cfg: TransformerConfig, params):
     """The tree a serving replica holds: exactly the leaves the decode
-    programs cast — the stacked matmul weights under `layers`, `embed` and
-    `unembed` — in `cfg.dtype`, every norm scale as it came (float32). The
+    programs cast — the stacked matmul weights of each layer stack, `embed`
+    and `unembed` — in `cfg.dtype`, every norm scale (and the router bias
+    and hyper-connection leaves) as it came (float32). The
     cast is the convert the programs would run, done ONCE per tree instead
     of in every prefill and every decode step, on the device and leaf by
     leaf: a leaf keeps its sharding, and one already in `cfg.dtype` is
-    returned as it is (no copy), so the call is idempotent.
-
-    `consume=True` is for a caller that owns `params` and is done with it:
-    each source leaf is deleted as soon as its copy is ready, so no second
-    whole tree ever exists on the device."""
+    returned as it is (no copy), so the call is idempotent and never touches
+    the caller's tree. A replica that draws its own weights never makes the
+    float32 tree in the first place: `init_params(held=True)`."""
     dtype = jnp.dtype(cfg.dtype)
 
     def held(leaf):
-        if leaf.dtype == dtype:
-            return leaf
-        leaf = jnp.asarray(leaf)
-        out = leaf.astype(dtype)
-        if consume:
-            out.block_until_ready()
-            leaf.delete()
-        return out
+        return leaf if leaf.dtype == dtype else jnp.asarray(leaf).astype(dtype)
 
-    layers = dict(params["layers"])
-    for key in _MATMUL_KEYS:
-        if key in layers:
-            layers[key] = held(layers[key])
-    out = {**params, "layers": layers, "embed": held(params["embed"])}
+    out = _map_matmul_leaves(params, held)
+    out["embed"] = held(params["embed"])
     if "unembed" in params:
         out["unembed"] = held(params["unembed"])
     return out
@@ -586,11 +853,186 @@ def _qkv(x, lp, cfg: TransformerConfig, cos, sin, positions=None,
     return q, k, v
 
 
+def yarn_inv_freq(dim: int, theta: float, factor: float, original_max: int,
+                  beta_fast: float, beta_slow: float):
+    """YaRN's inverse frequencies [dim // 2], f32: dimension i keeps
+    theta^(-2i/dim) below the correction dim of `beta_fast` rotations over
+    `original_max` positions, takes that over `factor` above the one of
+    `beta_slow`, and a linear ramp between them (DeepSeek-V2's
+    `DeepseekV2YarnRotaryEmbedding`)."""
+    def correction_dim(rotations):
+        return (dim * math.log(original_max / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    extra = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    ramp = jnp.clip(
+        (jnp.arange(dim // 2, dtype=jnp.float32) - low)
+        / max(high - low, 1e-3), 0.0, 1.0)
+    return extra / factor * ramp + extra * (1.0 - ramp)
+
+
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def _rope_tables(cfg: TransformerConfig):
+    """(cos, sin) [max_seq_len, rope_dim // 2] of the model's RoPE: over
+    d_head, or under latent attention over qk_rope_head_dim, YaRN-scaled
+    where `rope_factor` says so."""
+    dim = cfg.qk_rope_head_dim if cfg.kv_lora_rank else cfg.d_head
+    if cfg.rope_factor <= 1:
+        return rope_frequencies(dim, cfg.max_seq_len, cfg.rope_theta)
+    inv_freq = yarn_inv_freq(
+        dim, cfg.rope_theta, cfg.rope_factor, cfg.rope_original_max,
+        cfg.rope_beta_fast, cfg.rope_beta_slow)
+    freqs = jnp.outer(jnp.arange(cfg.max_seq_len, dtype=jnp.float32), inv_freq)
+    m = (_yarn_mscale(cfg.rope_factor, cfg.rope_mscale)
+         / _yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim))
+    return jnp.cos(freqs) * m, jnp.sin(freqs) * m
+
+
+def attention_scale(cfg: TransformerConfig) -> float:
+    """What the scores are multiplied by: 1/sqrt(head dim), times YaRN's
+    (0.1 mscale_all_dim ln factor + 1)^2 under latent attention."""
+    if not cfg.kv_lora_rank:
+        return cfg.d_head**-0.5
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    if cfg.rope_factor > 1 and cfg.rope_mscale_all_dim:
+        scale *= _yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim) ** 2
+    return scale
+
+
+def _qkv_latent(x, lp, cfg: TransformerConfig, cos, sin, positions=None):
+    """The latent sibling of `_qkv` (MLA): from the layer's input x
+    [B, S, E] ->
+
+      q       [B, S, H, nope + rope]   c_q = RMSNorm(h Wq_a); q = c_q Wq_b,
+                                       its last `rope` columns roped
+      latent  [B, S, 1, latent_width]  what the token CACHES: the normed
+                                       c_kv and the roped key all heads
+                                       share, [c_kv | k_rope]
+      wkv_b   [R, H, nope + v]         this layer's up-projection of c_kv
+                                       to per-head k_nope and v: handed to
+                                       the program's `attend`, which expands
+                                       K/V with it (materialised) or folds
+                                       it into q and the output (absorbed)
+
+    `attend(q, latent, wkv_b)` returns [B, S, H, v_head_dim]."""
+    dn, R = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+    c_q = rms_norm(
+        jnp.einsum("bse,eq->bsq", h, lp["wq_a"].astype(h.dtype)),
+        lp["q_a_norm"], cfg.rms_norm_eps)
+    q = jnp.einsum("bsq,qhd->bshd", c_q, lp["wq_b"].astype(h.dtype))
+    q = jnp.concatenate(
+        [q[..., :dn], apply_rope(q[..., dn:], cos, sin, positions=positions)],
+        axis=-1)
+    ckv = jnp.einsum("bse,er->bsr", h, lp["wkv_a"].astype(h.dtype))
+    c_kv = rms_norm(ckv[..., :R], lp["kv_a_norm"], cfg.rms_norm_eps)
+    k_rope = apply_rope(ckv[..., None, R:], cos, sin, positions=positions)
+    latent = jnp.concatenate([c_kv[..., None, :], k_rope], axis=-1)
+    return q, latent, lp["wkv_b"].astype(h.dtype)
+
+
+def expand_latent(latent, wkv_b, cfg: TransformerConfig):
+    """Materialised MLA: cached rows [B, K, 1, >= latent_width] -> per-head
+    (k [B, K, H, nope + rope], v [B, K, H, v_head_dim])."""
+    R, dn, dr = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    kv = jnp.einsum("bkr,rhd->bkhd", latent[:, :, 0, :R], wkv_b)
+    k_rope = jnp.broadcast_to(
+        latent[:, :, :, R:R + dr], kv.shape[:3] + (dr,))
+    return jnp.concatenate([kv[..., :dn], k_rope], axis=-1), kv[..., dn:]
+
+
+def _sinkhorn(logits, iters: int, eps: float):
+    """exp(logits) [..., n, n] made doubly stochastic: `iters` rounds of
+    rows over (their sum + eps), then columns over (theirs + eps)."""
+    m = jnp.exp(logits)
+    for _ in range(iters):
+        m = m / (jnp.sum(m, axis=-1, keepdims=True) + eps)
+        m = m / (jnp.sum(m, axis=-2, keepdims=True) + eps)
+    return m
+
+
+def hc_maps(x, lp, sub: str, cfg: TransformerConfig):
+    """The three maps of one sublayer's hyper-connection from the streams
+    x [..., n, C], all f32: H_pre [..., n] = sigmoid, H_post [..., n] =
+    2 sigmoid, H_res [..., n, n] = Sinkhorn(clip(.)) — each an affine map
+    (gain alpha, bias) of phi applied to vec(x) under ONE RMS over all n*C
+    values, with no learned scale."""
+    n = cfg.hc_mult
+    flat = x.reshape(x.shape[:-2] + (-1,)).astype(jnp.float32)
+    flat = flat * jax.lax.rsqrt(
+        jnp.mean(flat * flat, axis=-1, keepdims=True) + cfg.rms_norm_eps)
+    proj = jnp.einsum("...c,cw->...w", flat, lp[f"hc_{sub}_phi"],
+                      precision=jax.lax.Precision.HIGHEST)
+    alpha, bias = lp[f"hc_{sub}_alpha"], lp[f"hc_{sub}_bias"]
+    gain = jnp.repeat(alpha, np.array([n, n, n * n]),
+                      total_repeat_length=_hc_width(cfg))
+    proj = proj * gain + bias
+    h_pre = jax.nn.sigmoid(proj[..., :n])
+    h_post = 2.0 * jax.nn.sigmoid(proj[..., n:2 * n])
+    h_res = _sinkhorn(
+        jnp.clip(proj[..., 2 * n:], -cfg.hc_res_clamp, cfg.hc_res_clamp)
+        .reshape(proj.shape[:-1] + (n, n)),
+        cfg.hc_sinkhorn_iters, cfg.hc_eps)
+    return h_pre, h_post, h_res
+
+
+def _hc_mix(x, lp, sub: str, cfg: TransformerConfig):
+    """Entry of a sublayer under hyper-connections: x [B, S, n, C] ->
+    (u [B, S, C] = H_pre x, the sublayer's input; (H_res x, H_post), what
+    `_residual` needs once the sublayer's output is there)."""
+    with jax.named_scope("hc.mix"):
+        h_pre, h_post, h_res = hc_maps(x, lp, sub, cfg)
+        x32 = x.astype(jnp.float32)
+        # n is 4: sums of n scaled streams, which fuse elementwise (a dot
+        # with a 4-wide contraction would go to the MXU one token a time)
+        u = jnp.sum(h_pre[..., None] * x32, axis=-2).astype(x.dtype)
+        kept = jnp.sum(h_res[..., None] * x32[:, :, None], axis=-2)
+    return u, (kept, h_post)
+
+
+def _residual(x, y, mix=None):
+    """The residual connection, for both sublayers of every program: the
+    plain add, or under hyper-connections (mix from `_hc_mix`)
+    H_res x + H_post^T y over the n streams."""
+    if mix is None:
+        return x + y
+    kept, h_post = mix
+    with jax.named_scope("hc.mix"):
+        out = kept + h_post[..., None] * y.astype(jnp.float32)[:, :, None, :]
+        return out.astype(x.dtype)
+
+
+def _hc_expand(x, cfg: TransformerConfig):
+    """Embedding rows [B, S, C] -> the layers' carry: the row copied into
+    each of the n streams [B, S, n, C] (itself without hyper-connections)."""
+    if not cfg.hc_mult:
+        return x
+    return jnp.broadcast_to(
+        x[:, :, None, :], x.shape[:2] + (cfg.hc_mult, x.shape[-1]))
+
+
+def _hc_collapse(x, cfg: TransformerConfig):
+    """The last layer's carry -> [B, S, C]: the streams summed."""
+    if not cfg.hc_mult:
+        return x
+    return jnp.sum(x.astype(jnp.float32), axis=2).astype(x.dtype)
+
+
 def _block(x, lp, cfg: TransformerConfig, cos, sin, attend, constrain_fn, *,
-           positions=None, head_major: bool = False, routed=None):
-    """One decoder layer, written once for every program: `_qkv`, the
-    program's own attention, the output projection and its residual, then
-    the post-norm MLP (dense or sparse experts) and its residual.
+           positions=None, head_major: bool = False, routed=None,
+           dense: bool = False):
+    """One decoder layer, written once for every program: `_qkv` (or its
+    latent sibling), the program's own attention, the output projection and
+    its residual, then the post-norm MLP (dense or sparse experts) and its
+    residual. Both residuals are `_residual`: the plain add, or the
+    hyper-connection's mix over the n streams x then carries
+    ([B, S, n, C]). `dense` marks a layer of the leading dense stack of a
+    model whose other layers have experts.
 
     `attend(q, k, v) -> (attn, kept)` is all that differs between the
     programs: causal / flash over the sequence itself (the trainer's
@@ -598,25 +1040,57 @@ def _block(x, lp, cfg: TransformerConfig, cos, sin, attend, constrain_fn, *,
     the block window (paged prefill and decode, `kept` = the pool's new
     leaves); the cached window plus the in-flight tail (verify, `kept` =
     this layer's k, v, committed after acceptance). `kept` is handed back
-    as it came.
+    as it came. Under latent attention it is called with `_qkv_latent`'s
+    (q, latent, wkv_b).
 
     `routed(idx)`, where the layer has experts, gets the router's choices
     idx [B*S, k] as soon as they exist — before the residual add, where
     decode's expert-load count has always been traced — and its result is
     returned. Returns (x, kept, routed's result or None)."""
-    q, k, v = _qkv(x, lp, cfg, cos, sin, positions, head_major)
+    u, mix = _hc_mix(x, lp, "attn", cfg) if cfg.hc_mult else (x, None)
+    if cfg.kv_lora_rank:
+        q, k, v = _qkv_latent(u, lp, cfg, cos, sin, positions)
+    else:
+        q, k, v = _qkv(u, lp, cfg, cos, sin, positions, head_major)
     attn, kept = attend(q, k, v)
     wo_eq = "bhsd,hde->bse" if head_major else "bshd,hde->bse"
-    x = x + jnp.einsum(wo_eq, attn, lp["wo"].astype(x.dtype))
-    h2 = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
+    x = _residual(x, jnp.einsum(wo_eq, attn, lp["wo"].astype(x.dtype)), mix)
+    u, mix = _hc_mix(x, lp, "mlp", cfg) if cfg.hc_mult else (x, None)
+    h2 = rms_norm(u, lp["mlp_norm"], cfg.rms_norm_eps)
     stat = None
-    if cfg.n_experts:
+    if cfg.n_experts and not dense:
         y, idx = _moe(h2, lp, cfg, constrain_fn)
         if routed is not None:
             stat = routed(idx)
     else:
         y = _mlp(h2, lp, cfg, constrain_fn)
-    return constrain_fn(x + y, "batch", "seq", "embed"), kept, stat
+    axes = ("batch", "seq", None, "embed") if cfg.hc_mult else (
+        "batch", "seq", "embed")
+    return constrain_fn(_residual(x, y, mix), *axes), kept, stat
+
+
+def _scan_stacks(layer_fn, carry, params, layer_ids):
+    """`lax.scan` of `layer_fn(dense)(carry, (layer weights, layer index))`
+    over the model's layer stacks in turn — the leading dense stack, where
+    the model has one, then the main stack — with ONE running layer index
+    (`layer_ids`: stack name -> its layers' indices into the KV pool).
+    -> (carry, the main stack's ys)."""
+    ys = None
+    for stack in _STACKS:
+        if stack in params:
+            carry, ys = lax.scan(
+                layer_fn(stack == "dense_layers"), carry,
+                (params[stack], layer_ids[stack]))
+    return carry, ys
+
+
+def _layer_ids(cfg: TransformerConfig):
+    """stack name -> int32 indices of its layers in the whole model."""
+    ids = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+    if not cfg.first_k_dense:
+        return {"layers": ids}
+    return {"dense_layers": ids[:cfg.first_k_dense],
+            "layers": ids[cfg.first_k_dense:]}
 
 
 def make_forward(
@@ -630,7 +1104,7 @@ def make_forward(
     `rules`+`mesh` enable sharding constraints and (for ring/ulysses
     attention) the shard_map-wrapped sequence-parallel kernels.
     """
-    cos, sin = rope_frequencies(cfg.d_head, cfg.max_seq_len, cfg.rope_theta)
+    cos, sin = _rope_tables(cfg)
 
     if cfg.attention == "ring":
         inner_attn = partial(ring_attention, axis_name="sp", causal=True)
@@ -643,7 +1117,14 @@ def make_forward(
     # relayout transposes around attention cost more than attention itself
     # at small d_head); ring/ulysses keep [B,S,H,D] (seq must be a leading
     # non-minor dim for the sp shard_map)
-    head_major = inner_attn is None
+    # (latent attention: the plain dense oracle, K/V materialised per head)
+    head_major = inner_attn is None and not cfg.kv_lora_rank
+    if cfg.kv_lora_rank and (cfg.attention != "dense" or cfg.pp_stages > 1):
+        raise NotImplementedError(
+            "latent attention trains through attention='dense' on one "
+            f"pipeline stage only, got attention={cfg.attention!r}, "
+            f"pp_stages={cfg.pp_stages}"
+        )
 
     def attend(q, k, v):
         if inner_attn is not None and mesh is not None:
@@ -694,12 +1175,19 @@ def make_forward(
 
     def attend_seq(q, k, v):
         # the sequence attends itself: nothing is kept for later
+        if cfg.kv_lora_rank:
+            k, v = expand_latent(k, v, cfg)
+            return causal_attention(
+                q, k, v, scale=attention_scale(cfg)), None
         return attend(_constrain(q, *q_axes), k, v), None
 
-    def layer_step(x, lp):
-        x, _, _ = _block(x, lp, cfg, cos, sin, attend_seq, _constrain,
-                         head_major=head_major)
-        return x, None
+    def make_step(dense: bool):
+        def layer_step(x, lp):
+            x, _, _ = _block(x, lp, cfg, cos, sin, attend_seq, _constrain,
+                             head_major=head_major, dense=dense)
+            return x, None
+
+        return layer_step
 
     if cfg.remat:
         cp = jax.checkpoint_policies
@@ -730,10 +1218,10 @@ def make_forward(
                 "flash_out", "flash_lse", "rope_q", "rope_k", "attn_v",
             ),
         }
-        policy = policies[cfg.remat_policy]
-        step = jax.checkpoint(layer_step, policy=policy)
+        remat = partial(jax.checkpoint, policy=policies[cfg.remat_policy])
     else:
-        step = layer_step
+        remat = lambda f: f  # noqa: E731
+    step, dense_step = remat(make_step(False)), remat(make_step(True))
 
     def _apply_layers(params, x):
         if cfg.pp_stages > 1:
@@ -759,8 +1247,10 @@ def make_forward(
                 axis_name=stage_axes or "pp",
                 virtual_stages_per_device=cfg.pp_interleave,
             )
+        if "dense_layers" in params:
+            x, _ = lax.scan(dense_step, x, params["dense_layers"])
         if not cfg.scan_layers:
-            for i in range(cfg.n_layers):
+            for i in range(cfg.n_layers - cfg.first_k_dense):
                 lp_i = jax.tree.map(lambda a: a[i], params["layers"])
                 x, _ = step(x, lp_i)
             return x
@@ -774,7 +1264,7 @@ def make_forward(
         x = params["embed"].astype(cfg.dtype)[tokens]
         x = _constrain(x, "batch", "seq", "embed")
         params = _cast_matmul_params(cfg, params)
-        x = _apply_layers(params, x)
+        x = _hc_collapse(_apply_layers(params, _hc_expand(x, cfg)), cfg)
         x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
         unembed = params.get("unembed")
         if unembed is None:
@@ -853,20 +1343,41 @@ def _cached_attend(q, kc, vc, mask, scale, n_rep):
 KV_SCALE_AXES = ("layers", "batch", "kv_heads")
 
 
+def refuse_on_latent_pool(cfg: TransformerConfig, *, kv_dtype=None, mesh=None,
+                          speculative_k: int = 0) -> None:
+    """What a latent (MLA) pool does not support, refused BY NAME where a
+    pool or its programs are built — `kv_dtype` int8 (per-block scales of a
+    row that is key and value at once), `mesh` (a sharded pool's
+    cross-shard merge), `speculative_k` (verify's in-flight tail) — so that
+    none of them can run wrong silently. A no-op for per-head pools."""
+    if not cfg.kv_lora_rank:
+        return
+    asked = {
+        "kv_dtype": kv_dtype if kv_dtype is not None
+        and jnp.dtype(kv_dtype) == jnp.int8 else None,
+        "mesh": mesh, "speculative_k": speculative_k or None,
+    }
+    for name, value in asked.items():
+        if value is not None:
+            raise NotImplementedError(
+                f"a latent (MLA) KV pool does not support {name}={value!r}: "
+                "int8 latent rows, a sharded latent pool and speculative "
+                "verify over one are not written (ROADMAP, Reach A5 / A7)"
+            )
+
+
 def paged_kv_block_bytes(
     cfg: TransformerConfig, block_tokens: int, dtype=None
 ) -> int:
-    """HBM bytes ONE physical block costs across all layers (K + V + the
-    per-block scales when quantized) — the unit the engine's byte-budget
-    pool sizing divides by, which is how int8 pools end up with ~2x the
-    blocks of a bf16 pool for the same budget."""
-    dtype = dtype or cfg.dtype
-    itemsize = jnp.dtype(dtype).itemsize
-    per = cfg.n_layers * block_tokens * cfg.n_kv_heads * cfg.d_head * itemsize
-    total = 2 * per  # k + v
-    if dtype == jnp.int8:
-        total += 2 * cfg.n_layers * cfg.n_kv_heads * 4  # f32 scales
-    return total
+    """HBM bytes ONE physical block costs across all layers — the unit the
+    engine's byte-budget pool sizing divides by, which is how int8 pools
+    end up with ~2x the blocks of a bf16 pool for the same budget. Read off
+    the pool's own leaves (K + V + the per-block scales when quantized, or
+    the one latent leaf), not recounted."""
+    pool = jax.eval_shape(
+        lambda: init_paged_kv_cache(cfg, 1, block_tokens, dtype=dtype))
+    return sum(
+        math.prod(a.shape) * a.dtype.itemsize for a in jax.tree.leaves(pool))
 
 
 def init_paged_kv_cache(
@@ -886,8 +1397,17 @@ def init_paged_kv_cache(
 
     `dtype=jnp.int8` stores the pool quantized with per-block, per-kv-head
     f32 scales (`k_scale`/`v_scale` leaves, x ~= q * scale): half the HBM
-    per resident token, dequantized at the attention read."""
+    per resident token, dequantized at the attention read.
+
+    Under latent attention the pool is ONE leaf, `kv`
+    [L, N, block_tokens, 1, latent_row]: a token's normed c_kv and shared
+    roped key in its first `latent_width` columns (`cfg.latent_row` says
+    why the row is wider), serving every head as key and as value."""
     dtype = dtype or cfg.dtype
+    if cfg.kv_lora_rank:
+        refuse_on_latent_pool(cfg, kv_dtype=dtype, mesh=mesh)
+        return {"kv": jnp.zeros(
+            (cfg.n_layers, num_blocks, block_tokens, 1, cfg.latent_row), dtype)}
     shape = (cfg.n_layers, num_blocks, block_tokens, cfg.n_kv_heads, cfg.d_head)
     pool = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
     if dtype == jnp.int8:
@@ -1019,8 +1539,10 @@ def make_paged_decoder(
         )
     kv_dtype = kv_dtype or cfg.dtype
     quant = kv_dtype == jnp.int8
-    cos, sin = rope_frequencies(cfg.d_head, cfg.max_seq_len, cfg.rope_theta)
-    scale = cfg.d_head**-0.5
+    latent = bool(cfg.kv_lora_rank)
+    refuse_on_latent_pool(cfg, kv_dtype=kv_dtype, mesh=mesh)
+    cos, sin = _rope_tables(cfg)
+    scale = attention_scale(cfg)
     n_rep = cfg.n_heads // cfg.n_kv_heads
 
     def _constrain(x, *axes):
@@ -1036,14 +1558,59 @@ def make_paged_decoder(
         (`leaf[l, block]`): handed to `lax.scan` as xs/ys instead, every
         layer's [N, bt, KV, D] slice would be copied out of the stack and
         copied back, whole-pool traffic for a few tokens' write."""
+        if latent:  # the one latent leaf rides where K does
+            return (pool["kv"], None, None, None)
         return (pool["k"], pool["v"], pool.get("k_scale"), pool.get("v_scale"))
 
     def _pool_dict(kc, vc, ksc, vsc):
+        if latent:
+            return {"kv": kc}
         if quant:
             return {"k": kc, "v": vc, "k_scale": ksc, "v_scale": vsc}
         return {"k": kc, "v": vc}
 
-    layer_ids = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+    layer_ids = _layer_ids(cfg)
+
+    # ---- latent (MLA) pools: one row per token for every head -----------
+
+    def _latent_row(lat):
+        """[..., 1, latent_width] -> a pool row, zero-padded to its width."""
+        pad = cfg.latent_row - lat.shape[-1]
+        return jnp.pad(lat, [(0, 0)] * (lat.ndim - 1) + [(0, pad)])
+
+    def _latent_attend(q, wkv_b, kc, l, tables, positions, kv_len, mask,
+                       materialise=False):
+        """q [B, Q, H, nope + rope] against layer `l` of the latent pool
+        (this step's rows already written) -> [B, Q, H, v_head_dim].
+
+        ABSORBED (decode under both implementations, prefill under
+        "fused"): wkv_b's key half folds into q (nope -> rank columns), the
+        heads score the cached rows as they are and sum their first `rank`
+        columns, and wkv_b's value half maps that sum to v_head_dim — the
+        cache is read once for all heads and no K or V is ever expanded.
+        "fused" walks the table in place (ops.mla_paged_attention); "gather"
+        gathers the window and runs the dense softmax of the per-head path.
+        MATERIALISED (`materialise`, "gather" prefill — the oracle the
+        absorbed paths are held to): expand the window's rows to per-head
+        K and V and attend as the trainer's forward does."""
+        R, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+        if attention_impl == "gather":
+            win = kc[l, tables].reshape(tables.shape[0], -1, 1, cfg.latent_row)
+            if materialise:
+                k, v = expand_latent(win, wkv_b, cfg)
+                return _cached_attend(q, k, v, mask, scale, 1)
+        q_lat = jnp.einsum("bqhd,rhd->bqhr", q[..., :dn], wkv_b[..., :dn])
+        qx = _latent_row(jnp.concatenate([q_lat, q[..., dn:]], axis=-1))
+        if attention_impl == "fused":
+            from ..ops.paged_attention import mla_paged_attention
+
+            o_lat = mla_paged_attention(
+                qx, kc, tables, positions, layer=l, rank=R, scale=scale,
+                kv_len=kv_len)
+        else:
+            o_lat = _cached_attend(
+                qx, win, win[..., :R], mask, scale, cfg.n_heads)
+        return jnp.einsum("bqhr,rhd->bqhd", o_lat, wkv_b[..., dn:])
 
     def _gather_window(kc, ksc, l, tables):
         """[B, Nmax] tables -> each slot's window [B, Nmax*bt, KV, D] of
@@ -1229,7 +1796,7 @@ def make_paged_decoder(
         params = _cast_matmul_params(cfg, params)
         Sb = tokens.shape[1]
         x = params["embed"].astype(cfg.dtype)[tokens]
-        x = _constrain(x, "batch", "seq", "embed")
+        x = _hc_expand(_constrain(x, "batch", "seq", "embed"), cfg)
         qpos = ctx_len + jnp.arange(Sb)  # global positions of the suffix
         valid_tok = jnp.arange(Sb) < length
         # padded suffix tokens write into the null block (0), never into a
@@ -1277,13 +1844,24 @@ def make_paged_decoder(
             kw = _dequant(q8, s).reshape(1, G * bt, *win.shape[2:])
             return kc.at[l, window].set(q8), ksc.at[l, window].set(s), kw
 
-        def layer_fn(carry, per_layer):
+        def layer_fn(carry, per_layer, dense=False):
             x, *leaves = carry
             lp, l = per_layer
 
             def attend(q, k, v):
                 kc, vc, ksc, vsc = leaves
                 q = _constrain(q, "batch", "seq", "heads", "head_dim")
+                if latent:
+                    # k is the suffix's latent rows, v the layer's wkv_b
+                    kc = kc.at[l, w_phys, w_off].set(
+                        _latent_row(k[0]).astype(kc.dtype))
+                    attn = _latent_attend(
+                        q, v, kc, l, window[None],
+                        jnp.reshape(jnp.asarray(ctx_len, jnp.int32), (1,)),
+                        jnp.reshape(
+                            jnp.asarray(ctx_len + length, jnp.int32), (1,)),
+                        kmask, materialise=True)
+                    return attn, (kc, None, None, None)
                 # write the suffix K/V first — suffix keys are then read
                 # back from the pool, so cache content is authoritative
                 # either way
@@ -1314,13 +1892,14 @@ def make_paged_decoder(
                 return attn, (kc, vc, ksc, vsc)
 
             x, leaves, _ = _block(x, lp, cfg, cos, sin, attend, _constrain,
-                                  positions=qpos[None])
+                                  positions=qpos[None], dense=dense)
             return (x, *leaves), None
 
-        (x, *leaves), _ = lax.scan(
-            layer_fn, (x,) + _pool_leaves(pool), (params["layers"], layer_ids)
-        )
-        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        (x, *leaves), _ = _scan_stacks(
+            lambda dense: partial(layer_fn, dense=dense),
+            (x,) + _pool_leaves(pool), params, layer_ids)
+        x = rms_norm(_hc_collapse(x, cfg), params["final_norm"],
+                     cfg.rms_norm_eps)
         x_last = x[0, jnp.maximum(length - 1, 0)][None]
         logits = jnp.einsum("be,ev->bv", x_last, _unembed_matrix(cfg, params))
         logits = _constrain(logits, "batch", "vocab")
@@ -1355,7 +1934,7 @@ def make_paged_decoder(
         params = _cast_matmul_params(cfg, params)
         W = tables.shape[1] * bt
         x = params["embed"].astype(cfg.dtype)[tokens][:, None, :]  # [B,1,E]
-        x = _constrain(x, "batch", "seq", "embed")
+        x = _hc_expand(_constrain(x, "batch", "seq", "embed"), cfg)
         pos2 = positions[:, None]
         kmask = (jnp.arange(W)[None, :] <= pos2)[:, None, :]  # [B,1,W]
 
@@ -1376,13 +1955,19 @@ def make_paged_decoder(
             # slot writes to the null block, 0)
             return _fullest_expert(idx, write_phys > 0, cfg.n_experts)
 
-        def layer_fn(carry, per_layer):
+        def layer_fn(carry, per_layer, dense=False):
             x, *leaves = carry
             lp, l = per_layer
 
             def attend(q, k, v):
                 # q [B,1,H,D]; k, v [B,1,KV,D]
                 kc, vc, ksc, vsc = leaves
+                if latent:
+                    kc = kc.at[l, write_phys, write_off].set(
+                        _latent_row(k[:, 0]).astype(kc.dtype))
+                    attn = _latent_attend(
+                        q, v, kc, l, tables, positions, positions + 1, kmask)
+                    return attn, (kc, None, None, None)
                 if quant:
                     kc, ksc = _write_token_quant(kc, ksc, l, k[:, 0])
                     vc, vsc = _write_token_quant(vc, vsc, l, v[:, 0])
@@ -1407,13 +1992,14 @@ def make_paged_decoder(
 
             x, leaves, hottest = _block(
                 x, lp, cfg, cos, sin, attend, _constrain, positions=pos2,
-                routed=fullest)
+                routed=fullest, dense=dense)
             return (x, *leaves), hottest
 
-        (x, *leaves), hottest = lax.scan(
-            layer_fn, (x,) + _pool_leaves(pool), (params["layers"], layer_ids)
-        )
-        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        (x, *leaves), hottest = _scan_stacks(
+            lambda dense: partial(layer_fn, dense=dense),
+            (x,) + _pool_leaves(pool), params, layer_ids)
+        x = rms_norm(_hc_collapse(x, cfg), params["final_norm"],
+                     cfg.rms_norm_eps)
         logits = jnp.einsum("be,ev->bv", x[:, 0], _unembed_matrix(cfg, params))
         logits = _constrain(logits, "batch", "vocab")
         moe_hottest = None if hottest is None else jnp.sum(hottest)
@@ -1456,6 +2042,11 @@ def make_paged_decoder(
 
     def paged_verify(params, pool, tables, tokens, positions, draft_len,
                      write_phys, write_off, key):
+        refuse_on_latent_pool(cfg, speculative_k=True)
+        if cfg.first_k_dense or cfg.hc_mult:
+            raise NotImplementedError(
+                "speculative verify walks one layer stack with the plain "
+                "residual: first_k_dense / hc_mult models are not written")
         params = _cast_matmul_params(cfg, params)
         B, K1 = tokens.shape
         Nmax = tables.shape[1]
@@ -1513,7 +2104,8 @@ def make_paged_decoder(
                               positions=rope_pos)
             return x, kv
 
-        x, (ks, vs) = lax.scan(layer_fn, x, (params["layers"], layer_ids))
+        x, (ks, vs) = lax.scan(
+            layer_fn, x, (params["layers"], layer_ids["layers"]))
         x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
         logits = jnp.einsum("bse,ev->bsv", x, _unembed_matrix(cfg, params))
         logits = _constrain(logits, "batch", "seq", "vocab")

@@ -454,6 +454,197 @@ def paged_attention(
     return out
 
 
+# --------------------------------------------------------------------------
+# latent (MLA) pools: one row per token, shared by every head
+# --------------------------------------------------------------------------
+
+
+def _mla_kernel(tables_ref, pos_ref, kvlen_ref, layer_ref, q_ref, *rest,
+                bt, qb, nb, heads, rank, scale, out_dtype):
+    del layer_ref  # read by the pool's index maps only
+    tiles, (o_ref, acc, m_i, l_i) = rest[:nb], rest[nb:]
+    b = pl.program_id(0)
+    qt = pl.program_id(1)
+    j = pl.program_id(2)
+    nj = pl.num_programs(2)
+    rows = qb * heads
+    span = nb * bt  # tokens one grid step attends
+
+    @pl.when(j == 0)
+    def _init():
+        acc[:] = jnp.zeros_like(acc)
+        m_i[:] = jnp.full_like(m_i, NEG_INF)
+        l_i[:] = jnp.zeros_like(l_i)
+
+    pos = pos_ref[b]
+    qbase = pos + qt * qb  # global position of this tile's first query
+    # a slot's table is live from the front (the engine fills it in order,
+    # and a latent pool is never sharded), so the keys of this step that
+    # exist are those before `n_live` blocks' worth and before kv_len
+    n_live = jnp.int32(0)
+    for i in range(nb):
+        n_live += (tables_ref[b, j * nb + i] >= 0).astype(jnp.int32)
+    kvl = jnp.minimum(kvlen_ref[b], (j * nb + n_live) * bt)
+    live = jnp.logical_and(j * span < kvl, j * span <= qbase + qb - 1)
+
+    @pl.when(live)
+    def _attend():
+        # [span, W]: the step's blocks, rows = consecutive token positions
+        kv = jnp.concatenate([t[...] for t in tiles], axis=0)
+        q = q_ref[0].reshape(rows, kv.shape[1])
+        s = lax.dot_general(
+            q, kv, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [rows, span]
+        kpos = j * span + lax.broadcasted_iota(jnp.int32, (rows, span), 1)
+        # row = query * heads + head
+        qi = lax.broadcasted_iota(jnp.int32, (rows, span), 0) // heads
+        mask = jnp.logical_and(kpos <= qbase + qi, kpos < kvl)
+        s = jnp.where(mask, s, NEG_INF)
+        m_prev = m_i[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_i[:] = alpha * l_i[:] + jnp.sum(p, axis=1, keepdims=True)
+        m_i[:] = m_new
+        # the values are the rows' first `rank` columns
+        pv = lax.dot_general(
+            p.astype(kv.dtype), kv[:, :rank], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        acc[:] = acc[:] * alpha + pv
+
+    @pl.when(j == nj - 1)
+    def _finalize():
+        l = l_i[:]
+        safe_l = jnp.where(l == 0.0, 1.0, l)
+        o_ref[0] = (acc[:] / safe_l).reshape(qb, heads, rank).astype(out_dtype)
+
+
+def _mla_attention_pallas(q, pool, ptable, positions, kv_len, layer, rank,
+                          scale, interpret, block_q, blocks_per_step):
+    b, Q, h, w = q.shape
+    n_layers, n_blocks, bt = pool.shape[:3]
+    # [L, N, bt, 1, W] -> [L, N, bt, W]: the unit head dim would otherwise
+    # be the tile's sublane dim (a bitcast: it is degenerate)
+    pool = pool.reshape(n_layers, n_blocks, bt, w)
+    nb = max(1, min(int(blocks_per_step), ptable.shape[1]))
+    nmax = -(-ptable.shape[1] // nb) * nb
+    if nmax != ptable.shape[1]:
+        ptable = jnp.pad(ptable, ((0, 0), (0, nmax - ptable.shape[1])),
+                         constant_values=-1)
+    qb = max(1, min(int(block_q), Q))
+    qp = -(-Q // qb) * qb
+    if qp != Q:
+        q = jnp.pad(q, ((0, 0), (0, qp - Q), (0, 0), (0, 0)))
+    grid = (b, qp // qb, nmax // nb)
+
+    def tile_spec(i):
+        def index(b_, qt_, j_, tbl, pos, kvl, lyr):
+            entry = tbl[b_, j_ * nb + i]
+            first = (j_ * nb + i) * bt  # the block's first token position
+            # a block no query of this tile may see — dead, past kv_len, or
+            # after the tile's last query — maps to block 0: a repeated
+            # index is not fetched again
+            seen = jnp.logical_and(
+                entry >= 0,
+                jnp.logical_and(first < kvl[b_],
+                                first <= pos[b_] + (qt_ + 1) * qb - 1))
+            return lyr[0], jnp.where(seen, entry, 0), 0, 0
+
+        return pl.BlockSpec((None, None, bt, w), index)
+
+    o_map = lambda b_, qt_, j_, *_: (b_, qt_, 0, 0)
+    kernel = functools.partial(
+        _mla_kernel, bt=bt, qb=qb, nb=nb, heads=h, rank=rank, scale=scale,
+        out_dtype=q.dtype,
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=grid,
+            # the pool goes in once per block of a step, each with its own
+            # index map: `nb` table entries' blocks are in flight together,
+            # so a step attends nb * bt tokens (a grid step costs the same
+            # whatever it moves; at one 64-token block a step the walk of a
+            # 16k context is all overhead)
+            in_specs=[pl.BlockSpec((1, qb, h, w), o_map)]
+            + [tile_spec(i) for i in range(nb)],
+            out_specs=[pl.BlockSpec((1, qb, h, rank), o_map)],
+            scratch_shapes=[
+                pltpu.VMEM((qb * h, rank), jnp.float32),
+                pltpu.VMEM((qb * h, 1), jnp.float32),
+                pltpu.VMEM((qb * h, 1), jnp.float32),
+            ],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((b, qp, h, rank), q.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
+        interpret=interpret,
+        # the profiler's event is %mla_paged_attention.<n>: the benchmark's
+        # readers find the kernel by this name
+        name="mla_paged_attention",
+    )(ptable, positions, kv_len, jnp.reshape(layer, (1,)), q, *([pool] * nb))
+    return out[0][:, :Q]
+
+
+def mla_paged_attention(
+    q: jnp.ndarray,        # [B, Q, H, W]: [q absorbed into the latent | q_rope],
+                           # zero-padded to the pool's row width
+    pool: jnp.ndarray,     # [L, N, block_tokens, 1, W] latent rows
+    tables: jnp.ndarray,   # [B, Nmax] int32, 0 = the null block
+    positions: jnp.ndarray,  # [B] int32 global position of query 0
+    *,
+    layer,                 # int32 scalar (traced or not)
+    rank: int,             # the rows' first `rank` columns are the values
+    scale: float,
+    kv_len: Optional[jnp.ndarray] = None,
+    impl: str = "auto",    # auto | kernel | xla
+    interpret: Optional[bool] = None,
+    block_q: int = 16,       # query rows a tile (the per-head op's default)
+    blocks_per_step: int = 8,  # pool blocks in flight a grid step
+) -> jnp.ndarray:
+    """Absorbed multi-query attention over a latent (MLA) pool: every head
+    of query i of slot b scores ALL W columns of each cached row at key
+    positions t <= positions[b] + i, t < kv_len[b], and the output is the
+    softmax-weighted sum of the rows' first `rank` columns -> [B, Q, H,
+    rank] in q's dtype. One row serves as key and value for all H heads, so
+    the walk reads a token's row once. Tables are live from the front (no
+    signed / sharded tables here). The XLA twin is the per-head op's chunked
+    walk with the pool as K and as V over one kv head."""
+    global _LAST_IMPL
+    if pool.ndim != 5 or pool.shape[3] != 1 or q.shape[-1] != pool.shape[-1]:
+        raise ValueError(
+            "mla_paged_attention takes a stacked latent pool [L, N, bt, 1, W]"
+            f" and q [B, Q, H, W]: got pool {pool.shape}, q {q.shape}"
+        )
+    if impl not in ("auto", "kernel", "xla"):
+        raise ValueError(f"impl must be auto|kernel|xla, got {impl!r}")
+    if impl == "auto":
+        impl = "kernel" if jax.default_backend() == "tpu" else "xla"
+    layer = jnp.asarray(layer, jnp.int32)
+    positions = positions.astype(jnp.int32)
+    if kv_len is None:
+        kv_len = positions + q.shape[1]
+    kv_len = kv_len.astype(jnp.int32)
+    ptable = jnp.where(tables > 0, tables, -1).astype(jnp.int32)
+    _LAST_IMPL = impl
+    if impl == "kernel":
+        if interpret is None:
+            interpret = jax.default_backend() != "tpu"
+        return _mla_attention_pallas(
+            q, pool, ptable, positions, kv_len, layer, rank, float(scale),
+            interpret, block_q, blocks_per_step,
+        )
+    out = _paged_attention_xla(
+        q, pool, pool, ptable, positions, kv_len, layer, None, None,
+        float(scale), False, 8,
+    )
+    return out[..., :rank]
+
+
 def merge_partials(acc, m, l, axis_names=None, out_dtype=jnp.float32):
     """Combine per-shard online-softmax partials into the final output.
 

@@ -393,10 +393,10 @@ class KVGenerationServer:
     """Deployment-ready paged generation server with the cluster-wide KV
     plane wired in. Builds a PagedDecodeEngine (weights re-derived from
     `weights_seed`, so every replica holds identical parameters: the f32
-    draw of `init_params` cast once by `serving_params`, as the engine
-    holds any tree — matmul weights, `embed`, `unembed` in `cfg.dtype`,
-    norm scales float32 — and the f32 leaves freed before the pool is
-    allocated) + a ContinuousBatcher + a KVTransferManager, and exposes:
+    draws of `init_params`, each cast as it is drawn (`held=True`) to what
+    the engine holds of any tree — matmul weights, `embed`, `unembed` in
+    `cfg.dtype`, norm scales float32 — so no float32 tree ever exists)
+    + a ContinuousBatcher + a KVTransferManager, and exposes:
 
       generate(tokens, max_new_tokens)  greedy generation; pulls the
           prompt's prefix from a peer (monolithic role) or from the
@@ -420,7 +420,7 @@ class KVGenerationServer:
         import jax
 
         from ray_tpu.models.kv_paging import PagedDecodeEngine
-        from ray_tpu.models.transformer import init_params, serving_params
+        from ray_tpu.models.transformer import init_params
 
         from .batching import ContinuousBatcher
 
@@ -428,12 +428,11 @@ class KVGenerationServer:
             raise ValueError(f"unknown role {role!r}")
         self.role = role
         self.deployment = deployment
-        # the tree is this server's own, so the f32 leaves from the seed go
-        # as they are cast, before the engine allocates its pool
-        params = serving_params(
-            cfg, init_params(jax.random.PRNGKey(int(weights_seed)), cfg),
-            consume=True,
-        )
+        # each f32 leaf from the seed is cast as it is drawn and then freed:
+        # the whole float32 tree never exists (it need not fit the chip),
+        # and nothing of it is left when the engine allocates its pool
+        params = init_params(
+            jax.random.PRNGKey(int(weights_seed)), cfg, held=True)
         kw = dict(engine_kwargs or {})
         self.engine = PagedDecodeEngine(cfg, params, **kw)
         self.batcher = ContinuousBatcher(self.engine)

@@ -379,6 +379,71 @@ def test_olmoe_experts_are_grouped_matmuls(one_chip, monkeypatch, program,
     assert f"{n * cfg.top_k},{cfg.d_model}" in shapes
 
 
+# ---- the latent (MLA) geometry: one 640-wide row a token ------------------
+
+
+def _xing4_block(n_layers=2):
+    """Xing4.0-29B-A4B's layers as benchmark/blocks/xing4.py maps them: one
+    leading dense layer, then expert layers."""
+    from ray_tpu.models.transformer import TransformerConfig
+
+    return TransformerConfig(
+        vocab_size=131072, d_model=3584, n_layers=n_layers, n_heads=32,
+        n_kv_heads=32, d_head=192, d_ff=1024, max_seq_len=18432,
+        q_lora_rank=768, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, rope_factor=64.0,
+        rope_original_max=4096, rope_mscale_all_dim=1.0, first_k_dense=1,
+        d_ff_dense=9216, n_experts=64, top_k=4, moe_scoring="sigmoid",
+        moe_route_scale=2.0, n_shared_experts=1, moe_capacity_factor=None,
+        hc_mult=4,
+    )
+
+
+@pytest.mark.parametrize("batch,q_len,table", [(32, 1, 288), (1, 256, 260)],
+                         ids=["decode", "prefill"])
+def test_mla_kernel_compiles(one_chip, batch, q_len, table):
+    """The latent kernel at the benchmark cell's shapes: 32 heads over
+    640-wide rows, 8 blocks a grid step, an 18,432-token table."""
+    import importlib
+
+    pa = importlib.import_module("ray_tpu.ops.paged_attention")
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fn(q, pool, tables, positions):
+        return pa.mla_paged_attention(
+            q, pool, tables, positions, layer=jnp.int32(1), rank=512,
+            scale=0.14, impl="kernel", interpret=False)
+
+    compiled = _compile(
+        fn, sds((batch, q_len, 32, 640), jnp.bfloat16),
+        sds((2, 1036, BLOCK_TOKENS, 1, 640), jnp.bfloat16),
+        sds((batch, table), jnp.int32), sds((batch,), jnp.int32))
+    text = compiled.as_text()
+    assert "mla_paged_attention" in text and "tpu_custom_call" in text
+    # the [L, N, bt, 1, W] -> [L, N, bt, W] view is free: no copy of the pool
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_latent_paged_programs_move_no_pool(one_chip, monkeypatch, program):
+    """Decode and prefill over the latent pool: the donated pool is the
+    output, the kernel is in the program, and what the program keeps
+    besides does not grow with the pool."""
+    temps = []
+    for num_blocks in (1036, 4144):
+        cfg, compiled = _compile_paged_program(
+            one_chip, monkeypatch, program, "fused", False, num_blocks,
+            cfg=_xing4_block(), tree="held")
+        assert "mla_paged_attention" in compiled.as_text()
+        mem = compiled.memory_analysis()
+        pool_bytes = cfg.n_layers * num_blocks * BLOCK_TOKENS * 640 * 2
+        assert mem.alias_size_in_bytes >= pool_bytes
+        temps.append(mem.temp_size_in_bytes)
+    assert temps[1] - temps[0] < 2 * BLOCK_TOKENS * 640 * 2, temps
+
+
 # ---- the held tree: no weight is cast inside a paged program -------------
 #
 # A PagedDecodeEngine holds serving_params of its tree: matmul weights,

@@ -529,11 +529,12 @@ def test_engine_owned_tree_is_held_cast_and_sharding_is_kept(tiny_f32):
         assert a.sharding == b.sharding, (a.sharding, b.sharding)
     assert held["layers"]["wq"].dtype == jnp.bfloat16
     assert len(held["layers"]["wq"].sharding.device_set) == 8
-    # consumed: the source leaves that were cast are gone, the rest shared
-    taken = serving_params(cfg, sharded, consume=True)
-    assert sharded["layers"]["wq"].is_deleted()
-    assert taken["final_norm"] is sharded["final_norm"]
-    assert np.array_equal(taken["layers"]["wq"], held["layers"]["wq"])
+    # the caller's tree is never touched: what is not cast is shared, and a
+    # held tree goes through again as it is
+    assert not sharded["layers"]["wq"].is_deleted()
+    assert held["final_norm"] is sharded["final_norm"]
+    again = serving_params(cfg, held)
+    assert again["layers"]["wq"] is held["layers"]["wq"]
 
 
 def test_preemption_sse_streams_survive():

@@ -360,13 +360,24 @@ def _paged(q, k, v):
         jnp.zeros((1,), jnp.int32), impl="kernel", interpret=True)
 
 
+def _mla(q, k, v):
+    from ray_tpu.ops.paged_attention import mla_paged_attention
+
+    pool = jnp.zeros((1, 4, 8, 1, 128), jnp.float32)
+    return mla_paged_attention(
+        jnp.zeros((1, 1, 2, 128), jnp.float32), pool,
+        jnp.zeros((1, 2), jnp.int32), jnp.zeros((1,), jnp.int32), layer=0,
+        rank=32, scale=1.0, impl="kernel", interpret=True)
+
+
 @pytest.mark.parametrize("fn,kv_heads,names", [
     (_flash_fwd, 2, ["flash_attention_fwd"]),
     (_flash_bwd, 1, ["flash_attention_fwd", "flash_attention_bwd_dq",
                      "flash_attention_bwd_dkv"]),
     (_flash_bwd_one_block, 2, ["flash_attention_fwd", "flash_attention_bwd"]),
     (_paged, 2, ["paged_attention"]),
-], ids=["flash_fwd", "flash_bwd", "flash_bwd_fused", "paged"])
+    (_mla, 2, ["mla_paged_attention"]),
+], ids=["flash_fwd", "flash_bwd", "flash_bwd_fused", "paged", "mla_paged"])
 def test_kernels_carry_their_names(fn, kv_heads, names):
     q = jnp.ones((1, 32, 2, 16), jnp.float32)
     kv = jnp.ones((1, 32, kv_heads, 16), jnp.float32)
@@ -387,3 +398,67 @@ def test_kernels_carry_their_names(fn, kv_heads, names):
     # instruction after (%flash_attention_fwd.<n>)
     for name, scope in calls.items():
         assert name in scope.split("/")[-1], (name, scope)
+
+
+# ------------------- (e) the latent / hyper-connection layer's own names
+
+
+def _latent_engine(**kw):
+    """A small model of the Xing4.0 layer family: latent attention, a dense
+    layer before sigmoid-routed experts with a shared one, four streams."""
+    from ray_tpu.models.transformer import TransformerConfig
+
+    cfg = TransformerConfig(
+        vocab_size=256, d_model=64, n_layers=2, n_heads=4, n_kv_heads=4,
+        d_head=24, d_ff=32, max_seq_len=128, n_experts=4, top_k=2,
+        moe_capacity_factor=None, q_lora_rank=48, kv_lora_rank=32,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        first_k_dense=1, d_ff_dense=96, moe_scoring="sigmoid",
+        moe_route_scale=2.0, n_shared_experts=1, hc_mult=4)
+    return cfg, PagedDecodeEngine(
+        cfg, max_batch_size=2, seed=0, block_tokens=8, prefix_cache=False,
+        prefill_buckets=(16,), **kw)
+
+
+@pytest.mark.parametrize("which", ["paged_prefill", "paged_decode"])
+def test_latent_layer_lowers_under_its_scopes_and_kernel(which):
+    """`hc.mix` (both sublayers' mixes), `moe.shared` NESTED under
+    `moe.experts` (so `moe_device_ms` counts the shared expert) and the
+    kernel's own name `mla_paged_attention`: what the readers
+    hc_device_ms and mla_attention_ms find
+    their operations by. The programs keep their names."""
+    _, eng = _latent_engine(attention_impl="fused")
+    if which == "paged_prefill":
+        eng.admit(0, {"tokens": np.arange(1, 10), "max_new_tokens": 2})
+    fn, args = _program_args(eng, which)
+    text = fn.lower(*args).as_text(debug_info=True)
+    assert f"module @jit_{which} " in text
+    for scope in ("hc.mix/", "moe.route/", "moe.experts/moe.shared/"):
+        assert scope in text, scope
+    # (on the CPU the fused op takes its XLA twin; the kernel's name is
+    # pinned in test_kernels_carry_their_names)
+    _, plain = _tiny_engine(False, prefix_cache=False, prefill_buckets=(16,))
+    if which == "paged_prefill":
+        plain.admit(0, {"tokens": np.arange(1, 10), "max_new_tokens": 2})
+    fn, args = _program_args(plain, which)
+    plain_text = fn.lower(*args).as_text(debug_info=True)
+    assert "hc.mix" not in plain_text and "moe.shared" not in plain_text
+
+
+def test_latent_decode_span_and_stats_keep_their_attributes(tmp_path):
+    """`engine.decode` keeps `kv_tokens`, `moe_pairs`, `moe_hottest` — the
+    last two over the EXPERT layers, not all layers — and `engine.stats()`
+    says what a resident token costs by the pool's own leaves."""
+    cfg, eng = _latent_engine()
+    eng.admit(0, {"tokens": np.arange(1, 12), "max_new_tokens": 8})
+    eng.step([0])  # compiled outside the trace
+    with _Trace(tmp_path) as tr:
+        eng.step([0])
+    (_, _, st), = tr.spans("engine.decode")
+    assert st["kv_tokens"] == 13
+    assert st["moe_pairs"] == cfg.top_k * 1  # one expert layer of two layers
+    assert st["moe_hottest"] == 1
+    stats = eng.stats()
+    assert stats["kv_bytes_per_token"] == cfg.n_layers * 128 * 2
+    assert stats["kv_pool_bytes"] == eng.pool["kv"].nbytes
+    assert stats["attention_kernel"] == "gather"  # "pallas" only on a TPU
