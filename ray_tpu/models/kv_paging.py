@@ -637,6 +637,7 @@ class PagedDecodeEngine:
         # sparse-expert models: see the decode step
         self.moe_pairs = 0
         self.moe_hottest = 0
+        self.moe_touched = 0
         self.prefix_hits = 0
         self.prefix_tokens_reused = 0
         self.preemptions = 0
@@ -1201,7 +1202,7 @@ class PagedDecodeEngine:
         # trace their number; kv_tokens is what the paged kernel must read
         step_span.set(slots=tuple(surviving), kv_tokens=kv_tokens)
         with span("engine.dispatch"):
-            next_toks, logits, self.pool, hottest = self._decode_step(
+            next_toks, logits, self.pool, moe_load = self._decode_step(
                 self.params, self.pool, self._tables, self._last_tokens,
                 self._positions, write_phys, write_off, key,
             )
@@ -1211,16 +1212,20 @@ class PagedDecodeEngine:
                 np.asarray(self._lp_fn(logits, next_toks))
                 if self.logprobs else None
             )
-            if hottest is not None:
+            if moe_load is not None:
                 # a sparse-expert model: the step's routed (token, expert)
-                # pairs and the load of its fullest expert, both summed
-                # over the layers (pairs * n_experts / hottest = 1: even)
+                # pairs, the load of its fullest expert and the experts
+                # with any pair (the groups the grouped matmul reads), each
+                # summed over the layers (pairs * n_experts / hottest = 1:
+                # even); the two counts ride one array, one fetch
                 pairs = (len(surviving) * self.cfg.top_k
                          * self.cfg.n_expert_layers)
-                hottest = int(hottest)
-                step_span.set(moe_pairs=pairs, moe_hottest=hottest)
+                hottest, touched = map(int, np.asarray(moe_load))
+                step_span.set(moe_pairs=pairs, moe_hottest=hottest,
+                              moe_touched=touched)
                 self.moe_pairs += pairs
                 self.moe_hottest += hottest
+                self.moe_touched += touched
         with span("engine.bookkeep"):
             out: Dict[int, Tuple[Any, bool]] = {}
             for s in surviving:
@@ -1686,9 +1691,11 @@ class PagedDecodeEngine:
             ),
             "decode_steps": self.decode_steps,
             # decode steps of a sparse-expert model: routed (token, expert)
-            # pairs, and the summed load of each step's fullest expert
+            # pairs, the summed load of each step's fullest expert, and
+            # the (layer, expert) groups with a pair: what the steps read
             "moe_pairs": self.moe_pairs,
             "moe_hottest": self.moe_hottest,
+            "moe_touched": self.moe_touched,
             "max_batch_size": self.max_batch_size,
             "block_tokens": self.block_tokens,
             "kv_cache_dtype": self.kv_cache_dtype,

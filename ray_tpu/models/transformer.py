@@ -10,7 +10,13 @@ model-parallel story is external Alpa, release/alpa_tests/):
     layers, and XLA pipelines the scan on TPU. What a layer only READS
     (its weights) is the scan's xs; state a layer WRITES a few rows of —
     the paged KV pool — is carried whole and addressed by layer index, so
-    it is updated in place instead of sliced out and written back.
+    it is updated in place instead of sliced out and written back. The
+    serving programs hand the dropless experts' three [L, X, ...] leaves
+    to the layer body whole as well (`_scan_stacks`): the chip's grouped
+    matmul takes a whole buffer as its weight operand, so a layer's slice
+    of the stack would be COPIED in front of it; over the stack viewed as
+    [L*X, ...], with the other layers' group sizes zero, it reads the
+    layer's experts where they lie.
   - each scan step is jax.checkpoint'ed (rematerialization: trade MXU FLOPs
     for HBM, the standard TPU memory trade).
   - attention impl is selectable: dense (small L), ring (sequence-parallel
@@ -650,7 +656,7 @@ def _moe_dispatch(x, w, idx, lp, cfg: TransformerConfig, constrain_fn):
     return jnp.zeros((N, E), x.dtype).at[st].add(contrib)
 
 
-def _moe_dropless(x, w, idx, lp, cfg: TransformerConfig):
+def _moe_dropless(x, w, idx, lp, cfg: TransformerConfig, layer=None):
     """Dropless top-k MoE (`moe_capacity_factor=None`): EVERY routed
     (token, expert) pair is computed, at the FLOPs of the N*k pairs.
 
@@ -660,7 +666,18 @@ def _moe_dropless(x, w, idx, lp, cfg: TransformerConfig):
     — on TPU one grouped-matmul kernel that reads an expert's weights once
     and skips experts without rows. Shapes are static for any N >= 1 and
     any routing (all pairs on one expert included); the output is gathered
-    back per token and the k contributions summed in f32."""
+    back per token and the k contributions summed in f32.
+
+    The expert leaves are this layer's [X, ...] slices (the trainer's
+    forward) or the WHOLE [L, X, ...] stacks with `layer`, the layer's
+    index in the model (the paged programs, `_scan_stacks`). The kernel
+    takes a whole buffer as its weight operand: a slice of the stack would
+    be materialised in front of each call (three copies of a layer's
+    experts a layer, most of a decode step's device time: PERF.md, PR 34).
+    A stack is therefore read in place — viewed as L*X groups (merging the
+    two leading dims moves nothing), with the router's sizes written at
+    this layer's X groups and zero rows for every other layer's. Same
+    rows, same experts, same three matmuls."""
     N, E = x.shape
     X, k = cfg.n_experts, cfg.top_k
     flat_e = idx.reshape(-1)                       # [N*k] destination expert
@@ -668,10 +685,23 @@ def _moe_dropless(x, w, idx, lp, cfg: TransformerConfig):
     sizes = jnp.sum(
         jax.nn.one_hot(flat_e, X, dtype=jnp.int32), axis=0
     )                                              # [X] rows per expert
+    in_stack = lp["w_gate"].ndim == 4
+    if in_stack:
+        groups = lp["w_gate"].shape[0] * X
+        sizes = lax.dynamic_update_slice(
+            jnp.zeros((groups,), jnp.int32), sizes,
+            ((layer - cfg.first_k_dense) * X,))
+
+    def experts(rows, name):
+        wt = lp[name].astype(x.dtype)
+        if in_stack:
+            wt = wt.reshape(groups, *wt.shape[2:])
+        return lax.ragged_dot(rows, wt, sizes)
+
     xs = x[order // k]                             # [N*k, E] sorted rows
-    g = lax.ragged_dot(xs, lp["w_gate"].astype(x.dtype), sizes)
-    u = lax.ragged_dot(xs, lp["w_up"].astype(x.dtype), sizes)
-    y = lax.ragged_dot(jax.nn.silu(g) * u, lp["w_down"].astype(x.dtype), sizes)
+    g = experts(xs, "w_gate")
+    u = experts(xs, "w_up")
+    y = experts(jax.nn.silu(g) * u, "w_down")
     # back to token order: pair j of token n sits at sorted row inv[n*k+j]
     inv = jnp.zeros((N * k,), jnp.int32).at[order].set(
         jnp.arange(N * k, dtype=jnp.int32))
@@ -679,10 +709,12 @@ def _moe_dropless(x, w, idx, lp, cfg: TransformerConfig):
     return jnp.sum(y * w[..., None], axis=1).astype(x.dtype)
 
 
-def _moe(h, lp, cfg: TransformerConfig, constrain_fn):
+def _moe(h, lp, cfg: TransformerConfig, constrain_fn, layer=None):
     """The sparse-expert MLP: h [B, S, E] -> (out [B, S, E], idx [B*S, k],
     the experts each token was routed to). A shared expert (`ws_*`), where
-    the layer has one, is added once, unweighted."""
+    the layer has one, is added once, unweighted. `layer`: the layer's
+    index in the model, where `lp` holds the expert stacks whole
+    (`_moe_dropless`)."""
     B, S, E = h.shape
     x = h.reshape(B * S, E)
     w, idx = _moe_route(x, lp, cfg)
@@ -690,7 +722,7 @@ def _moe(h, lp, cfg: TransformerConfig, constrain_fn):
         if cfg.moe_impl == "dense":
             out = _moe_dense(x, w, idx, lp, cfg)
         elif cfg.moe_capacity_factor is None:
-            out = _moe_dropless(x, w, idx, lp, cfg)
+            out = _moe_dropless(x, w, idx, lp, cfg, layer)
         else:
             out = _moe_dispatch(x, w, idx, lp, cfg, constrain_fn)
         if cfg.n_shared_experts:
@@ -703,11 +735,14 @@ def _moe(h, lp, cfg: TransformerConfig, constrain_fn):
     return out.reshape(B, S, E), idx
 
 
-def _fullest_expert(idx, live, n_experts: int):
-    """Load of the fullest expert: the most (token, expert) pairs any one
-    expert got from the tokens marked `live` ([N] bool). idx [N, k]."""
+def _expert_load(idx, live, n_experts: int):
+    """What the tokens marked `live` ([N] bool) ask of one expert layer,
+    idx [N, k] -> int32 [2]: the load of the fullest expert (the most
+    (token, expert) pairs any one expert got) and the number of experts
+    with at least one pair — the groups the grouped matmul reads."""
     hits = jax.nn.one_hot(idx, n_experts, dtype=jnp.int32) * live[:, None, None]
-    return jnp.max(jnp.sum(hits, axis=(0, 1)))
+    load = jnp.sum(hits, axis=(0, 1))
+    return jnp.stack([jnp.max(load), jnp.sum(load > 0, dtype=jnp.int32)])
 
 
 _MATMUL_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "router",
@@ -715,6 +750,7 @@ _MATMUL_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "router",
 # what a serving replica holds in cfg.dtype (`serving_params`)
 _HELD_KEYS = _MATMUL_KEYS + ("embed", "unembed")
 _STACKS = ("dense_layers", "layers")
+_EXPERT_KEYS = ("w_gate", "w_up", "w_down")
 
 
 # The compiler's constant folding walks the counters of the random bits, an
@@ -1025,14 +1061,15 @@ def _hc_collapse(x, cfg: TransformerConfig):
 
 def _block(x, lp, cfg: TransformerConfig, cos, sin, attend, constrain_fn, *,
            positions=None, head_major: bool = False, routed=None,
-           dense: bool = False):
+           dense: bool = False, layer=None):
     """One decoder layer, written once for every program: `_qkv` (or its
     latent sibling), the program's own attention, the output projection and
     its residual, then the post-norm MLP (dense or sparse experts) and its
     residual. Both residuals are `_residual`: the plain add, or the
     hyper-connection's mix over the n streams x then carries
     ([B, S, n, C]). `dense` marks a layer of the leading dense stack of a
-    model whose other layers have experts.
+    model whose other layers have experts; `layer` is the layer's index in
+    the model, needed where `lp` holds the expert stacks whole (`_moe`).
 
     `attend(q, k, v) -> (attn, kept)` is all that differs between the
     programs: causal / flash over the sequence itself (the trainer's
@@ -1059,7 +1096,7 @@ def _block(x, lp, cfg: TransformerConfig, cos, sin, attend, constrain_fn, *,
     h2 = rms_norm(u, lp["mlp_norm"], cfg.rms_norm_eps)
     stat = None
     if cfg.n_experts and not dense:
-        y, idx = _moe(h2, lp, cfg, constrain_fn)
+        y, idx = _moe(h2, lp, cfg, constrain_fn, layer)
         if routed is not None:
             stat = routed(idx)
     else:
@@ -1069,18 +1106,35 @@ def _block(x, lp, cfg: TransformerConfig, cos, sin, attend, constrain_fn, *,
     return constrain_fn(_residual(x, y, mix), *axes), kept, stat
 
 
-def _scan_stacks(layer_fn, carry, params, layer_ids):
+def _experts_in_place(cfg: TransformerConfig) -> bool:
+    """Whether `_moe_dropless` runs the expert layers: it reads a layer's
+    experts in place in their stack."""
+    return bool(cfg.n_experts and cfg.moe_impl != "dense"
+                and cfg.moe_capacity_factor is None)
+
+
+def _scan_stacks(layer_fn, carry, params, layer_ids, cfg: TransformerConfig):
     """`lax.scan` of `layer_fn(dense)(carry, (layer weights, layer index))`
     over the model's layer stacks in turn — the leading dense stack, where
     the model has one, then the main stack — with ONE running layer index
     (`layer_ids`: stack name -> its layers' indices into the KV pool).
-    -> (carry, the main stack's ys)."""
+    Dropless experts' three leaves are not sliced by the scan: every layer
+    gets them whole, [L, X, ...], under their own keys (`_moe_dropless`
+    says why). -> (carry, the main stack's ys)."""
     ys = None
     for stack in _STACKS:
-        if stack in params:
-            carry, ys = lax.scan(
-                layer_fn(stack == "dense_layers"), carry,
-                (params[stack], layer_ids[stack]))
+        if stack not in params:
+            continue
+        step, layers = layer_fn(stack == "dense_layers"), params[stack]
+        if stack == "layers" and _experts_in_place(cfg):
+            whole = {k: layers[k] for k in _EXPERT_KEYS}
+            layers = {k: a for k, a in layers.items() if k not in whole}
+
+            def step(carry, per_layer, step=step, whole=whole):
+                lp, l = per_layer
+                return step(carry, ({**lp, **whole}, l))
+
+        carry, ys = lax.scan(step, carry, (layers, layer_ids[stack]))
     return carry, ys
 
 
@@ -1464,14 +1518,16 @@ def make_paged_decoder(
 
     paged_decode_step(params, pool, tables[B,Nmax], tokens[B],
                       positions[B], write_phys[B], write_off[B], key)
-        -> (next_tokens[B], logits[B,V], pool, moe_hottest)
+        -> (next_tokens[B], logits[B,V], pool, moe_load)
       One cached decode step for every slot: the new K/V is written at the
       host-resolved (physical block, offset) pair — inactive slots route to
       the null block — and attention reads each slot's logical sequence
       via its block table. ONE compiled shape per (B, Nmax) regardless of
-      live sequence lengths or block-table contents. `moe_hottest` is None
-      without experts; with them, the load of the step's fullest expert
-      (pairs routed to it by the live slots), summed over the layers.
+      live sequence lengths or block-table contents. `moe_load` is None
+      without experts; with them int32 [2], both summed over the expert
+      layers: `moe_hottest`, the load of the step's fullest expert (pairs
+      routed to it by the live slots), and `moe_touched`, the experts with
+      at least one such pair — the groups whose weights the step reads.
 
     paged_verify_step(params, pool, tables[B,Nmax], tokens[B,K1],
                       positions[B], draft_len[B], write_phys[B,K1],
@@ -1892,12 +1948,12 @@ def make_paged_decoder(
                 return attn, (kc, vc, ksc, vsc)
 
             x, leaves, _ = _block(x, lp, cfg, cos, sin, attend, _constrain,
-                                  positions=qpos[None], dense=dense)
+                                  positions=qpos[None], dense=dense, layer=l)
             return (x, *leaves), None
 
         (x, *leaves), _ = _scan_stacks(
             lambda dense: partial(layer_fn, dense=dense),
-            (x,) + _pool_leaves(pool), params, layer_ids)
+            (x,) + _pool_leaves(pool), params, layer_ids, cfg)
         x = rms_norm(_hc_collapse(x, cfg), params["final_norm"],
                      cfg.rms_norm_eps)
         x_last = x[0, jnp.maximum(length - 1, 0)][None]
@@ -1950,10 +2006,10 @@ def make_paged_decoder(
             return (kc.at[l, write_phys].set(q8),
                     ksc.at[l, write_phys].set(s1))
 
-        def fullest(idx):
-            # the step's fullest expert among the live slots (an inactive
+        def load(idx):
+            # what the live slots ask of the layer's experts (an inactive
             # slot writes to the null block, 0)
-            return _fullest_expert(idx, write_phys > 0, cfg.n_experts)
+            return _expert_load(idx, write_phys > 0, cfg.n_experts)
 
         def layer_fn(carry, per_layer, dense=False):
             x, *leaves = carry
@@ -1990,20 +2046,20 @@ def make_paged_decoder(
                     attn = _cached_attend(q, kw, vw, kmask, scale, n_rep)
                 return attn, (kc, vc, ksc, vsc)
 
-            x, leaves, hottest = _block(
+            x, leaves, stats = _block(
                 x, lp, cfg, cos, sin, attend, _constrain, positions=pos2,
-                routed=fullest, dense=dense)
-            return (x, *leaves), hottest
+                routed=load, dense=dense, layer=l)
+            return (x, *leaves), stats
 
-        (x, *leaves), hottest = _scan_stacks(
+        (x, *leaves), stats = _scan_stacks(
             lambda dense: partial(layer_fn, dense=dense),
-            (x,) + _pool_leaves(pool), params, layer_ids)
+            (x,) + _pool_leaves(pool), params, layer_ids, cfg)
         x = rms_norm(_hc_collapse(x, cfg), params["final_norm"],
                      cfg.rms_norm_eps)
         logits = jnp.einsum("be,ev->bv", x[:, 0], _unembed_matrix(cfg, params))
         logits = _constrain(logits, "batch", "vocab")
-        moe_hottest = None if hottest is None else jnp.sum(hottest)
-        return _sample(logits, key), logits, _pool_dict(*leaves), moe_hottest
+        moe_load = None if stats is None else jnp.sum(stats, axis=0)
+        return _sample(logits, key), logits, _pool_dict(*leaves), moe_load
 
     def _rmw_commit_quant(kc, ksc, knew, wp_i, wo_i):
         """[L]-batched twin of the decode step's `_write_token_quant`:
@@ -2101,11 +2157,11 @@ def make_paged_decoder(
                 return attn, (k, v)
 
             x, kv, _ = _block(x, lp, cfg, cos, sin, attend, _constrain,
-                              positions=rope_pos)
+                              positions=rope_pos, layer=l)
             return x, kv
 
-        x, (ks, vs) = lax.scan(
-            layer_fn, x, (params["layers"], layer_ids["layers"]))
+        x, (ks, vs) = _scan_stacks(
+            lambda dense: layer_fn, x, params, layer_ids, cfg)
         x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
         logits = jnp.einsum("bse,ev->bsv", x, _unembed_matrix(cfg, params))
         logits = _constrain(logits, "batch", "seq", "vocab")

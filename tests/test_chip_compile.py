@@ -444,6 +444,51 @@ def test_latent_paged_programs_move_no_pool(one_chip, monkeypatch, program):
     assert temps[1] - temps[0] < 2 * BLOCK_TOKENS * 640 * 2, temps
 
 
+# ---- the experts are read in place in their [L, X, ...] stack -------------
+#
+# The grouped matmul takes a whole buffer as its weight operand. A layer's
+# [X, d_model, d_ff] slice of the stack was therefore COPIED in front of
+# each call (`dynamic-slice_bitcast_fusion`, three a layer: 61 % of OLMoE's
+# device time, 56 % of Xing4.0's, PERF.md PR 34). The paged programs now
+# hand the kernel the stack itself, viewed as L*X groups.
+
+
+def _operand_source(defs, name):
+    """The op that made `name`, seen through bitcasts."""
+    op, rest = defs[name]
+    while op == "bitcast":
+        op, rest = defs[re.match(r"%([^\s,)]+)", rest).group(1)]
+    return op
+
+
+@TREES
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("block", ["olmoe", "xing4"])
+def test_expert_weights_are_never_copied_out_of_their_stack(
+        one_chip, monkeypatch, block, program, tree):
+    # two expert layers each (Xing4.0's behind its dense one): a stack of
+    # one layer IS a layer's experts
+    cfg = _olmoe_block() if block == "olmoe" else _xing4_block(3)
+    _, compiled = _compile_paged_program(
+        one_chip, monkeypatch, program, "fused", False, 2049, cfg=cfg,
+        tree=tree)
+    text = compiled.as_text()
+    insts = _HLO_INSTRUCTION.findall(text)
+    X, d, f = cfg.n_experts, cfg.d_model, cfg.d_ff
+    one_layer = {f"{X},{d},{f}", f"{X},{f},{d}"}
+    made = [f"%{name}: {op} -> [{dims}]"
+            for _, name, _, dims, op, _ in insts if dims in one_layer]
+    assert not made, made
+    defs = {name: (op, rest) for _, name, _, _, op, rest in insts}
+    calls = [rest for _, _, _, _, op, rest in insts
+             if op == "custom-call" and "ragged_dot_tiling" in rest]
+    assert len(calls) == 3
+    for rest in calls:
+        operands = re.findall(r"%([^\s,)]+)", rest.split(")")[0])
+        assert _operand_source(defs, operands[-1]) in (
+            "parameter", "get-tuple-element"), rest[:200]
+
+
 # ---- the held tree: no weight is cast inside a paged program -------------
 #
 # A PagedDecodeEngine holds serving_params of its tree: matmul weights,
@@ -520,10 +565,8 @@ def test_held_tree_is_cast_in_no_paged_program(one_chip, monkeypatch, block,
             params["layers"][k] for k in _MATMUL_KEYS if k in params["layers"]]
     }, casts["f32"]
     assert not casts["held"], casts["held"]
-    # and the bfloat16 copies were temporaries of every call. (The OLMoE
-    # programs give back 0.03 % less than the stacked leaves' size: what
-    # remains — each layer's experts copied out of their stack — shares
-    # half a megabyte less with nothing.)
+    # and the bfloat16 copies were temporaries of every call (to within a
+    # thousandth: what else the two programs keep packs a little differently)
     stacked = sum(
         2 * params["layers"][k].size for k in _MATMUL_KEYS
         if k in params["layers"])

@@ -267,9 +267,14 @@ def test_decode_span_carries_the_expert_load(tmp_path):
     # pair; two tokens share an expert or not: one or two pairs a layer
     assert one["moe_hottest"] == cfg.n_layers
     assert cfg.n_layers <= both["moe_hottest"] <= 2 * cfg.n_layers
+    # ... and so each is a group the step reads: top_k a layer for one
+    # token, that to twice that for two
+    assert one["moe_touched"] == per_slot
+    assert per_slot <= both["moe_touched"] <= 2 * per_slot
     stats = eng.stats()
     assert stats["moe_pairs"] == 5 * per_slot
     assert stats["moe_hottest"] >= 3 * cfg.n_layers
+    assert 4 * per_slot <= stats["moe_touched"] <= 5 * per_slot
 
     _, dense = _tiny_engine(None)
     dense.admit(0, {"tokens": np.arange(1, 10), "max_new_tokens": 4})
@@ -277,8 +282,71 @@ def test_decode_span_carries_the_expert_load(tmp_path):
     with _Trace(tmp_path / "dense") as tr:
         dense.step([0])
     (_, _, st), = tr.spans("engine.decode")
-    assert "moe_pairs" not in st and "moe_hottest" not in st
-    assert dense.stats()["moe_pairs"] == 0
+    assert not {"moe_pairs", "moe_hottest", "moe_touched"} & set(st)
+    assert dense.stats()["moe_pairs"] == dense.stats()["moe_touched"] == 0
+
+
+def test_moe_touched_counts_the_groups_the_live_slots_read(tmp_path,
+                                                           monkeypatch):
+    """`moe_touched` on a hand-made routing: the number of distinct
+    (layer, expert) groups with a pair among the LIVE slots — the numpy
+    count — riding the array `moe_hottest` comes in (one fetch a step);
+    a slot that is not stepped routes too (to the null block) and is left
+    out. None for a dense model, as `moe_hottest` is."""
+    import ray_tpu.models.transformer as tfm
+
+    cfg = dataclasses.replace(CONFIGS["tiny_moe"], max_seq_len=128,
+                              n_layers=3, n_experts=8, top_k=3,
+                              moe_capacity_factor=None)
+    # slot -> its three experts, the same in every layer (a decode step's
+    # rows are the slots; a prefill's rows all take the first line)
+    choice = np.array([[0, 1, 2], [2, 3, 7], [1, 2, 3], [5, 6, 7]], np.int32)
+    route = tfm._moe_route
+
+    def hand_made(x, lp, cfg):
+        w, _ = route(x, lp, cfg)
+        rows = jnp.arange(x.shape[0]) % len(choice) * (x.shape[0] == 4)
+        return w, jnp.asarray(choice)[rows]
+
+    monkeypatch.setattr(tfm, "_moe_route", hand_made)
+    eng = PagedDecodeEngine(cfg, max_batch_size=4, seed=0, block_tokens=8)
+    for slot in range(4):
+        eng.admit(slot, {"tokens": np.arange(1, 8 + slot),
+                         "max_new_tokens": 8})
+    fetched = []
+    decode = eng._decode_step
+
+    def spy(*a):
+        out = decode(*a)
+        fetched.append(out[3])
+        return out
+
+    eng._decode_step = spy
+    eng.step([0, 1, 2, 3])  # compiled outside the trace
+    with _Trace(tmp_path) as tr:
+        for live in ([0, 1, 2, 3], [0, 2], [3], [1, 3]):
+            eng.step(live)
+    want_touched, want_hottest = [], []
+    for live in ([0, 1, 2, 3], [0, 1, 2, 3], [0, 2], [3], [1, 3]):
+        load = np.bincount(choice[live].ravel(), minlength=cfg.n_experts)
+        want_touched.append(cfg.n_layers * int(np.count_nonzero(load)))
+        want_hottest.append(cfg.n_layers * int(load.max()))
+    assert want_touched == [21, 21, 12, 9, 15]
+    spans = [st for _, _, st in tr.spans("engine.decode")]
+    assert [st["moe_touched"] for st in spans] == want_touched[1:]
+    assert [st["moe_hottest"] for st in spans] == want_hottest[1:]
+    # one array of expert statistics a step, both counts in it
+    assert all(a.shape == (2,) and a.dtype == jnp.int32 for a in fetched)
+    assert [a.tolist() for a in fetched] == [
+        list(pair) for pair in zip(want_hottest, want_touched)]
+    stats = eng.stats()
+    assert stats["moe_touched"] == sum(want_touched)
+    assert stats["moe_hottest"] == sum(want_hottest)
+
+    _, dense = _tiny_engine(None)
+    dense.admit(0, {"tokens": np.arange(1, 10), "max_new_tokens": 4})
+    out = dense._decode_step(*_program_args(dense, "paged_decode")[1])
+    assert out[3] is None
 
 
 # --------------------------------------------- (d) names on the device
@@ -333,7 +401,10 @@ def test_expert_layer_lowers_under_its_two_scopes(which, capacity):
     if which == "paged_prefill":
         dense.admit(0, {"tokens": np.arange(1, 10), "max_new_tokens": 2})
     fn, args = _program_args(dense, which)
-    assert "moe." not in fn.lower(*args).as_text(debug_info=True)
+    # by scope, not by "moe.": a location in the text may name a test file
+    # (tests/test_olmoe.py, where a cached helper was first traced)
+    dense_text = fn.lower(*args).as_text(debug_info=True)
+    assert "moe.route" not in dense_text and "moe.experts" not in dense_text
 
 
 def _flash_fwd(q, k, v):
@@ -458,6 +529,7 @@ def test_latent_decode_span_and_stats_keep_their_attributes(tmp_path):
     assert st["kv_tokens"] == 13
     assert st["moe_pairs"] == cfg.top_k * 1  # one expert layer of two layers
     assert st["moe_hottest"] == 1
+    assert st["moe_touched"] == cfg.top_k
     stats = eng.stats()
     assert stats["kv_bytes_per_token"] == cfg.n_layers * 128 * 2
     assert stats["kv_pool_bytes"] == eng.pool["kv"].nbytes
