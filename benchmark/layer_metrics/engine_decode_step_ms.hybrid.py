@@ -1,0 +1,6 @@
+"""`engine_decode_step_ms` in the long-session cell of the hybrid cache,
+where it is read beside completed tokens per second (the cell does not
+report `itl_p95_ms`: see `itl_p95_ms.hybrid`). Same reader, same facts."""
+from benchmark import common
+
+read = common.load_reader("engine_decode_step_ms")

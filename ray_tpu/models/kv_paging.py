@@ -15,6 +15,13 @@ the pool safe to oversubscribe:
                     blocks instead of recomputing prefill for the shared
                     span. Cache-held blocks are evicted LRU-leaf-first
                     under pool pressure.
+  StatePool         the rows of a hybrid cache's recurrent-state pool (a
+                    model with linear-attention layers keeps ONE state a
+                    sequence beside the KV blocks of its attention layers):
+                    a row per decode slot plus a free list of SNAPSHOT
+                    rows. A snapshot hangs on the prefix-cache node of the
+                    block boundary it was taken at; a prefix hit is usable
+                    only up to the deepest node that holds one.
   PagedDecodeEngine the `ContinuousBatcher` engine contract (admit / step /
                     release) over the pool, plus:
                       can_admit(request)  worst-case block-budget admission
@@ -68,9 +75,12 @@ from .transformer import (
     TransformerConfig,
     init_paged_kv_cache,
     init_params,
+    make_copy_state,
     make_paged_decoder,
     paged_kv_block_bytes,
+    paged_state_row_bytes,
     refuse_on_latent_pool,
+    refuse_on_state_pool,
     serving_params,
 )
 
@@ -142,6 +152,38 @@ class BlockAllocator:
         return int(self._ref[block])
 
 
+class StatePool:
+    """Rows of the recurrent-state pool (transformer.init_paged_kv_cache,
+    `STATE_LEAVES`): rows 0..n_slots-1 belong to the decode slots, row for
+    slot; the `n_snapshots` rows behind them are handed out as snapshots —
+    copies of a slot's row at a block boundary, kept on a prefix-cache node
+    (`PrefixCache.attach_snapshot`) until that node is dropped or the pool
+    needs the row for a newer one. This is the seam a cache that is not a
+    chain of blocks plugs into: rows are reserved (`take`), committed to a
+    node, copied for a fork or a restore (the engine's `copy_state`
+    program), and released (`give`); what a row costs — one sequence,
+    whatever its length — is the engine's `state_row_bytes`."""
+
+    def __init__(self, n_slots: int, n_snapshots: int):
+        if n_snapshots < 1:
+            raise ValueError(
+                f"a state pool needs >= 1 snapshot row, got {n_snapshots}")
+        self.n_slots = int(n_slots)
+        self.rows_total = self.n_slots + int(n_snapshots)
+        self._free: List[int] = list(
+            range(self.rows_total - 1, self.n_slots - 1, -1))
+
+    @property
+    def num_free(self) -> int:
+        return len(self._free)
+
+    def take(self) -> Optional[int]:
+        return self._free.pop() if self._free else None
+
+    def give(self, row: int) -> None:
+        self._free.append(int(row))
+
+
 class PrefixCache:
     """Hash-trie over full prompt blocks.
 
@@ -150,18 +192,31 @@ class PrefixCache:
     a node iff they share every token up to and including that block. The
     cache holds its own reference on every registered block; a block whose
     only reference is the cache's (refcount 1) is evictable, leaf-first in
-    LRU order so chains never dangle."""
+    LRU order so chains never dangle.
 
-    def __init__(self, allocator: BlockAllocator, block_tokens: int):
+    On a hybrid cache (`state_pool` given) a node may also hold a SNAPSHOT:
+    the row of the state pool with the recurrent state after the node's
+    last token ("snap"). A snapshot lives and dies with its node — node
+    eviction and `flush` hand the row back — and when the state pool has no
+    free row the oldest snapshot that no admission ever restored is dropped
+    first, then the least recently used of the restored ones: the few
+    histories every session returns to outlive the tails of finished
+    requests, however many of those pass."""
+
+    def __init__(self, allocator: BlockAllocator, block_tokens: int,
+                 state_pool: Optional[StatePool] = None):
         self._alloc = allocator
         self.block_tokens = int(block_tokens)
-        # key -> {"block": int, "parent": key, "ts": int}
+        self._state = state_pool
+        # key -> {"block": int, "parent": key, "ts": int} (+ "snap": row,
+        # "used": restores, on a hybrid cache)
         self._nodes: Dict[bytes, Dict[str, Any]] = {}
         self._children: Dict[bytes, set] = {}
         self._clock = 0
         self.hits = 0
         self.evictions = 0
         self.flushes = 0
+        self.snapshot_evictions = 0
 
     def __len__(self) -> int:
         return len(self._nodes)
@@ -197,6 +252,75 @@ class PrefixCache:
         if out:
             self.hits += 1
         return out
+
+    def lookup_snapshot(self, prompt: np.ndarray, max_blocks: int,
+                        touch: bool = True) -> Tuple[List[int], Optional[int]]:
+        """The hybrid cache's lookup: the matching chain cut at its DEEPEST
+        node that holds a snapshot -> (its blocks, that snapshot's row), or
+        ([], None) when no node of the chain has one — keys and values
+        without the state after them cannot be resumed from. `touch`
+        LRU-touches the whole matching chain and counts the hit (off for
+        admission budgeting)."""
+        blocks, depth, found = [], 0, None
+        for _, node in self._chain(prompt, max_blocks):
+            if touch:
+                node["ts"] = self._tick()
+            blocks.append(node["block"])
+            if node.get("snap") is not None:
+                depth, found = len(blocks), node
+        if found is None:
+            return [], None
+        if touch:
+            found["used"] = found.get("used", 0) + 1
+            self.hits += 1
+        return blocks[:depth], found["snap"]
+
+    def _node_at(self, prompt: np.ndarray, n_blocks: int):
+        """the node of the prompt's first `n_blocks` blocks, or None."""
+        chain = list(self._chain(prompt, n_blocks))
+        return chain[-1][1] if len(chain) == n_blocks else None
+
+    def attach_snapshot(self, prompt: np.ndarray, n_blocks: int,
+                        row: Optional[int] = None) -> Optional[int]:
+        """Hang a snapshot on the node of the prompt's first `n_blocks`
+        blocks (registered already): `row` if the caller has filled one,
+        else a row reserved here, which the caller must now copy the state
+        into -> the row, or None: the node already holds a snapshot, or
+        there is no such node. A full state pool drops a snapshot first
+        (`steal_snapshot`)."""
+        node = self._node_at(prompt, n_blocks) if n_blocks else None
+        if node is None or node.get("snap") is not None:
+            return None
+        if row is None:
+            row = self._state.take()
+        if row is None:
+            row = self.steal_snapshot(keep=node)
+        node["snap"] = row
+        return row
+
+    def steal_snapshot(self, keep=None) -> Optional[int]:
+        """Drop one node's snapshot for its row (the node keeps its block):
+        the oldest that no admission restored, else the least recently
+        used. None when no node (but `keep`) holds one."""
+        victim = min(
+            (n for n in self._nodes.values()
+             if n.get("snap") is not None and n is not keep),
+            key=lambda n: (n.get("used", 0) > 0, n["ts"]), default=None)
+        if victim is None:
+            return None
+        row, victim["snap"] = victim["snap"], None
+        self.snapshot_evictions += 1
+        return row
+
+    def snapshots(self) -> int:
+        return sum(1 for n in list(self._nodes.values())
+                   if n.get("snap") is not None)
+
+    def _drop_snapshot(self, node) -> None:
+        if node.get("snap") is not None:
+            self._state.give(node["snap"])
+            node["snap"] = None
+            self.snapshot_evictions += 1
 
     def match_count(self, prompt: np.ndarray, max_blocks: int) -> int:
         """lookup() length without the LRU touch (admission budgeting)."""
@@ -258,6 +382,7 @@ class PrefixCache:
                 self._children.get(node["parent"], set()).discard(key)
                 self._children.pop(key, None)
                 self._alloc.decref(node["block"])
+                self._drop_snapshot(node)
                 self.evictions += 1
                 freed += 1
         return freed
@@ -272,6 +397,7 @@ class PrefixCache:
         n = len(self._nodes)
         for node in self._nodes.values():
             self._alloc.decref(node["block"])
+            self._drop_snapshot(node)
         self._nodes.clear()
         self._children.clear()
         self.flushes += 1
@@ -326,6 +452,7 @@ class PagedDecodeEngine:
         telemetry=None,
         model_id: Optional[str] = None,
         logprobs: bool = False,
+        n_snapshots: Optional[int] = None,
     ):
         import jax
         import jax.numpy as jnp
@@ -373,6 +500,14 @@ class PagedDecodeEngine:
         self.kv_cache_dtype = kv_cache_dtype
         kv_dtype = jnp.int8 if kv_cache_dtype == "int8" else cfg.dtype
         self.kv_block_bytes = paged_kv_block_bytes(cfg, bt, kv_dtype)
+        # a hybrid cache: the model's linear layers keep one recurrent
+        # state a sequence in a state pool beside the KV pool (StatePool)
+        self.hybrid = bool(cfg.layers_of("linear"))
+        self.state_row_bytes = paged_state_row_bytes(cfg)
+        if n_snapshots is not None and not self.hybrid:
+            raise ValueError(
+                "n_snapshots given but the model has no linear layers: "
+                "there is no state pool to size")
 
         # cross-replica transfer identity (serve/kv_transfer.py): two
         # engines produce matching export keys iff they agree on every
@@ -448,6 +583,9 @@ class PagedDecodeEngine:
         # a latent (MLA) pool: what it does not support fails here, by name
         refuse_on_latent_pool(cfg, kv_dtype=kv_dtype, mesh=mesh,
                               speculative_k=speculative_k)
+        # ... and so does a hybrid cache
+        refuse_on_state_pool(cfg, kv_dtype=kv_dtype, mesh=mesh,
+                             speculative_k=speculative_k)
         self.drafter = None
         if speculative_k:
             if temperature > 0.0:
@@ -580,11 +718,22 @@ class PagedDecodeEngine:
         self.allocator = BlockAllocator(self.num_blocks)
         if prefix_cache is None:
             prefix_cache = bool(gcfg.serve_kv_prefix_cache)
+        self.state_pool = None
+        if self.hybrid:
+            # twice the slots by default: every slot's running tail and as
+            # many snapshots on cached prefixes again
+            self.state_pool = StatePool(
+                self.max_batch_size,
+                2 * self.max_batch_size if n_snapshots is None
+                else int(n_snapshots))
+            self._copy_state = make_copy_state()
         self.prefix_cache = (
-            PrefixCache(self.allocator, bt) if prefix_cache else None
+            PrefixCache(self.allocator, bt, self.state_pool)
+            if prefix_cache else None
         )
         self.pool = init_paged_kv_cache(
-            cfg, self.num_blocks, bt, mesh=mesh, rules=rules, dtype=kv_dtype
+            cfg, self.num_blocks, bt, mesh=mesh, rules=rules, dtype=kv_dtype,
+            state_rows=self.state_pool.rows_total if self.hybrid else 0,
         )
         self._prefill, self._decode_step, self._verify_step, self._copy_blocks = (
             make_paged_decoder(
@@ -626,6 +775,13 @@ class PagedDecodeEngine:
         # logprob of the pending first sampled token per slot (set by the
         # completing prefill chunk, read by admit()/step() when emitting)
         self._lp_pending = np.zeros(B, np.float64)
+        # hybrid cache: the slot's running TAIL snapshot — (row, tokens):
+        # its state as of its last whole block, taken when decode crossed
+        # that boundary; it goes onto the prefix cache when the slot's
+        # blocks are released
+        self._tail_snap: List[Optional[Tuple[int, int]]] = [None] * B
+        self.state_snapshots = 0
+        self.state_restores = 0
 
         # counters (bench/observability/tests)
         self.tokens_generated = 0
@@ -678,6 +834,15 @@ class PagedDecodeEngine:
             sig.update(
                 f"|latent={self.cfg.kv_lora_rank}+{self.cfg.qk_rope_head_dim}"
                 f"/{self.cfg.latent_row}".encode())
+        if self.hybrid:
+            # a hybrid cache: the state's geometry joins the key space, so
+            # unlike replicas refuse each other
+            c = self.cfg
+            sig.update(
+                f"|state={c.layers_of('linear')}x{c.linear_n_heads}"
+                f"x{c.linear_d_k}x{c.linear_d_v}/{self.state_row_bytes}B"
+                f"|conv={c.linear_conv_kernel}"
+                f"|kvL={c.layers_of('full')}".encode())
         # version 0 (never swapped) keeps the original byte layout, so
         # engines that never hot-swap interoperate with older peers; any
         # swap moves the whole key space
@@ -715,7 +880,69 @@ class PagedDecodeEngine:
             return True
         return int(self._positions[slot]) >= self.max_seq_len
 
+    # ------------------------------------------------ hybrid cache: state
+
+    def _hang_snapshot(self, slot: int, tokens: np.ndarray, depth: int,
+                       row: Optional[int] = None) -> Optional[int]:
+        """Register the slot's first `depth` tokens' blocks (a block
+        multiple; the slot's table must still hold them) and hang a
+        snapshot on their node: `row` if it is filled already, else a row
+        reserved for the caller to fill -> the row, or None (the node holds
+        a snapshot already)."""
+        n = depth // self.block_tokens
+        self.prefix_cache.register(
+            tokens, [int(b) for b in self._tables[slot, :n]])
+        return self.prefix_cache.attach_snapshot(tokens, n, row)
+
+    def _snapshot(self, slot: int, tokens: np.ndarray, depth: int) -> None:
+        """Leave a snapshot of the slot's state row, which has consumed
+        exactly the first `depth` tokens, on their prefix-cache node."""
+        row = self._hang_snapshot(slot, tokens, depth)
+        if row is not None:
+            self._copy_rows(slot, row, "engine.state_snapshot", depth)
+            self.state_snapshots += 1
+
+    def _copy_rows(self, src: int, dst: int, name: str, tokens: int) -> None:
+        with span(name, slot=min(src, dst), tokens=int(tokens)):
+            self.pool = self._copy_state(
+                self.pool, np.asarray([src], np.int32),
+                np.asarray([dst], np.int32))
+
+    def _keep_tail(self, slot: int) -> None:
+        """Decode has just carried the slot onto a block boundary: copy its
+        row into the slot's tail snapshot (one row a slot, overwritten as
+        the sequence grows; taken from the free rows, else from the cache's
+        oldest snapshot)."""
+        held = self._tail_snap[slot]
+        row = held[0] if held else self.state_pool.take()
+        if row is None and self.prefix_cache is not None:
+            row = self.prefix_cache.steal_snapshot()
+        if row is None:
+            return
+        depth = int(self._positions[slot])
+        self._copy_rows(slot, row, "engine.state_snapshot", depth)
+        self.state_snapshots += 1
+        self._tail_snap[slot] = (row, depth)
+
+    def _commit_tail(self, slot: int) -> None:
+        """The slot's blocks are about to go: its tail snapshot moves onto
+        the prefix-cache node of its depth (a finished sequence leaves its
+        state at its last whole block; a preempted one finds it again at
+        re-admission), or back to the free rows."""
+        held, self._tail_snap[slot] = self._tail_snap[slot], None
+        if held is None:
+            return
+        row, depth = held
+        hist = self._history[slot]
+        if self.prefix_cache is not None and hist is not None:
+            tokens = np.asarray(hist[:depth], np.int32)
+            if self._hang_snapshot(slot, tokens, depth, row) is not None:
+                return
+        self.state_pool.give(row)
+
     def _release_blocks(self, slot: int) -> None:
+        if self.hybrid:
+            self._commit_tail(slot)
         for bi in range(int(self._row_blocks[slot])):
             b = int(self._tables[slot, bi])
             if b:
@@ -783,9 +1010,11 @@ class PagedDecodeEngine:
         if self.prefix_cache is not None:
             evictable = self.prefix_cache.evictable()
             if length > 1:
-                hits = self.prefix_cache.match_blocks(
-                    prompt, (length - 1) // self.block_tokens
-                )
+                cap = (length - 1) // self.block_tokens
+                # a hybrid cache reuses only up to its deepest snapshot
+                hits = (self.prefix_cache.lookup_snapshot(
+                            prompt, cap, touch=False)[0] if self.hybrid
+                        else self.prefix_cache.match_blocks(prompt, cap))
                 reusable = len(hits)
                 # a cache-only hit block is counted in evictable() but
                 # admission will PIN it (incref), not evict it — counting
@@ -858,8 +1087,18 @@ class PagedDecodeEngine:
         # length-1 so at least one real token remains to prefill (its
         # hidden state produces the first sampled token)
         hit_blocks: List[int] = []
+        snap_row = None
         if self.prefix_cache is not None and length > 1:
-            hit_blocks = self.prefix_cache.lookup(prompt, (length - 1) // bt)
+            if self.hybrid:
+                # ... and on a hybrid cache only as deep as the deepest
+                # node with a state snapshot: keys and values alone cannot
+                # be resumed from. What lies behind it in the cache is
+                # computed again, into blocks of the slot's own
+                hit_blocks, snap_row = self.prefix_cache.lookup_snapshot(
+                    prompt, (length - 1) // bt)
+            else:
+                hit_blocks = self.prefix_cache.lookup(
+                    prompt, (length - 1) // bt)
         p_hit = len(hit_blocks) * bt
         for b in hit_blocks:
             self.allocator.incref(b)
@@ -911,6 +1150,11 @@ class PagedDecodeEngine:
         if self._rec is not None:
             self._rec.record("admit", slot=slot,
                              args={"prompt": length, "hit_tokens": p_hit})
+        if snap_row is not None:
+            # the state after the reused tokens, into the slot's row (a
+            # cold start needs no copy: prefill starts from zeros at ctx 0)
+            self._copy_rows(snap_row, slot, "engine.state_restore", p_hit)
+            self.state_restores += 1
 
         chunk = self.prefill_chunk_tokens
         if chunk and length - p_hit > chunk:
@@ -979,11 +1223,16 @@ class PagedDecodeEngine:
         next_tok, logits, self.pool = self._prefill(
             self.params, self.pool, self._tables[slot],
             padded[None], np.int32(take), np.int32(ctx),
-            key, ctx_blocks,
+            key, ctx_blocks, **({"row": np.int32(slot)} if self.hybrid else {}),
         )
         self._positions[slot] = ctx + take
         self.prefill_tokens += take
         self.prefill_chunks += 1
+        if (self.hybrid and self.prefix_cache is not None
+                and (ctx + take) % bt == 0):
+            # the chunk ended on a block boundary: the state behind it is
+            # worth keeping (a prompt that shares these tokens resumes here)
+            self._snapshot(slot, prompt, ctx + take)
         if not last:
             return None
         tok = int(next_tok[0])
@@ -1033,6 +1282,11 @@ class PagedDecodeEngine:
         self._history[dst] = list(self._history[src] or [])
         self._seq += 1
         self._admit_seq[dst] = self._seq
+        if self.hybrid:
+            # the state cannot be shared by reference: the fork gets a copy
+            self._tail_snap[dst] = None
+            self._copy_rows(src, dst, "engine.state_restore",
+                            int(self._positions[src]))
 
     def force_token(self, slot: int, token: int) -> None:
         """Teacher-force the next input token for `slot` (replaces the
@@ -1243,6 +1497,10 @@ class PagedDecodeEngine:
                     self._rec.record("eos", slot=s)
             self.decode_steps += 1
             self.tokens_generated += len(surviving)
+        if self.hybrid:
+            for s in surviving:
+                if int(self._positions[s]) % bt == 0:
+                    self._keep_tail(s)
         return out
 
     # ----------------------------------------------------- speculative path
@@ -1508,6 +1766,14 @@ class PagedDecodeEngine:
 
     # --------------------------------------------- cross-replica KV transfer
 
+    def _refuse_transfer(self, what: str) -> None:
+        if self.hybrid:
+            raise NotImplementedError(
+                f"a hybrid cache (recurrent state beside KV) does not "
+                f"support {what}: a payload of blocks without the state "
+                "snapshot behind them cannot be resumed from, and shipping "
+                "snapshots is not written (ROADMAP, Reach A6)")
+
     def transfer_keys(self, tokens, n_blocks: int) -> List[bytes]:
         """Content-addressed keys for the prompt's first `n_blocks` FULL
         blocks. The chain is seeded with `transfer_sig` (model_id + block
@@ -1547,6 +1813,7 @@ class PagedDecodeEngine:
         admit/step — the match and the pool gather must see one
         consistent pool state); serving code routes here via
         ContinuousBatcher.run_on_loop."""
+        self._refuse_transfer("export_prefix")
         if self.prefix_cache is None:
             return None
         prompt = np.asarray(tokens, np.int32)
@@ -1595,6 +1862,7 @@ class PagedDecodeEngine:
         thread only (admit() applies request-borne payloads itself)."""
         import jax.numpy as jnp
 
+        self._refuse_transfer("import_prefix")
         bt = self.block_tokens
         tokens = None
         n = 0
@@ -1729,6 +1997,21 @@ class PagedDecodeEngine:
             ),
             "prefix_hits": self.prefix_hits,
             "prefix_tokens_reused": self.prefix_tokens_reused,
+            # hybrid cache (0 / None without linear layers): what one
+            # sequence's recurrent state costs whatever its length, the
+            # state pool's snapshot rows, and the snapshots taken, restored
+            # into a slot at admission, and dropped (with their node, or
+            # for their row)
+            "state_bytes_per_seq": self.state_row_bytes,
+            "state_rows_total": (
+                self.state_pool.rows_total if self.hybrid else 0),
+            "state_rows_free": (
+                self.state_pool.num_free if self.hybrid else 0),
+            "state_snapshots": self.state_snapshots,
+            "state_restores": self.state_restores,
+            "state_snapshot_evictions": (
+                self.prefix_cache.snapshot_evictions
+                if self.prefix_cache is not None else 0),
             # cross-replica KV transfer (serve/kv_transfer.py): rejects
             # count payloads dropped at verification or under pool
             # pressure — each one is a recompute fallback upstream

@@ -186,8 +186,58 @@ class TransformerConfig:
     hc_sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
     hc_res_clamp: float = 30.0
+    # a layer PERIOD: the kinds ("linear" | "full") of the blocks that
+    # repeat through the depth, n_layers / len(period) times. "full" is the
+    # attention layer of the fields above (params["layers"], stacked over
+    # the full layers only); "linear" is a gated-delta-rule layer
+    # (ops/gated_delta.py; params["linear_layers"]) of linear_n_heads heads
+    # with linear_d_k-wide keys and linear_d_v-wide values behind a causal
+    # depthwise convolution of kernel linear_conv_kernel. () = every layer
+    # is the attention layer
+    layer_period: Tuple[str, ...] = ()
+    linear_n_heads: int = 0
+    linear_d_k: int = 0
+    linear_d_v: int = 0
+    linear_conv_kernel: int = 4
+    # where a sublayer's RMSNorm sits: "pre" x + F(norm(x)), or "post"
+    # x + norm(F(x)) — on the sublayer's OUTPUT, none on its input
+    norm_placement: str = "pre"
+    # False: q and k are not rotated (positions reach such a layer only
+    # through what the layers before it carry)
+    use_rope: bool = True
 
     def __post_init__(self):
+        if self.norm_placement not in ("pre", "post"):
+            raise ValueError(
+                f"norm_placement must be 'pre' or 'post', "
+                f"got {self.norm_placement!r}"
+            )
+        if self.layer_period:
+            object.__setattr__(self, "layer_period", tuple(self.layer_period))
+            bad = sorted(set(self.layer_period) - {"linear", "full"})
+            if bad:
+                raise ValueError(
+                    f"layer_period kinds must be 'linear' or 'full', got {bad}")
+            if self.n_layers % len(self.layer_period):
+                raise ValueError(
+                    f"n_layers {self.n_layers} is not whole periods of "
+                    f"{len(self.layer_period)} layers")
+            if "linear" in self.layer_period and not (
+                    self.linear_n_heads and self.linear_d_k
+                    and self.linear_d_v and self.linear_conv_kernel > 1):
+                raise ValueError(
+                    "a 'linear' layer needs linear_n_heads, linear_d_k, "
+                    "linear_d_v and linear_conv_kernel > 1")
+            for name in ("n_experts", "first_k_dense", "kv_lora_rank",
+                         "hc_mult"):
+                if getattr(self, name):
+                    raise NotImplementedError(
+                        f"a layer period beside {name}="
+                        f"{getattr(self, name)!r} is not written")
+            if self.pp_stages > 1:
+                raise NotImplementedError(
+                    "pp_stages > 1 over a layer period is not written: a "
+                    "stage boundary would have to fall between periods")
         if self.mlp_variant not in ("silu_gate", "gelu"):
             raise ValueError(
                 f"mlp_variant must be 'silu_gate' or 'gelu', "
@@ -244,6 +294,33 @@ class TransformerConfig:
     def n_expert_layers(self) -> int:
         return self.n_layers - self.first_k_dense if self.n_experts else 0
 
+    def layers_of(self, kind: str) -> int:
+        """How many of the model's layers are of `kind` ("linear" |
+        "full"); without a period every layer is "full"."""
+        if not self.layer_period:
+            return self.n_layers if kind == "full" else 0
+        return (self.layer_period.count(kind)
+                * (self.n_layers // len(self.layer_period)))
+
+    @property
+    def kv_pool_heads(self) -> int:
+        """KV heads of the paged pool's leaves: n_kv_heads, rounded up to
+        whole 8-row tiles where it is more than one tile and not whole ones
+        (30 -> 32). A [.., block_tokens, 30, 128] leaf is given a device
+        layout with the HEAD dim major over the tokens (padding-free), and
+        every program would relay the whole pool into the row-major layout
+        its scatters and the paged kernel take, and back: two pool-sized
+        copies a step. Row-major tiles pad 30 to 32 rows anyway; the two
+        extra heads are written as zeros and attended by two zero query
+        heads that are dropped."""
+        kv = self.n_kv_heads
+        return kv if kv <= 8 or kv % 8 == 0 else -(-kv // 8) * 8
+
+    @property
+    def linear_channels(self) -> int:
+        """Channels of a linear layer's convolution: [q | k | v]."""
+        return self.linear_n_heads * (2 * self.linear_d_k + self.linear_d_v)
+
     def flops_per_token(self) -> float:
         """Approximate training FLOPs/token (fwd+bwd ≈ 6 * params-matmul)."""
         attn = 2 * self.d_model * self.d_head * (self.n_heads + 2 * self.n_kv_heads)
@@ -272,7 +349,16 @@ class TransformerConfig:
             lp += self.n_experts * 3 * self.d_model * self.d_ff
         else:
             lp += (2 if self.mlp_variant == "gelu" else 3) * self.d_model * self.d_ff
-        total = self.n_layers * lp + self.d_model
+        total = self.layers_of("full") * lp + self.d_model
+        if self.layers_of("linear"):
+            H, dk, dv = self.linear_n_heads, self.linear_d_k, self.linear_d_v
+            total += self.layers_of("linear") * (
+                2 * self.d_model                          # norms
+                + self.d_model * H * (2 * dk + 2 * dv)    # q, k, v, gate
+                + 2 * self.d_model * H + 2 * H            # a, b, A_log, dt_bias
+                + self.linear_channels * self.linear_conv_kernel
+                + dv + H * dv * self.d_model              # o_norm, wo
+                + 3 * self.d_model * self.d_ff)
         total += self.vocab_size * self.d_model * (1 if self.tie_embeddings else 2)
         return total
 
@@ -385,6 +471,40 @@ def _extra_leaves(cfg: TransformerConfig, stack: str):
     return out
 
 
+def _linear_leaves(cfg: TransformerConfig):
+    """(name, shape without the layer dim, logical axes, kind) of ONE
+    linear (gated-delta-rule) layer, `_extra_leaves`' form: the four
+    projections q, k, v and the output gate, the two H-wide gates a (decay)
+    and b (write strength), the depthwise convolution over [q | k | v],
+    A_log and dt_bias of the decay, the dv-wide scale of the gated output
+    norm (shared by the heads), the output projection, the MLP and the two
+    norm scales. Kinds beside a fan-in / "ones": "a_log" = ln U(0, 16),
+    "dt_bias" = softplus^-1 of exp U(ln 1e-3, ln 1e-1) — the published
+    implementation's draws, decays between ~0.2 and ~0.9999 a token."""
+    E, F = cfg.d_model, cfg.d_ff
+    H, dk, dv = cfg.linear_n_heads, cfg.linear_d_k, cfg.linear_d_v
+    hd = ("embed", "heads", "head_dim")
+    return [
+        ("attn_norm", (E,), ("embed",), "ones"),
+        ("wq", (E, H, dk), hd, E),
+        ("wk", (E, H, dk), hd, E),
+        ("wv", (E, H, dv), hd, E),
+        ("wg", (E, H, dv), hd, E),
+        ("wa", (E, H), ("embed", "heads"), E),
+        ("wb", (E, H), ("embed", "heads"), E),
+        ("conv_w", (cfg.linear_channels, cfg.linear_conv_kernel),
+         (None, None), cfg.linear_conv_kernel),
+        ("a_log", (H,), ("heads",), "a_log"),
+        ("dt_bias", (H,), ("heads",), "dt_bias"),
+        ("o_norm", (dv,), ("head_dim",), "ones"),
+        ("wo", (H, dv, E), ("heads", "head_dim", "embed"), H * dv),
+        ("mlp_norm", (E,), ("embed",), "ones"),
+        ("w_gate", (E, F), ("embed", "mlp"), E),
+        ("w_up", (E, F), ("embed", "mlp"), E),
+        ("w_down", (F, E), ("mlp", "embed"), F),
+    ]
+
+
 def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
     """Logical-axis tuples mirroring the param pytree. With pp_stages>1 the
     layer leaves carry a leading ("stage",) dim sharded on the pp axis."""
@@ -437,6 +557,9 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
         specs["dense_layers"] = {
             "attn_norm": ("layers", "embed"), "mlp_norm": ("layers", "embed"),
             **extras("dense_layers")}
+    if cfg.layers_of("linear"):
+        specs["linear_layers"] = {
+            n: ("layers",) + ax for n, _, ax, _ in _linear_leaves(cfg)}
     if not cfg.tie_embeddings:
         specs["unembed"] = ("embed", "vocab")
     return specs
@@ -461,7 +584,7 @@ def init_params(rng: jax.Array, cfg: TransformerConfig, *,
         cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.d_head, cfg.d_ff,
     )
     Ld = cfg.first_k_dense
-    L = L - Ld  # layers of the main stack
+    L = cfg.layers_of("full") - Ld  # layers of the main stack
     keys = iter(jax.random.split(rng, 16))
     dtype = jnp.dtype(cfg.dtype)
 
@@ -484,12 +607,20 @@ def init_params(rng: jax.Array, cfg: TransformerConfig, *,
     def named_key(stack, name):
         return jax.random.fold_in(rng, zlib.crc32(f"{stack}/{name}".encode()))
 
-    def extras(stack, n):
+    def extras(stack, n, leaves=None):
         out = {}
-        for name, shape, _, kind in _extra_leaves(cfg, stack):
+        for name, shape, _, kind in leaves or _extra_leaves(cfg, stack):
             key, shape = named_key(stack, name), (n,) + shape
             if kind == "ones":
                 leaf = norm_init(*shape)
+            elif kind == "a_log":
+                leaf = jnp.log(jax.random.uniform(
+                    key, shape, jnp.float32, minval=1e-4, maxval=16.0))
+            elif kind == "dt_bias":
+                dt = jnp.exp(jax.random.uniform(
+                    key, shape, jnp.float32, minval=math.log(1e-3),
+                    maxval=math.log(1e-1)))
+                leaf = dt + jnp.log(-jnp.expm1(-dt))
             elif kind == "bias":
                 # moves the choice between near-equal experts, not all of it
                 leaf = 0.05 * jax.random.normal(key, shape, jnp.float32)
@@ -562,6 +693,9 @@ def init_params(rng: jax.Array, cfg: TransformerConfig, *,
         params["dense_layers"] = {
             "attn_norm": norm_init(Ld, E), "mlp_norm": norm_init(Ld, E),
             **extras("dense_layers", Ld)}
+    if cfg.layers_of("linear"):
+        params["linear_layers"] = extras(
+            "linear_layers", cfg.layers_of("linear"), _linear_leaves(cfg))
     return params
 
 
@@ -746,10 +880,13 @@ def _expert_load(idx, live, n_experts: int):
 
 
 _MATMUL_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "router",
-                "wq_a", "wq_b", "wkv_a", "wkv_b", "ws_gate", "ws_up", "ws_down")
+                "wq_a", "wq_b", "wkv_a", "wkv_b", "ws_gate", "ws_up", "ws_down",
+                "wg", "wa", "wb")
 # what a serving replica holds in cfg.dtype (`serving_params`)
 _HELD_KEYS = _MATMUL_KEYS + ("embed", "unembed")
 _STACKS = ("dense_layers", "layers")
+# the stacks of a layer period, by the kind of their layers
+_KIND_STACK = {"linear": "linear_layers", "full": "layers"}
 _EXPERT_KEYS = ("w_gate", "w_up", "w_down")
 
 
@@ -774,7 +911,7 @@ def _map_matmul_leaves(params, fn):
     """`params` with `fn` applied to the stacked matmul weights of every
     layer stack it has."""
     out = dict(params)
-    for stack in _STACKS:
+    for stack in _STACKS + ("linear_layers",):
         if stack in params:
             layers = dict(params[stack])
             for key in _MATMUL_KEYS:
@@ -859,11 +996,13 @@ def _qkv(x, lp, cfg: TransformerConfig, cos, sin, positions=None,
     """(q, k, v) of one layer from the layer's input x [B, S, E] — the
     front half of `_block`: pre-norm with the config's eps, the three
     projections, QK-norm over the whole projection when the config has it,
-    RoPE at `positions` ([B, S] or [1, S]; None = 0..S-1). Layout
+    RoPE at `positions` ([B, S] or [1, S]; None = 0..S-1) — no input norm
+    under `norm_placement="post"`, no rotation without `use_rope`. Layout
     [B, S, H, D], or [B, H, S, D] when `head_major` (the training forward's
     kernel-native layout, which also names q, k, v for the remat
     policies)."""
-    h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+    h = x if cfg.norm_placement == "post" else rms_norm(
+        x, lp["attn_norm"], cfg.rms_norm_eps)
     out = "bhsd" if head_major else "bshd"
     q = jnp.einsum(f"bse,ehd->{out}", h, lp["wq"].astype(h.dtype))
     k = jnp.einsum(f"bse,ehd->{out}", h, lp["wk"].astype(h.dtype))
@@ -872,11 +1011,16 @@ def _qkv(x, lp, cfg: TransformerConfig, cos, sin, positions=None,
         q = _whole_projection_norm(q, lp["q_norm"], cfg.rms_norm_eps, head_major)
         k = _whole_projection_norm(k, lp["k_norm"], cfg.rms_norm_eps, head_major)
     if not head_major:
-        q = apply_rope(q, cos, sin, positions=positions)
-        k = apply_rope(k, cos, sin, positions=positions)
+        if cfg.use_rope:
+            q = apply_rope(q, cos, sin, positions=positions)
+            k = apply_rope(k, cos, sin, positions=positions)
         return q, k, v
     assert positions is None, "head-major RoPE runs at positions 0..S-1"
     from jax.ad_checkpoint import checkpoint_name
+
+    if not cfg.use_rope:
+        return (checkpoint_name(q, "rope_q"), checkpoint_name(k, "rope_k"),
+                checkpoint_name(v, "attn_v"))
 
     # post-rope q/k and v are named so the flash remat policies can save
     # exactly these — backward then reads them instead of re-deriving
@@ -917,6 +1061,8 @@ def _rope_tables(cfg: TransformerConfig):
     """(cos, sin) [max_seq_len, rope_dim // 2] of the model's RoPE: over
     d_head, or under latent attention over qk_rope_head_dim, YaRN-scaled
     where `rope_factor` says so."""
+    if not cfg.use_rope:
+        return None, None
     dim = cfg.qk_rope_head_dim if cfg.kv_lora_rank else cfg.d_head
     if cfg.rope_factor <= 1:
         return rope_frequencies(dim, cfg.max_seq_len, cfg.rope_theta)
@@ -1059,13 +1205,71 @@ def _hc_collapse(x, cfg: TransformerConfig):
     return jnp.sum(x.astype(jnp.float32), axis=2).astype(x.dtype)
 
 
+def _linear_mix(x, lp, cfg: TransformerConfig, recur):
+    """The token-mixing half of a linear (gated-delta-rule) layer, the
+    sibling of `_qkv` + attend + the output projection: from the sublayer's
+    input x [B, S, E] the projections q, k, v (one [B, S, C] row, the
+    convolution's input), the output gate z, and the decay and write
+    strength (`gate_and_beta`, float32); then the program's own
+    `recur(u, g, beta) -> (o [B, S, H, dv] float32, kept)` — convolution
+    from its tail, the delta rule from its state, both wherever the
+    program keeps them — the gated RMSNorm over each head's dv values and
+    the output projection. -> (y [B, S, E], kept)."""
+    from ..ops.gated_delta import gate_and_beta
+
+    B, S, _ = x.shape
+    if cfg.norm_placement != "post":
+        x = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+    u = jnp.concatenate([
+        jnp.einsum("bse,ehd->bshd", x, lp[w].astype(x.dtype)).reshape(B, S, -1)
+        for w in ("wq", "wk", "wv")], axis=-1)
+    z = jnp.einsum("bse,ehd->bshd", x, lp["wg"].astype(x.dtype))
+    a = jnp.einsum("bse,eh->bsh", x, lp["wa"].astype(x.dtype),
+                   preferred_element_type=jnp.float32)
+    b = jnp.einsum("bse,eh->bsh", x, lp["wb"].astype(x.dtype),
+                   preferred_element_type=jnp.float32)
+    g, beta = gate_and_beta(a, b, lp["a_log"], lp["dt_bias"])
+    o, kept = recur(u, g, beta)
+    y = rms_norm(o, lp["o_norm"], cfg.rms_norm_eps) * jax.nn.silu(
+        z.astype(jnp.float32))
+    return jnp.einsum("bshd,hde->bse", y.astype(x.dtype),
+                      lp["wo"].astype(x.dtype)), kept
+
+
+def _recur_sequence(lp, cfg: TransformerConfig):
+    """`_linear_mix`'s `recur` for a sequence that starts at its first
+    token and keeps nothing (the trainer's forward): zero tail, zero
+    state, the chunked form."""
+    from ..ops import gated_delta as gd
+
+    H, dk, dv = cfg.linear_n_heads, cfg.linear_d_k, cfg.linear_d_v
+
+    def recur(u, g, beta):
+        B = u.shape[0]
+        with jax.named_scope("gdn.conv"):
+            tail = jnp.zeros((B, cfg.linear_conv_kernel - 1, u.shape[-1]),
+                             u.dtype)
+            q, k, v = gd.split_heads(
+                gd.causal_conv(u, lp["conv_w"], tail), H, dk, dv)
+        with jax.named_scope("gdn.scan"):
+            o, _ = gd.chunked(q, k, v, g, beta,
+                              jnp.zeros((B, H, dk, dv), jnp.float32))
+        return o, None
+
+    return recur
+
+
 def _block(x, lp, cfg: TransformerConfig, cos, sin, attend, constrain_fn, *,
            positions=None, head_major: bool = False, routed=None,
-           dense: bool = False, layer=None):
+           dense: bool = False, layer=None, linear: bool = False):
     """One decoder layer, written once for every program: `_qkv` (or its
     latent sibling), the program's own attention, the output projection and
     its residual, then the post-norm MLP (dense or sparse experts) and its
-    residual. Both residuals are `_residual`: the plain add, or the
+    residual — or, `linear`, the gated-delta-rule sibling of the attention
+    half (`_linear_mix`, with `attend` the program's `recur`). Each
+    sublayer's RMSNorm sits on its input, or under
+    `cfg.norm_placement="post"` on its OUTPUT: x + norm(F(x)).
+    Both residuals are `_residual`: the plain add, or the
     hyper-connection's mix over the n streams x then carries
     ([B, S, n, C]). `dense` marks a layer of the leading dense stack of a
     model whose other layers have experts; `layer` is the layer's index in
@@ -1084,16 +1288,24 @@ def _block(x, lp, cfg: TransformerConfig, cos, sin, attend, constrain_fn, *,
     idx [B*S, k] as soon as they exist — before the residual add, where
     decode's expert-load count has always been traced — and its result is
     returned. Returns (x, kept, routed's result or None)."""
+    post = cfg.norm_placement == "post"
     u, mix = _hc_mix(x, lp, "attn", cfg) if cfg.hc_mult else (x, None)
-    if cfg.kv_lora_rank:
-        q, k, v = _qkv_latent(u, lp, cfg, cos, sin, positions)
+    if linear:
+        # `attend` is the program's `recur` here (`_linear_mix`)
+        y, kept = _linear_mix(u, lp, cfg, attend)
     else:
-        q, k, v = _qkv(u, lp, cfg, cos, sin, positions, head_major)
-    attn, kept = attend(q, k, v)
-    wo_eq = "bhsd,hde->bse" if head_major else "bshd,hde->bse"
-    x = _residual(x, jnp.einsum(wo_eq, attn, lp["wo"].astype(x.dtype)), mix)
+        if cfg.kv_lora_rank:
+            q, k, v = _qkv_latent(u, lp, cfg, cos, sin, positions)
+        else:
+            q, k, v = _qkv(u, lp, cfg, cos, sin, positions, head_major)
+        attn, kept = attend(q, k, v)
+        wo_eq = "bhsd,hde->bse" if head_major else "bshd,hde->bse"
+        y = jnp.einsum(wo_eq, attn, lp["wo"].astype(x.dtype))
+    if post:
+        y = rms_norm(y, lp["attn_norm"], cfg.rms_norm_eps)
+    x = _residual(x, y, mix)
     u, mix = _hc_mix(x, lp, "mlp", cfg) if cfg.hc_mult else (x, None)
-    h2 = rms_norm(u, lp["mlp_norm"], cfg.rms_norm_eps)
+    h2 = u if post else rms_norm(u, lp["mlp_norm"], cfg.rms_norm_eps)
     stat = None
     if cfg.n_experts and not dense:
         y, idx = _moe(h2, lp, cfg, constrain_fn, layer)
@@ -1101,6 +1313,8 @@ def _block(x, lp, cfg: TransformerConfig, cos, sin, attend, constrain_fn, *,
             stat = routed(idx)
     else:
         y = _mlp(h2, lp, cfg, constrain_fn)
+    if post:
+        y = rms_norm(y, lp["mlp_norm"], cfg.rms_norm_eps)
     axes = ("batch", "seq", None, "embed") if cfg.hc_mult else (
         "batch", "seq", "embed")
     return constrain_fn(_residual(x, y, mix), *axes), kept, stat
@@ -1113,19 +1327,57 @@ def _experts_in_place(cfg: TransformerConfig) -> bool:
                 and cfg.moe_capacity_factor is None)
 
 
+def _scan_period(layer_fn, carry, params, cfg: TransformerConfig,
+                 wrap=lambda f: f):
+    """The layers of a model with a layer PERIOD: ONE `lax.scan` over the
+    periods whose body runs the period's blocks in order,
+    `layer_fn(stack)(carry, (layer weights, index))` each. The weights are
+    stacked per KIND (`_KIND_STACK`) and the body indexes the stacks whole;
+    a layer's index counts the layers of ITS kind — each kind addresses
+    its own cache (a full layer the KV pool, a linear one the state pool).
+    `wrap` is applied to the scan body (the trainer's remat). -> carry."""
+    n_periods = cfg.n_layers // len(cfg.layer_period)
+    stacks = sorted({_KIND_STACK[k] for k in cfg.layer_period})
+    per = {st: sum(_KIND_STACK[k] == st for k in cfg.layer_period)
+           for st in stacks}
+    steps = {st: layer_fn(st) for st in stacks}
+
+    def period(carry, p):
+        seen = dict.fromkeys(stacks, 0)
+        for kind in cfg.layer_period:
+            st = _KIND_STACK[kind]
+            l = p * per[st] + seen[st]
+            seen[st] += 1
+            # ONE slice a layer, straight out of the kind's whole stack (as
+            # a scan slices its xs): a period's layers sliced out together
+            # first would be a copy of every weight, every step
+            lp = jax.tree.map(
+                lambda a: lax.dynamic_index_in_dim(a, l, keepdims=False),
+                params[st])
+            carry, _ = steps[st](carry, (lp, l))
+        return carry, None
+
+    carry, _ = lax.scan(
+        wrap(period), carry, jnp.arange(n_periods, dtype=jnp.int32))
+    return carry
+
+
 def _scan_stacks(layer_fn, carry, params, layer_ids, cfg: TransformerConfig):
-    """`lax.scan` of `layer_fn(dense)(carry, (layer weights, layer index))`
+    """`lax.scan` of `layer_fn(stack)(carry, (layer weights, layer index))`
     over the model's layer stacks in turn — the leading dense stack, where
     the model has one, then the main stack — with ONE running layer index
-    (`layer_ids`: stack name -> its layers' indices into the KV pool).
+    (`layer_ids`: stack name -> its layers' indices into the KV pool); a
+    model with a layer period goes through `_scan_period` instead.
     Dropless experts' three leaves are not sliced by the scan: every layer
     gets them whole, [L, X, ...], under their own keys (`_moe_dropless`
     says why). -> (carry, the main stack's ys)."""
+    if cfg.layer_period:
+        return _scan_period(layer_fn, carry, params, cfg), None
     ys = None
     for stack in _STACKS:
         if stack not in params:
             continue
-        step, layers = layer_fn(stack == "dense_layers"), params[stack]
+        step, layers = layer_fn(stack), params[stack]
         if stack == "layers" and _experts_in_place(cfg):
             whole = {k: layers[k] for k in _EXPERT_KEYS}
             layers = {k: a for k, a in layers.items() if k not in whole}
@@ -1243,6 +1495,19 @@ def make_forward(
 
         return layer_step
 
+    def period_step(stack):
+        # a layer of a period: (x, (weights, index)) as `_scan_period` calls
+        def layer_step(x, per_layer):
+            lp, _ = per_layer
+            linear = stack == "linear_layers"
+            x, _, _ = _block(
+                x, lp, cfg, cos, sin,
+                _recur_sequence(lp, cfg) if linear else attend_seq,
+                _constrain, head_major=head_major, linear=linear)
+            return x, None
+
+        return layer_step
+
     if cfg.remat:
         cp = jax.checkpoint_policies
         policies = {
@@ -1301,6 +1566,8 @@ def make_forward(
                 axis_name=stage_axes or "pp",
                 virtual_stages_per_device=cfg.pp_interleave,
             )
+        if cfg.layer_period:
+            return _scan_period(period_step, x, params, cfg, wrap=remat)
         if "dense_layers" in params:
             x, _ = lax.scan(dense_step, x, params["dense_layers"])
         if not cfg.scan_layers:
@@ -1420,6 +1687,45 @@ def refuse_on_latent_pool(cfg: TransformerConfig, *, kv_dtype=None, mesh=None,
             )
 
 
+# the leaves of a paged pool that are NOT chains of token blocks: a linear
+# layer's recurrent state and conv tail, one row a slot or snapshot
+STATE_LEAVES = ("state", "conv")
+
+
+def refuse_on_state_pool(cfg: TransformerConfig, *, kv_dtype=None, mesh=None,
+                         speculative_k: int = 0) -> None:
+    """What a hybrid cache (a recurrent-state pool beside the KV pool: a
+    model with linear layers) does not support, refused BY NAME where a
+    pool or its programs are built — `kv_dtype` int8, `mesh` (a sharded
+    state pool) and `speculative_k` (verify would have to roll a state back
+    past its rejected drafts). A no-op without linear layers."""
+    if not cfg.layers_of("linear"):
+        return
+    asked = {
+        "kv_dtype": kv_dtype if kv_dtype is not None
+        and jnp.dtype(kv_dtype) == jnp.int8 else None,
+        "mesh": mesh, "speculative_k": speculative_k or None,
+    }
+    for name, value in asked.items():
+        if value is not None:
+            raise NotImplementedError(
+                f"a hybrid cache (recurrent state beside KV) does not "
+                f"support {name}={value!r}: an int8 KV pool beside a state "
+                "pool, a sharded state pool and speculative verify over a "
+                "recurrent state are not written (ROADMAP, Reach A6)"
+            )
+
+
+def paged_state_row_bytes(cfg: TransformerConfig) -> int:
+    """HBM bytes ONE row of the state pool costs across the linear layers
+    (the recurrent state and the conv tail of one sequence, whatever its
+    length), read off the pool's own leaves; 0 without linear layers."""
+    pool = jax.eval_shape(
+        lambda: init_paged_kv_cache(cfg, 1, 1, state_rows=1))
+    return sum(math.prod(pool[n].shape) * pool[n].dtype.itemsize
+               for n in STATE_LEAVES if n in pool)
+
+
 def paged_kv_block_bytes(
     cfg: TransformerConfig, block_tokens: int, dtype=None
 ) -> int:
@@ -1441,6 +1747,7 @@ def init_paged_kv_cache(
     mesh=None,
     rules: Optional[ShardingRules] = None,
     dtype=None,
+    state_rows: int = 0,
 ):
     """Allocate the pooled (paged) per-layer KV cache: `num_blocks` physical
     blocks of `block_tokens` tokens each, shared by every decode slot via
@@ -1456,16 +1763,37 @@ def init_paged_kv_cache(
     Under latent attention the pool is ONE leaf, `kv`
     [L, N, block_tokens, 1, latent_row]: a token's normed c_kv and shared
     roped key in its first `latent_width` columns (`cfg.latent_row` says
-    why the row is wider), serving every head as key and as value."""
+    why the row is wider), serving every head as key and as value.
+
+    A model with a layer period keeps K and V for its FULL layers only
+    (the leaves' layer dim counts those), and with `state_rows` > 0 the
+    pool holds the linear layers' STATE POOL beside them (`STATE_LEAVES`):
+    `state` [L_lin, rows, dk, H*dv], always float32, one recurrent
+    state a row in the layout of ops/gated_delta.py — 96 x 5760 at the
+    published widths, no lane or sublane padding on the chip — and `conv`
+    [L_lin, rows, (K-1)*C] in the compute dtype, the conv tail flattened so
+    that its minor dim is whole lanes too. A row belongs to a decode slot
+    or to a snapshot (kv_paging.py). The K/V leaves hold
+    `cfg.kv_pool_heads` heads (30 -> 32)."""
     dtype = dtype or cfg.dtype
+    refuse_on_state_pool(cfg, kv_dtype=dtype, mesh=mesh)
     if cfg.kv_lora_rank:
         refuse_on_latent_pool(cfg, kv_dtype=dtype, mesh=mesh)
         return {"kv": jnp.zeros(
             (cfg.n_layers, num_blocks, block_tokens, 1, cfg.latent_row), dtype)}
-    shape = (cfg.n_layers, num_blocks, block_tokens, cfg.n_kv_heads, cfg.d_head)
+    kv_layers = cfg.layers_of("full")
+    shape = (kv_layers, num_blocks, block_tokens, cfg.kv_pool_heads, cfg.d_head)
     pool = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    if state_rows and cfg.layers_of("linear"):
+        n_lin = cfg.layers_of("linear")
+        pool["state"] = jnp.zeros(
+            (n_lin, state_rows, cfg.linear_d_k,
+             cfg.linear_n_heads * cfg.linear_d_v), jnp.float32)
+        pool["conv"] = jnp.zeros(
+            (n_lin, state_rows,
+             (cfg.linear_conv_kernel - 1) * cfg.linear_channels), cfg.dtype)
     if dtype == jnp.int8:
-        sshape = (cfg.n_layers, num_blocks, cfg.n_kv_heads)
+        sshape = (kv_layers, num_blocks, cfg.kv_pool_heads)
         pool["k_scale"] = jnp.zeros(sshape, jnp.float32)
         pool["v_scale"] = jnp.zeros(sshape, jnp.float32)
     if mesh is not None and rules is not None:
@@ -1596,7 +1924,9 @@ def make_paged_decoder(
     kv_dtype = kv_dtype or cfg.dtype
     quant = kv_dtype == jnp.int8
     latent = bool(cfg.kv_lora_rank)
+    hybrid = bool(cfg.layers_of("linear"))
     refuse_on_latent_pool(cfg, kv_dtype=kv_dtype, mesh=mesh)
+    refuse_on_state_pool(cfg, kv_dtype=kv_dtype, mesh=mesh)
     cos, sin = _rope_tables(cfg)
     scale = attention_scale(cfg)
     n_rep = cfg.n_heads // cfg.n_kv_heads
@@ -1605,6 +1935,19 @@ def make_paged_decoder(
         if rules is None or mesh is None:
             return x
         return constrain(x, rules, *axes, mesh=mesh)
+
+    def _pool_heads(q, k, v):
+        """q, k, v [B, S, heads, D] at the head counts of the pool's
+        leaves (`cfg.kv_pool_heads`; whole zero groups appended, so a query
+        head keeps its KV head) — themselves where the pool has the
+        model's own count."""
+        def pad(x, n):
+            if x.shape[2] == n:
+                return x
+            return jnp.pad(x, ((0, 0), (0, 0), (0, n - x.shape[2]), (0, 0)))
+
+        kvp = cfg.kv_pool_heads
+        return pad(q, kvp * n_rep), pad(k, kvp), pad(v, kvp)
 
     _sample = _make_sampler(temperature, cfg.vocab_pad)
 
@@ -1616,13 +1959,18 @@ def make_paged_decoder(
         copied back, whole-pool traffic for a few tokens' write."""
         if latent:  # the one latent leaf rides where K does
             return (pool["kv"], None, None, None)
+        if hybrid:  # the state pool's two leaves ride behind
+            return (pool["k"], pool["v"], None, None,
+                    pool["state"], pool["conv"])
         return (pool["k"], pool["v"], pool.get("k_scale"), pool.get("v_scale"))
 
-    def _pool_dict(kc, vc, ksc, vsc):
+    def _pool_dict(kc, vc, ksc, vsc, *state):
         if latent:
             return {"kv": kc}
         if quant:
             return {"k": kc, "v": vc, "k_scale": ksc, "v_scale": vsc}
+        if hybrid:
+            return {"k": kc, "v": vc, "state": state[0], "conv": state[1]}
         return {"k": kc, "v": vc}
 
     layer_ids = _layer_ids(cfg)
@@ -1848,7 +2196,8 @@ def make_paged_decoder(
             jnp.stack([l_w, l_f]), out_dtype=cfg.dtype,
         )
 
-    def _prefill_body(G, params, pool, table, tokens, length, ctx_len, key):
+    def _prefill_body(G, params, pool, table, tokens, length, ctx_len, key,
+                      row=None):
         params = _cast_matmul_params(cfg, params)
         Sb = tokens.shape[1]
         x = params["embed"].astype(cfg.dtype)[tokens]
@@ -1900,12 +2249,48 @@ def make_paged_decoder(
             kw = _dequant(q8, s).reshape(1, G * bt, *win.shape[2:])
             return kc.at[l, window].set(q8), ksc.at[l, window].set(s), kw
 
-        def layer_fn(carry, per_layer, dense=False):
+        def layer_fn(carry, per_layer, stack="layers"):
             x, *leaves = carry
             lp, l = per_layer
 
+            def recur(u, g, beta):
+                """A linear layer of the suffix, from the slot's row of the
+                state pool: the conv from the row's tail and the chunked
+                scan from its state (zeros when nothing is committed yet),
+                padded tokens passed through (g = 0, beta = 0 leaves a
+                state alone); the row takes the state and the tail after
+                `length` tokens."""
+                from ..ops import gated_delta as gd
+
+                state, conv = leaves[4:]
+                H, dk, dv = cfg.linear_n_heads, cfg.linear_d_k, cfg.linear_d_v
+                warm = (ctx_len > 0)
+                with jax.named_scope("gdn.conv"):
+                    tail = lax.dynamic_slice(
+                        conv, (l, row, 0), (1, 1, conv.shape[2]))
+                    tail = tail.reshape(1, -1, u.shape[-1]) * warm.astype(
+                        conv.dtype)
+                    q, k, v = gd.split_heads(
+                        gd.causal_conv(u, lp["conv_w"], tail), H, dk, dv)
+                    conv = lax.dynamic_update_slice(
+                        conv, gd.conv_tail(u, tail, length).reshape(1, 1, -1),
+                        (l, row, 0))
+                with jax.named_scope("gdn.scan"):
+                    live = valid_tok[None, :, None]
+                    s0 = lax.dynamic_slice(
+                        state, (l, row, 0, 0), (1, 1) + state.shape[2:])[0]
+                    s0 = gd.from_pool_layout(
+                        s0.astype(jnp.float32), H) * warm.astype(jnp.float32)
+                    o, s1 = gd.chunked(
+                        q, k, v, jnp.where(live, g, 0.0),
+                        jnp.where(live, beta, 0.0), s0)
+                    state = lax.dynamic_update_slice(
+                        state, gd.to_pool_layout(s1).astype(state.dtype)[None],
+                        (l, row, 0, 0))
+                return o, (*leaves[:4], state, conv)
+
             def attend(q, k, v):
-                kc, vc, ksc, vsc = leaves
+                kc, vc, ksc, vsc = leaves[:4]
                 q = _constrain(q, "batch", "seq", "heads", "head_dim")
                 if latent:
                     # k is the suffix's latent rows, v the layer's wkv_b
@@ -1918,6 +2303,7 @@ def make_paged_decoder(
                             jnp.asarray(ctx_len + length, jnp.int32), (1,)),
                         kmask, materialise=True)
                     return attn, (kc, None, None, None)
+                q, k, v = _pool_heads(q, k, v)
                 # write the suffix K/V first — suffix keys are then read
                 # back from the pool, so cache content is authoritative
                 # either way
@@ -1945,14 +2331,17 @@ def make_paged_decoder(
                         kw = _gather_window(kc, ksc, l, window[None])
                         vw = _gather_window(vc, vsc, l, window[None])
                     attn = _cached_attend(q, kw, vw, kmask, scale, n_rep)
-                return attn, (kc, vc, ksc, vsc)
+                return attn[:, :, :cfg.n_heads], (kc, vc, ksc, vsc, *leaves[4:])
 
-            x, leaves, _ = _block(x, lp, cfg, cos, sin, attend, _constrain,
-                                  positions=qpos[None], dense=dense, layer=l)
+            linear = stack == "linear_layers"
+            x, leaves, _ = _block(
+                x, lp, cfg, cos, sin, recur if linear else attend, _constrain,
+                positions=qpos[None], dense=stack == "dense_layers", layer=l,
+                linear=linear)
             return (x, *leaves), None
 
         (x, *leaves), _ = _scan_stacks(
-            lambda dense: partial(layer_fn, dense=dense),
+            lambda stack: partial(layer_fn, stack=stack),
             (x,) + _pool_leaves(pool), params, layer_ids, cfg)
         x = rms_norm(_hc_collapse(x, cfg), params["final_norm"],
                      cfg.rms_norm_eps)
@@ -1972,15 +2361,23 @@ def make_paged_decoder(
             return _prefill_body(
                 G, params, pool, table, tokens, length, ctx_len, key)
 
+        if hybrid:  # one more argument: the slot's row of the state pool
+            def paged_prefill(params, pool, table, tokens, length, ctx_len,
+                              key, row):
+                return _prefill_body(
+                    G, params, pool, table, tokens, length, ctx_len, key, row)
+
         return jax.jit(paged_prefill, donate_argnums=(1,))
 
     def prefill_dispatch(params, pool, table, tokens, length, ctx_len, key,
-                         ctx_blocks: int):
+                         ctx_blocks: int, row=None):
         Sb = tokens.shape[1]
         G = min(int(ctx_blocks) + -(-Sb // bt), table.shape[0])
         fn = _prefill_jits.get(G)
         if fn is None:
             fn = _prefill_jits[G] = _prefill_program(G)
+        if hybrid:
+            return fn(params, pool, table, tokens, length, ctx_len, key, row)
         return fn(params, pool, table, tokens, length, ctx_len, key)
 
     prefill_dispatch.programs = _prefill_jits  # window width G -> program
@@ -2011,19 +2408,54 @@ def make_paged_decoder(
             # slot writes to the null block, 0)
             return _expert_load(idx, write_phys > 0, cfg.n_experts)
 
-        def layer_fn(carry, per_layer, dense=False):
+        if hybrid:
+            # the rows of the state pool this step advances: the slots that
+            # write a real block, listed first (a slot that is not decoding
+            # — free, or mid-way through a chunked prefill — keeps its row)
+            active = write_phys > 0
+            live_rows = jnp.argsort(~active, stable=True).astype(jnp.int32)
+            n_live = jnp.sum(active, dtype=jnp.int32)
+
+        def layer_fn(carry, per_layer, stack="layers"):
             x, *leaves = carry
             lp, l = per_layer
 
+            def recur(u, g, beta):
+                """A linear layer's decode step: every slot's conv window
+                is its row's tail plus this token (the rows are the pool's
+                first B; only an active slot's tail is replaced), and the
+                state step runs over the live rows alone, in place."""
+                from ..ops import gated_delta as gd
+
+                state, conv = leaves[4:]
+                H, dk, dv = cfg.linear_n_heads, cfg.linear_d_k, cfg.linear_d_v
+                B = u.shape[0]
+                with jax.named_scope("gdn.conv"):
+                    tails = lax.dynamic_slice(
+                        conv, (l, 0, 0), (1, B, conv.shape[2]))
+                    tail = tails.reshape(B, -1, u.shape[-1])
+                    q, k, v = gd.split_heads(
+                        gd.causal_conv(u, lp["conv_w"], tail)[:, 0], H, dk, dv)
+                    new = jnp.concatenate([tail[:, 1:], u], axis=1)
+                    conv = lax.dynamic_update_slice(
+                        conv, jnp.where(active[:, None, None], new,
+                                        tail).reshape(tails.shape), (l, 0, 0))
+                with jax.named_scope("gdn.step"):
+                    o, state = gd.state_step(
+                        state, l, live_rows, n_live, q, k, v, g[:, 0],
+                        beta[:, 0])
+                return o[:, None], (*leaves[:4], state, conv)
+
             def attend(q, k, v):
                 # q [B,1,H,D]; k, v [B,1,KV,D]
-                kc, vc, ksc, vsc = leaves
+                kc, vc, ksc, vsc = leaves[:4]
                 if latent:
                     kc = kc.at[l, write_phys, write_off].set(
                         _latent_row(k[:, 0]).astype(kc.dtype))
                     attn = _latent_attend(
                         q, v, kc, l, tables, positions, positions + 1, kmask)
                     return attn, (kc, None, None, None)
+                q, k, v = _pool_heads(q, k, v)
                 if quant:
                     kc, ksc = _write_token_quant(kc, ksc, l, k[:, 0])
                     vc, vsc = _write_token_quant(vc, vsc, l, v[:, 0])
@@ -2044,15 +2476,17 @@ def make_paged_decoder(
                     kw = _gather_window(kc, ksc, l, tables)
                     vw = _gather_window(vc, vsc, l, tables)
                     attn = _cached_attend(q, kw, vw, kmask, scale, n_rep)
-                return attn, (kc, vc, ksc, vsc)
+                return attn[:, :, :cfg.n_heads], (kc, vc, ksc, vsc, *leaves[4:])
 
+            linear = stack == "linear_layers"
             x, leaves, stats = _block(
-                x, lp, cfg, cos, sin, attend, _constrain, positions=pos2,
-                routed=load, dense=dense, layer=l)
+                x, lp, cfg, cos, sin, recur if linear else attend, _constrain,
+                positions=pos2, routed=load, dense=stack == "dense_layers",
+                layer=l, linear=linear)
             return (x, *leaves), stats
 
         (x, *leaves), stats = _scan_stacks(
-            lambda dense: partial(layer_fn, dense=dense),
+            lambda stack: partial(layer_fn, stack=stack),
             (x,) + _pool_leaves(pool), params, layer_ids, cfg)
         x = rms_norm(_hc_collapse(x, cfg), params["final_norm"],
                      cfg.rms_norm_eps)
@@ -2099,6 +2533,7 @@ def make_paged_decoder(
     def paged_verify(params, pool, tables, tokens, positions, draft_len,
                      write_phys, write_off, key):
         refuse_on_latent_pool(cfg, speculative_k=True)
+        refuse_on_state_pool(cfg, speculative_k=True)
         if cfg.first_k_dense or cfg.hc_mult:
             raise NotImplementedError(
                 "speculative verify walks one layer stack with the plain "
@@ -2136,6 +2571,7 @@ def make_paged_decoder(
 
             def attend(q, k, v):
                 q = _constrain(q, "batch", "seq", "heads", "head_dim")
+                q, k, v = _pool_heads(q, k, v)
                 if attention_impl == "fused":
                     # multi-query fused walk over the cached window
                     # (kv_len = positions keeps the not-yet-written span
@@ -2154,14 +2590,14 @@ def make_paged_decoder(
                     kcat = jnp.concatenate([kw, k.astype(kw.dtype)], axis=1)
                     vcat = jnp.concatenate([vw, v.astype(vw.dtype)], axis=1)
                     attn = _cached_attend(q, kcat, vcat, mask, scale, n_rep)
-                return attn, (k, v)
+                return attn[:, :, :cfg.n_heads], (k, v)
 
             x, kv, _ = _block(x, lp, cfg, cos, sin, attend, _constrain,
                               positions=rope_pos, layer=l)
             return x, kv
 
         x, (ks, vs) = _scan_stacks(
-            lambda dense: layer_fn, x, params, layer_ids, cfg)
+            lambda stack: layer_fn, x, params, layer_ids, cfg)
         x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
         logits = jnp.einsum("bse,ev->bsv", x, _unembed_matrix(cfg, params))
         logits = _constrain(logits, "batch", "seq", "vocab")
@@ -2183,7 +2619,8 @@ def make_paged_decoder(
         # every pool leaf (K/V blocks AND their scales) has the physical
         # block dim at axis 1
         return {
-            name: a.at[:, dst].set(a[:, src]) for name, a in pool.items()
+            name: a if name in STATE_LEAVES else a.at[:, dst].set(a[:, src])
+            for name, a in pool.items()
         }
 
     return (
@@ -2192,6 +2629,21 @@ def make_paged_decoder(
         jax.jit(paged_verify, donate_argnums=(1,)),
         jax.jit(copy_blocks, donate_argnums=(0,)),
     )
+
+
+def make_copy_state():
+    """copy_state(pool, src[n], dst[n]) -> pool: the state pool's rows
+    `src` copied onto rows `dst` in every linear layer (snapshot -> slot at
+    admission, slot -> snapshot behind a prefill chunk or a finished
+    sequence, slot -> slot for a fork), the KV leaves untouched. The pool
+    is donated; the program's name is `jit_copy_state`."""
+    def copy_state(pool, src, dst):
+        return {
+            name: a.at[:, dst].set(a[:, src]) if name in STATE_LEAVES else a
+            for name, a in pool.items()
+        }
+
+    return jax.jit(copy_state, donate_argnums=(0,))
 
 
 def make_loss_fn(cfg: TransformerConfig, rules=None, mesh=None):

@@ -539,7 +539,11 @@ class ContinuousBatcher:
                           "spec_proposed_tokens", "spec_accepted_tokens",
                           "spec_emitted_tokens", "spec_accept_rate",
                           "spec_tokens_per_step",
-                          "weight_version", "weight_swaps"):
+                          "weight_version", "weight_swaps",
+                          # a hybrid cache's state pool (kv_paging.StatePool)
+                          "state_bytes_per_seq", "state_rows_total",
+                          "state_rows_free", "state_snapshots",
+                          "state_restores", "state_snapshot_evictions"):
                     if k in es:
                         out[k] = es[k]
         return out

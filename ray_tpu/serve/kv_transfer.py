@@ -216,6 +216,13 @@ class KVTransferManager:
         self.enabled = bool(
             cfg.serve_kv_transfer if enabled is None else enabled
         )
+        if getattr(self.engine, "hybrid", False):
+            # a hybrid cache (recurrent state beside KV) refuses
+            # export_prefix / import_prefix by name: blocks without the
+            # state snapshot behind them cannot be resumed from. Its
+            # transfer signature carries the state's geometry all the
+            # same, so a peer that asks is answered with a miss
+            self.enabled = False
         self.deployment = deployment
         self.min_blocks = max(1, int(cfg.serve_kv_transfer_min_blocks))
         self._tel = _tel_resolve(telemetry)

@@ -42,3 +42,11 @@ for _f in sorted(os.listdir(_DIR)):
 # test_cells_of_this_pr_are_declared_and_only_appended holds what still
 # has to hold.
 del test_every_new_metric_is_declared_with_its_cells  # noqa: F821
+
+# pins the Xing4.0 configuration and cell to the LAST place of BENCHMARK.json's
+# lists (`configs[-1]`, `workloads[-1]`, the cell LAST in every metric's list):
+# true until a later PR appends a configuration and a cell, which the
+# benchmark's contract allows and PR 35 does (the file is the benchmark's own).
+# Every other assertion of it is held, with Xing4.0 found by name, by
+# test_the_xing4_cell_is_declared_as_pr33_left_it (test_olmo_hybrid_block.py).
+del test_the_cell_is_declared_and_only_appended  # noqa: F821

@@ -18,6 +18,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -571,3 +572,103 @@ def test_held_tree_is_cast_in_no_paged_program(one_chip, monkeypatch, block,
         2 * params["layers"][k].size for k in _MATMUL_KEYS
         if k in params["layers"])
     assert temps["f32"] - temps["held"] >= 0.999 * stacked, (temps, stacked)
+
+
+# ---- the hybrid geometry: a state pool beside the KV pool ------------------
+
+
+def _olmo_hybrid_block(n_layers=4):
+    """Olmo-Hybrid-7B's layers as benchmark/blocks/olmo_hybrid.py maps them:
+    one period of three linear layers and a full one."""
+    from ray_tpu.models.transformer import TransformerConfig
+
+    return TransformerConfig(
+        vocab_size=100352, d_model=3840, n_layers=n_layers, n_heads=30,
+        n_kv_heads=30, d_head=128, d_ff=11008, max_seq_len=35328,
+        qk_norm=True, use_rope=False, norm_placement="post",
+        layer_period=("linear", "linear", "linear", "full"),
+        linear_n_heads=30, linear_d_k=96, linear_d_v=192,
+    )
+
+
+HYBRID_ROWS = 160  # the cell's: 32 slots + 128 snapshots
+
+
+def _compile_hybrid(one_chip, monkeypatch, program, num_blocks=1036):
+    from ray_tpu.models.transformer import (
+        init_paged_kv_cache, init_params, make_paged_decoder, serving_params,
+    )
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = _olmo_hybrid_block()
+    prefill, decode, _, _ = make_paged_decoder(
+        cfg, block_tokens=BLOCK_TOKENS, attention_impl="fused")
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = on_chip(jax.eval_shape(lambda: serving_params(
+        cfg, init_params(jax.random.PRNGKey(0), cfg))))
+    pool = on_chip(jax.eval_shape(lambda: init_paged_kv_cache(
+        cfg, num_blocks, BLOCK_TOKENS, state_rows=HYBRID_ROWS)))
+    key = on_chip(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    B, T = PAGED_SLOTS, PAGED_TABLE
+    if program == "decode":
+        lowered = decode.lower(
+            params, pool, i32(B, T), i32(B), i32(B), i32(B), i32(B), key)
+    else:  # a 512-token turn behind 2,048 cached tokens
+        lowered = jax.jit(
+            functools.partial(prefill, ctx_blocks=32), donate_argnums=(1,)
+        ).lower(params, pool, i32(T), i32(1, 512), i32(), i32(), key,
+                row=i32())
+    compiled = lowered.compile()
+    assert "tpu_custom_call" in compiled.as_text()  # the paged kernel
+    return cfg, pool, compiled
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_hybrid_programs_copy_no_pool_and_no_state_leaf(one_chip, monkeypatch,
+                                                        program):
+    """Both pools go through the period scan in place. The KV leaves hold 32
+    heads for the model's 30 (`kv_pool_heads`): with 30 the device layout
+    puts the heads major over a block's tokens and every program relays the
+    whole pool in and out (two pool-sized copies a step, found here). The
+    state leaf [L_lin, rows, 96, 5760] float32 and the conv leaf
+    [L_lin, rows, 34560] tile (8, 128) with no padding; the only
+    instructions that yield a state leaf's shape are the in-place updates of
+    one row (decode: one a live slot and layer, inside the loop over the
+    live rows; prefill: one a layer)."""
+    cfg, pool, compiled = _compile_hybrid(one_chip, monkeypatch, program)
+    text = compiled.as_text()
+    kv = ",".join(map(str, pool["k"].shape))
+    assert kv.endswith("64,32,128") and cfg.n_kv_heads == 30
+    state = ",".join(map(str, pool["state"].shape))
+    conv = ",".join(map(str, pool["conv"].shape))
+    assert (state, conv) == (f"3,{HYBRID_ROWS},96,5760", f"3,{HYBRID_ROWS},34560")
+    found = _HLO_INSTRUCTION.findall(text)
+    for _, name, dtype, dims, op, _ in found:
+        if dims in (kv, state, conv):  # copy, copy-start (a prefetch), ...
+            assert not op.startswith("copy"), (name, op, dims)
+            assert not (op == "fusion" and "copy" in name), (name, dims)
+    # the leaves' device layout is row-major and tiled without padding
+    assert f"f32[{state}]{{3,2,1,0:T(8,128)}}" in text
+    assert f"bf16[{conv}]{{2,1,0:T(8,128)(2,1)}}" in text
+    assert f"bf16[{kv}]{{4,3,2,1,0:T(8,128)(2,1)}}" in text
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(
+        int(np.prod(a.shape)) * a.dtype.itemsize for a in jax.tree.leaves(pool))
+    assert mem.alias_size_in_bytes >= pool_bytes  # donated, updated in place
+    # nothing a pool's size among the temporaries (a layer's weights are
+    # read in place too: a period's layers sliced out together first were
+    # 1.2 GB of copies a step)
+    assert mem.temp_size_in_bytes < 600e6, mem.temp_size_in_bytes
+    # a state row is read and written one at a time
+    row = "1,1,96,5760"
+    assert any(dims == row and op.startswith("dynamic-slice") or
+               (op == "fusion" and "dynamic-slice" in name and dims == row)
+               for _, name, _, dims, op, _ in found) or f"[{row}]" in text

@@ -534,3 +534,96 @@ def test_latent_decode_span_and_stats_keep_their_attributes(tmp_path):
     assert stats["kv_bytes_per_token"] == cfg.n_layers * 128 * 2
     assert stats["kv_pool_bytes"] == eng.pool["kv"].nbytes
     assert stats["attention_kernel"] == "gather"  # "pallas" only on a TPU
+
+
+# ---- a hybrid cache: linear (gated-delta-rule) layers beside full ones -----
+
+
+def _hybrid_engine(tel=False, **kw):
+    from ray_tpu.models.transformer import TransformerConfig
+
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=32, n_layers=4, n_heads=2, n_kv_heads=2,
+        d_head=16, d_ff=64, max_seq_len=128, qk_norm=True, use_rope=False,
+        norm_placement="post", layer_period=("linear",) * 3 + ("full",),
+        linear_n_heads=2, linear_d_k=8, linear_d_v=16, dtype=jnp.float32)
+    return cfg, PagedDecodeEngine(
+        cfg, max_batch_size=2, seed=0, block_tokens=8, telemetry=tel,
+        prefill_buckets=(16,), n_snapshots=3, **kw)
+
+
+@pytest.mark.parametrize("which", ["paged_prefill", "paged_decode"])
+def test_linear_layers_lower_under_their_scopes(which):
+    """`gdn.conv` in both programs, `gdn.scan` (the chunked form) in
+    prefill, `gdn.step` (the in-place state step) in decode: what the
+    readers gdn_scan_ms and gdn_step_ms find the linear layers' device time
+    by. No Pallas kernel is written yet; the names `gdn_chunk_scan` /
+    `gdn_state_step` are the readers' for when one is. The programs keep
+    their names, and a model without linear layers has none of the scopes."""
+    _, eng = _hybrid_engine()
+    if which == "paged_prefill":
+        eng.admit(0, {"tokens": np.arange(1, 10), "max_new_tokens": 2})
+    fn, args = _program_args(eng, which)
+    if which == "paged_prefill":
+        args += (np.int32(0),)  # the slot's row of the state pool
+    text = fn.lower(*args).as_text(debug_info=True)
+    assert f"module @jit_{which} " in text
+    own, other = (("gdn.scan/", "gdn.step/") if which == "paged_prefill"
+                  else ("gdn.step/", "gdn.scan/"))
+    assert "gdn.conv/" in text and own in text and other not in text
+    _, plain = _tiny_engine(False, prefix_cache=False, prefill_buckets=(16,))
+    if which == "paged_prefill":
+        plain.admit(0, {"tokens": np.arange(1, 10), "max_new_tokens": 2})
+    fn, args = _program_args(plain, which)
+    assert "gdn." not in fn.lower(*args).as_text(debug_info=True)
+    readers = {}
+    for name in ("gdn_step_ms", "gdn_scan_ms"):
+        from benchmark import common
+
+        mod = common._load_module("layer_metrics", name)
+        readers[name] = (mod.SCOPES, mod.KERNELS)
+    assert readers == {"gdn_step_ms": (("gdn.step",), ("gdn_state_step",)),
+                       "gdn_scan_ms": (("gdn.scan", "gdn.conv"),
+                                       ("gdn_chunk_scan",))}
+
+
+def test_copy_state_lowers_under_its_name():
+    _, eng = _hybrid_engine()
+    one = np.ones(1, np.int32)
+    assert "module @jit_copy_state " in eng._copy_state.lower(
+        eng.pool, one, one).as_text()[:200]
+
+
+def test_state_restore_and_snapshot_spans(tmp_path):
+    """`engine.state_snapshot` (slot -> snapshot: behind a prefill chunk
+    that ends on a block boundary, and when decode crosses one) and
+    `engine.state_restore` (snapshot -> slot, at admission) with `slot` and
+    `tokens`; `engine.decode` keeps `slots` and `kv_tokens`; the counters of
+    `engine.stats()`."""
+    cfg, eng = _hybrid_engine()
+    hist = np.arange(1, 17)  # two whole blocks
+    eng.admit(0, {"tokens": hist, "max_new_tokens": 2})
+    eng.step([0])
+    eng.release(0)
+    with _Trace(tmp_path) as tr:
+        eng.admit(1, {"tokens": np.concatenate([hist, [40, 41, 42]]),
+                      "max_new_tokens": 8})
+        for _ in range(6):  # 19 -> 25: crosses the boundary at 24
+            eng.step([1])
+    (_, _, st), = tr.spans("engine.state_restore")
+    assert (st["slot"], st["tokens"]) == (1, 16)
+    (_, _, st), = tr.spans("engine.state_snapshot")
+    assert st["tokens"] == 24
+    decodes = [st for _, _, st in tr.spans("engine.decode")]
+    assert [d["kv_tokens"] for d in decodes] == [20, 21, 22, 23, 24, 25]
+    assert all(d["slots"] == 1 for d in decodes)
+    stats = eng.stats()
+    assert stats["state_restores"] == 1
+    assert stats["state_snapshots"] == 2  # the history's end, the tail at 24
+    assert stats["prefix_tokens_reused"] == 16
+    assert stats["state_snapshot_evictions"] == 0
+    assert stats["state_rows_total"] == 2 + 3 and stats["state_rows_free"] == 1
+    assert stats["state_bytes_per_seq"] == sum(
+        eng.pool[n][:, 0].nbytes for n in ("state", "conv"))
+    assert stats["kv_bytes_per_token"] == 1 * 2 * 2 * 16 * 4  # ONE full layer
+    assert stats["kv_pool_bytes"] == eng.pool["k"].nbytes + eng.pool["v"].nbytes
