@@ -7,6 +7,8 @@ from .transformer import (  # noqa: F401
     init_paged_kv_cache,
     param_specs,
     make_paged_decoder,
+    pack_decode_inputs,
+    pack_prefill_inputs,
     make_forward,
     make_loss_fn,
     CONFIGS,

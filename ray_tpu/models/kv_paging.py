@@ -56,6 +56,15 @@ is enabled. Greedy output is token-for-token identical to non-speculative
 decode by construction (acceptance compares drafts against the model's own
 argmax); speculation is greedy-only.
 
+Host traffic: a dispatch of a model program (an admission's prefill chunk,
+a decode step) costs the host ONE upload — everything the program needs
+from the host, packed into one int32 array (transformer.py
+`pack_prefill_inputs` / `pack_decode_inputs`) — ONE launch and ONE fetch of
+one int32 vector (tokens, with experts their load counts, in logprob mode
+the log-probabilities). The RNG key is split on the host only at
+temperature > 0; greedy engines pass one device array made at construction.
+stats()["host_transfers"] and ["rng_dispatches"] count all of it.
+
 Not thread-safe: one loop thread (the batcher's) owns admit/step/release;
 stats() reads are safe from other threads (plain int reads).
 """
@@ -67,21 +76,24 @@ import logging
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from ..util.profiling import span
 from .transformer import (
-    NEG_INF,
     TransformerConfig,
     init_paged_kv_cache,
     init_params,
     make_copy_state,
     make_paged_decoder,
+    pack_decode_inputs,
+    pack_prefill_inputs,
     paged_kv_block_bytes,
     paged_state_row_bytes,
     refuse_on_latent_pool,
     refuse_on_state_pool,
     serving_params,
+    split_host_row,
 )
 
 logger = logging.getLogger(__name__)
@@ -628,9 +640,10 @@ class PagedDecodeEngine:
         # token becomes a (token, logprob) pair — the logprob of the
         # SAMPLED id under the exact distribution the sampler drew from
         # (same vocab_pad masking, same temperature scaling, fp32), so a
-        # dense re-forward reproduces it bit-for-tolerance. Restricted to
-        # speculative_k == 0: the verify step commits accepted drafts
-        # without returning per-position logits.
+        # dense re-forward reproduces it bit-for-tolerance. The programs
+        # compute it themselves and hand it back beside the token (one
+        # fetch). Restricted to speculative_k == 0: the verify step commits
+        # accepted drafts without returning per-position logits.
         self.logprobs = bool(logprobs)
         if self.logprobs and self.speculative_k:
             raise ValueError(
@@ -638,24 +651,6 @@ class PagedDecodeEngine:
                 "step returns no per-position logits to score"
             )
         self.temperature = float(temperature)
-        if self.logprobs:
-            vocab_pad = int(getattr(cfg, "vocab_pad", 0) or 0)
-            temp = self.temperature
-
-            def _lp(logits, toks):
-                logits = logits.astype(jnp.float32)
-                if vocab_pad:
-                    V = logits.shape[-1]
-                    pad = jnp.arange(V) >= V - vocab_pad
-                    logits = jnp.where(pad, NEG_INF, logits)
-                if temp > 0.0:
-                    logits = logits / temp
-                lp = jax.nn.log_softmax(logits, axis=-1)
-                return jnp.take_along_axis(
-                    lp, toks[:, None].astype(jnp.int32), axis=-1
-                )[:, 0]
-
-            self._lp_fn = jax.jit(_lp)
 
         if num_blocks is not None and pool_bytes is not None:
             raise ValueError(
@@ -739,7 +734,7 @@ class PagedDecodeEngine:
             make_paged_decoder(
                 cfg, rules=rules, mesh=mesh, temperature=temperature,
                 block_tokens=bt, kv_dtype=kv_dtype,
-                attention_impl=attention_impl,
+                attention_impl=attention_impl, logprobs=self.logprobs,
             )
         )
         buckets = sorted(set(
@@ -755,6 +750,14 @@ class PagedDecodeEngine:
             buckets.append(b)
         self.buckets = tuple(buckets)
         self._key = jax.random.PRNGKey(seed + 1)
+        # the key of every dispatch whose sample is greedy or thrown away:
+        # one device array, made once (`_sample_key`) — and replicated once
+        # over the mesh of a sharded pool, not by every launch
+        self._fixed_key = jax.random.PRNGKey(0)
+        if mesh is not None and rules is not None:
+            self._fixed_key = jax.device_put(
+                self._fixed_key,
+                jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec()))
 
         B = self.max_batch_size
         self._tables = np.zeros((B, self.blocks_per_slot), np.int32)
@@ -790,6 +793,14 @@ class PagedDecodeEngine:
         self.prefill_chunks = 0     # paged-prefill dispatches (>= prefills)
         self.chunked_prefills = 0   # admissions that streamed in chunks
         self.decode_steps = 0
+        # what the model programs cost the host: RNG splits dispatched (none
+        # at temperature 0), the dispatches of a model program (prefill,
+        # decode, verify), the NumPy arrays handed to them (each one is a
+        # host -> device copy the launch makes) and the answers read back
+        self.rng_dispatches = 0
+        self.model_dispatches = 0
+        self.uploads = 0
+        self.fetches = 0
         # sparse-expert models: see the decode step
         self.moe_pairs = 0
         self.moe_hottest = 0
@@ -850,11 +861,23 @@ class PagedDecodeEngine:
             sig.update(f"|wv={self.weight_version}".encode())
         return sig.digest()
 
-    def _next_key(self):
-        import jax
-
+    def _sample_key(self, sampled: bool = True):
+        """The key of one model-program dispatch. At temperature > 0 a
+        dispatch whose sample is kept takes the next key of the engine's
+        stream (ONE split per completing admission and per decode step);
+        a greedy sampler never reads its key, and a throwaway sample must
+        not consume the stream: both get the one fixed key."""
+        if not (sampled and self.temperature > 0.0):
+            return self._fixed_key
+        self.rng_dispatches += 1
         self._key, sub = jax.random.split(self._key)
         return sub
+
+    def _fetch(self, out) -> np.ndarray:
+        """One device -> host copy of a model program's answer (it waits
+        for the program)."""
+        self.fetches += 1
+        return np.asarray(out)
 
     def _bucket(self, length: int) -> int:
         for b in self.buckets:
@@ -949,6 +972,13 @@ class PagedDecodeEngine:
                 self.allocator.decref(b)
         self._tables[slot, :] = 0
         self._row_blocks[slot] = 0
+        # a released row is a never-used row again: the decode program
+        # runs every row, and a row left with its last token and length
+        # makes a step's cost (the experts its stale token routes to, the
+        # null blocks its stale length walks) depend on what finished
+        # streams held
+        self._last_tokens[slot] = 0
+        self._positions[slot] = 0
         self._live[slot] = False
         self._history[slot] = None
         self._chunk_state[slot] = None
@@ -1205,8 +1235,6 @@ class PagedDecodeEngine:
 
     def _prefill_chunk(self, slot: int, prompt, ctx: int, take: int,
                        last: bool) -> Optional[int]:
-        import jax
-
         bt = self.block_tokens
         length = int(prompt.size)
         bucket = self._bucket(take)
@@ -1219,12 +1247,15 @@ class PagedDecodeEngine:
         # stream: one key per admission regardless of chunking, which is
         # what keeps temperature > 0 tokens invariant to the chunk config
         # (greedy never reads the key at all)
-        key = self._next_key() if last else jax.random.PRNGKey(0)
-        next_tok, logits, self.pool = self._prefill(
-            self.params, self.pool, self._tables[slot],
-            padded[None], np.int32(take), np.int32(ctx),
-            key, ctx_blocks, **({"row": np.int32(slot)} if self.hybrid else {}),
-        )
+        key = self._sample_key(sampled=last)
+        # one NumPy array: the launch's one upload (the slot's row of the
+        # state pool is its own index)
+        inputs = pack_prefill_inputs(
+            self._tables[slot], padded, take, ctx, row=slot)
+        self.model_dispatches += 1
+        self.uploads += 1
+        out, _logits, self.pool = self._prefill(
+            self.params, self.pool, inputs, key, ctx_blocks, bucket)
         self._positions[slot] = ctx + take
         self.prefill_tokens += take
         self.prefill_chunks += 1
@@ -1235,11 +1266,12 @@ class PagedDecodeEngine:
             self._snapshot(slot, prompt, ctx + take)
         if not last:
             return None
-        tok = int(next_tok[0])
-        if self.logprobs:
-            self._lp_pending[slot] = float(
-                np.asarray(self._lp_fn(logits, next_tok))[0]
-            )
+        # one fetch: the first token and, in logprob mode, its logprob
+        toks, _, lps = split_host_row(
+            self._fetch(out), 1, logprobs=self.logprobs)
+        tok = int(toks[0])
+        if lps is not None:
+            self._lp_pending[slot] = float(lps[0])
         self._chunk_state[slot] = None
         self._last_tokens[slot] = tok
         self._new_counts[slot] = 1
@@ -1451,30 +1483,39 @@ class PagedDecodeEngine:
                 write_phys[s] = self._tables[s, pos // bt]
                 write_off[s] = pos % bt
                 kv_tokens += pos + 1  # the step attends to 0..pos
-            key = self._next_key()
+            # everything the program needs from the host, one array
+            inputs = pack_decode_inputs(
+                self._tables, self._last_tokens, self._positions,
+                write_phys, write_off)
+            key = self._sample_key()
         # slots: the recorder keeps the ids (one timeline lane each), the
         # trace their number; kv_tokens is what the paged kernel must read
         step_span.set(slots=tuple(surviving), kv_tokens=kv_tokens)
+        uploads, fetches = self.uploads, self.fetches
         with span("engine.dispatch"):
-            next_toks, logits, self.pool, moe_load = self._decode_step(
-                self.params, self.pool, self._tables, self._last_tokens,
-                self._positions, write_phys, write_off, key,
-            )
+            # one launch, and in it one upload: `inputs` is the only
+            # argument that is not on the device already
+            self.model_dispatches += 1
+            self.uploads += 1
+            out, _logits, self.pool = self._decode_step(
+                self.params, self.pool, inputs, key)
         with span("engine.fetch"):
-            toks = np.asarray(next_toks)
-            lps = (
-                np.asarray(self._lp_fn(logits, next_toks))
-                if self.logprobs else None
-            )
+            # one fetch (the wait for the device is in it): the tokens,
+            # with experts their two counts, in logprob mode the logprobs
+            toks, moe_load, lps = split_host_row(
+                self._fetch(out), self.max_batch_size,
+                experts=bool(self.cfg.n_experts), logprobs=self.logprobs)
+            step_span.set(uploads=self.uploads - uploads,
+                          fetches=self.fetches - fetches)
             if moe_load is not None:
                 # a sparse-expert model: the step's routed (token, expert)
                 # pairs, the load of its fullest expert and the experts
                 # with any pair (the groups the grouped matmul reads), each
                 # summed over the layers (pairs * n_experts / hottest = 1:
-                # even); the two counts ride one array, one fetch
+                # even)
                 pairs = (len(surviving) * self.cfg.top_k
                          * self.cfg.n_expert_layers)
-                hottest, touched = map(int, np.asarray(moe_load))
+                hottest, touched = map(int, moe_load)
                 step_span.set(moe_pairs=pairs, moe_hottest=hottest,
                               moe_touched=touched)
                 self.moe_pairs += pairs
@@ -1521,14 +1562,20 @@ class PagedDecodeEngine:
             if K1 in self.spec_shapes:
                 continue
             zeros = np.zeros((B, K1), np.int32)
-            _, _, self.pool = self._verify_step(
-                self.params, self.pool, self._tables, zeros,
-                np.zeros(B, np.int32), np.zeros(B, np.int32),
-                zeros, zeros, self._next_key(),
-            )
+            _, _, self.pool = self._verify(
+                self._tables, zeros, np.zeros(B, np.int32),
+                np.zeros(B, np.int32), zeros, zeros)
             self.spec_shapes.add(K1)
             warmed += 1
         return warmed
+
+    def _verify(self, *host_inputs):
+        """One dispatch of the verify program (no cell runs it: its six
+        inputs still go up one by one, and are counted as that)."""
+        self.model_dispatches += 1
+        self.uploads += len(host_inputs)
+        return self._verify_step(
+            self.params, self.pool, *host_inputs, self._sample_key())
 
     def _propose(self, surviving: List[int]) -> Dict[int, List[int]]:
         """Ask the drafter for up to k tokens per slot, capped so the
@@ -1626,12 +1673,11 @@ class PagedDecodeEngine:
             for i in range(len(d) + 1):
                 write_phys[s, i] = self._tables[s, (p + i) // bt]
                 write_off[s, i] = (p + i) % bt
-        out, accepted, self.pool = self._verify_step(
-            self.params, self.pool, self._tables, tokens, self._positions,
-            draft_len, write_phys, write_off, self._next_key(),
-        )
-        out = np.asarray(out)
-        accepted = np.asarray(accepted)
+        out, accepted, self.pool = self._verify(
+            self._tables, tokens, self._positions, draft_len, write_phys,
+            write_off)
+        out = self._fetch(out)
+        accepted = self._fetch(accepted)
 
         results: Dict[int, Tuple[List[int], bool]] = {}
         for s in surviving:
@@ -1958,6 +2004,16 @@ class PagedDecodeEngine:
                 1 for st in self._chunk_state if st is not None
             ),
             "decode_steps": self.decode_steps,
+            # RNG splits dispatched (0 at temperature 0) and what the model
+            # programs' dispatches cost in host<->device copies: a decode
+            # step and a completing admission are 1 upload + 1 fetch (a
+            # prefill chunk that is not the last fetches nothing)
+            "rng_dispatches": self.rng_dispatches,
+            "host_transfers": {
+                "dispatches": self.model_dispatches,
+                "uploads": self.uploads,
+                "fetches": self.fetches,
+            },
             # decode steps of a sparse-expert model: routed (token, expert)
             # pairs, the summed load of each step's fullest expert, and
             # the (layer, expert) groups with a pair: what the steps read

@@ -1636,6 +1636,64 @@ def _make_sampler(temperature: float, vocab_pad: int = 0):
     return _sample
 
 
+def _token_logprobs(logits, toks, temperature: float, vocab_pad: int = 0):
+    """log p(tok) under the distribution `_make_sampler` draws from (same
+    vocab_pad masking, same temperature scaling, float32): what a
+    `logprobs` engine emits beside each token. logits [N, V], toks [N]."""
+    logits = logits.astype(jnp.float32)
+    if vocab_pad:
+        V = logits.shape[-1]
+        logits = jnp.where(jnp.arange(V) >= V - vocab_pad, NEG_INF, logits)
+    if temperature > 0.0:
+        logits = logits / temperature
+    lp = jax.nn.log_softmax(logits, axis=-1)
+    return jnp.take_along_axis(
+        lp, toks[:, None].astype(jnp.int32), axis=-1)[:, 0]
+
+
+# The host's side of the paged programs (kv_paging.PagedDecodeEngine): what
+# a dispatch needs from the host goes up as ONE int32 array, built here with
+# NumPy and sliced inside the program, and what the host needs back comes
+# down as ONE int32 vector. A transfer of a few hundred bytes costs the host
+# what a launch does, so their NUMBER is the cost, not their size.
+
+DECODE_TAIL = 4  # tokens, positions, write_phys, write_off
+PREFILL_HEAD = 3  # length, ctx_len, row
+
+
+def pack_decode_inputs(tables, tokens, positions, write_phys, write_off):
+    """`paged_decode`'s one input, int32 [B, Nmax + 4]: each slot's block
+    table, then its pending token, its position and the (physical block,
+    offset) its new K/V goes to."""
+    tables = np.asarray(tables)
+    out = np.empty((tables.shape[0], tables.shape[1] + DECODE_TAIL), np.int32)
+    out[:, :-DECODE_TAIL] = tables
+    for i, col in enumerate((tokens, positions, write_phys, write_off)):
+        out[:, i - DECODE_TAIL] = col
+    return out
+
+
+def pack_prefill_inputs(table, tokens, length, ctx_len, row=0):
+    """`paged_prefill`'s one input, int32 [3 + Sb + Nmax]: (length,
+    ctx_len, row), the suffix padded to its bucket, the slot's block table.
+    `row` is the slot's row of a hybrid cache's state pool (read by no
+    other program)."""
+    return np.concatenate([
+        np.asarray([length, ctx_len, row], np.int32),
+        np.asarray(tokens, np.int32).reshape(-1),
+        np.asarray(table, np.int32)])
+
+
+def split_host_row(out, n: int, experts: bool = False, logprobs: bool = False):
+    """The vector a paged program hands back, on the host (NumPy) ->
+    (tokens [n], moe_load [2] or None, logprobs float32 [n] or None). The
+    programs lay it out as tokens | expert counts (decode, with experts) |
+    the tokens' log-probabilities bit-cast to int32 (`logprobs`)."""
+    at = n + (2 if experts else 0)
+    return (out[:n], out[n:at] if experts else None,
+            out[at:at + n].view(np.float32) if logprobs else None)
+
+
 def _unembed_matrix(cfg: TransformerConfig, params):
     u = params.get("unembed")
     if u is None:
@@ -1816,6 +1874,7 @@ def make_paged_decoder(
     block_tokens: int = 64,
     kv_dtype=None,
     attention_impl: str = "gather",
+    logprobs: bool = False,
 ):
     """Build the paged fast path: (paged_prefill, paged_decode_step,
     paged_verify_step, copy_blocks) over a block pool from
@@ -1831,8 +1890,19 @@ def make_paged_decoder(
     program scales with the pool (tests/test_chip_compile.py holds the
     programs to that).
 
-    paged_prefill(params, pool, table[Nmax], tokens[1,Sb], length, ctx_len,
-                  key, ctx_blocks) -> (next_token[1], logits[1,V], pool)
+    The two programs a serving step runs take what the host knows as ONE
+    int32 array (`pack_prefill_inputs`, `pack_decode_inputs`: built with
+    NumPy, sliced in the program) and hand back what the host reads as ONE
+    int32 vector (`split_host_row`), so a dispatch costs the host one
+    upload, one launch and one fetch. `key` is read at temperature > 0
+    only; greedy programs take any key (the engine passes one device array
+    it made once).
+
+    paged_prefill(params, pool, inputs[3 + Sb + Nmax], key, ctx_blocks, Sb)
+        -> (out[1 (+1)], logits[1,V], pool)
+      `inputs` packs (length, ctx_len, row), the suffix tokens[Sb] and the
+      slot's table[Nmax]; `out` is the next token, followed with
+      `logprobs` by its log-probability (float32 bits in an int32).
       B=1 prefill of a prompt SUFFIX whose first `ctx_len` tokens are
       already in the pool — a prefix-cache hit (block multiple), a prior
       prefill CHUNK of the same prompt (any offset; kv_paging's chunked
@@ -1840,18 +1910,21 @@ def make_paged_decoder(
       Suffix K/V is scattered into the slot's table blocks — a chunk
       boundary may land mid-block; the straddled block is slot-owned —
       and attention runs over the block window, so the committed span is
-      never recomputed. `ctx_blocks` is STATIC (bucketed by the caller —
-      kv_paging pads block counts to the same bucket boundaries as prompt
-      lengths) and keys the compile cache together with the suffix bucket.
+      never recomputed. `ctx_blocks` and `Sb` are STATIC (bucketed by the
+      caller — kv_paging pads block counts to the same bucket boundaries as
+      prompt lengths) and key the compiled programs. `row` is the slot's
+      row of a hybrid cache's state pool, read by no other model.
 
-    paged_decode_step(params, pool, tables[B,Nmax], tokens[B],
-                      positions[B], write_phys[B], write_off[B], key)
-        -> (next_tokens[B], logits[B,V], pool, moe_load)
+    paged_decode_step(params, pool, inputs[B, Nmax + 4], key)
+        -> (out[B (+2) (+B)], logits[B,V], pool)
+      `inputs` packs tables[B,Nmax] and the columns tokens, positions,
+      write_phys, write_off; `out` is next_tokens[B], then with experts
+      moe_load[2], then with `logprobs` the tokens' log-probabilities.
       One cached decode step for every slot: the new K/V is written at the
       host-resolved (physical block, offset) pair — inactive slots route to
       the null block — and attention reads each slot's logical sequence
       via its block table. ONE compiled shape per (B, Nmax) regardless of
-      live sequence lengths or block-table contents. `moe_load` is None
+      live sequence lengths or block-table contents. `moe_load` is absent
       without experts; with them int32 [2], both summed over the expert
       layers: `moe_hottest`, the load of the step's fullest expert (pairs
       routed to it by the live slots), and `moe_touched`, the experts with
@@ -1950,6 +2023,17 @@ def make_paged_decoder(
         return pad(q, kvp * n_rep), pad(k, kvp), pad(v, kvp)
 
     _sample = _make_sampler(temperature, cfg.vocab_pad)
+
+    def _host_row(toks, logits, moe_load=None):
+        """Everything the host reads back from one dispatch, one vector."""
+        parts = [toks]
+        if moe_load is not None:
+            parts.append(moe_load)
+        if logprobs:
+            parts.append(lax.bitcast_convert_type(
+                _token_logprobs(logits, toks, temperature, cfg.vocab_pad),
+                jnp.int32))
+        return jnp.concatenate(parts)  # of one part: the part itself
 
     def _pool_leaves(pool):
         """(k, v, k_scale, v_scale) of the STACKED pool, scales None for fp
@@ -2196,10 +2280,11 @@ def make_paged_decoder(
             jnp.stack([l_w, l_f]), out_dtype=cfg.dtype,
         )
 
-    def _prefill_body(G, params, pool, table, tokens, length, ctx_len, key,
-                      row=None):
+    def _prefill_body(G, Sb, params, pool, inputs, key):
         params = _cast_matmul_params(cfg, params)
-        Sb = tokens.shape[1]
+        length, ctx_len, row = (inputs[i] for i in range(PREFILL_HEAD))
+        tokens = inputs[PREFILL_HEAD:PREFILL_HEAD + Sb][None]
+        table = inputs[PREFILL_HEAD + Sb:]
         x = params["embed"].astype(cfg.dtype)[tokens]
         x = _hc_expand(_constrain(x, "batch", "seq", "embed"), cfg)
         qpos = ctx_len + jnp.arange(Sb)  # global positions of the suffix
@@ -2348,43 +2433,38 @@ def make_paged_decoder(
         x_last = x[0, jnp.maximum(length - 1, 0)][None]
         logits = jnp.einsum("be,ev->bv", x_last, _unembed_matrix(cfg, params))
         logits = _constrain(logits, "batch", "vocab")
-        return _sample(logits, key), logits, _pool_dict(*leaves)
+        return (_host_row(_sample(logits, key), logits), logits,
+                _pool_dict(*leaves))
 
     # jax.jit names a program after its function, and that name is what the
     # profiler's "XLA Modules" line shows: jit_paged_prefill,
     # jit_paged_decode, jit_paged_verify, jit_copy_blocks. The benchmark's
     # reduction finds the programs by these names (PERF.md, spans table).
-    _prefill_jits: Dict[int, Any] = {}
+    _prefill_jits: Dict[Tuple[int, int], Any] = {}
 
-    def _prefill_program(G: int):
-        def paged_prefill(params, pool, table, tokens, length, ctx_len, key):
-            return _prefill_body(
-                G, params, pool, table, tokens, length, ctx_len, key)
-
-        if hybrid:  # one more argument: the slot's row of the state pool
-            def paged_prefill(params, pool, table, tokens, length, ctx_len,
-                              key, row):
-                return _prefill_body(
-                    G, params, pool, table, tokens, length, ctx_len, key, row)
+    def _prefill_program(G: int, Sb: int):
+        def paged_prefill(params, pool, inputs, key):
+            return _prefill_body(G, Sb, params, pool, inputs, key)
 
         return jax.jit(paged_prefill, donate_argnums=(1,))
 
-    def prefill_dispatch(params, pool, table, tokens, length, ctx_len, key,
-                         ctx_blocks: int, row=None):
-        Sb = tokens.shape[1]
-        G = min(int(ctx_blocks) + -(-Sb // bt), table.shape[0])
-        fn = _prefill_jits.get(G)
+    def prefill_dispatch(params, pool, inputs, key, ctx_blocks: int, Sb: int):
+        Nmax = inputs.shape[0] - PREFILL_HEAD - Sb
+        G = min(int(ctx_blocks) + -(-Sb // bt), Nmax)
+        fn = _prefill_jits.get((G, Sb))
         if fn is None:
-            fn = _prefill_jits[G] = _prefill_program(G)
-        if hybrid:
-            return fn(params, pool, table, tokens, length, ctx_len, key, row)
-        return fn(params, pool, table, tokens, length, ctx_len, key)
+            fn = _prefill_jits[G, Sb] = _prefill_program(G, Sb)
+        return fn(params, pool, inputs, key)
 
-    prefill_dispatch.programs = _prefill_jits  # window width G -> program
+    # (window width G, suffix width Sb) -> program
+    prefill_dispatch.programs = _prefill_jits
 
-    def paged_decode(params, pool, tables, tokens, positions, write_phys,
-                     write_off, key):
+    def paged_decode(params, pool, inputs, key):
         params = _cast_matmul_params(cfg, params)
+        Nmax = inputs.shape[1] - DECODE_TAIL
+        tables = inputs[:, :Nmax]
+        tokens, positions, write_phys, write_off = (
+            inputs[:, Nmax + i] for i in range(DECODE_TAIL))
         W = tables.shape[1] * bt
         x = params["embed"].astype(cfg.dtype)[tokens][:, None, :]  # [B,1,E]
         x = _hc_expand(_constrain(x, "batch", "seq", "embed"), cfg)
@@ -2493,7 +2573,8 @@ def make_paged_decoder(
         logits = jnp.einsum("be,ev->bv", x[:, 0], _unembed_matrix(cfg, params))
         logits = _constrain(logits, "batch", "vocab")
         moe_load = None if stats is None else jnp.sum(stats, axis=0)
-        return _sample(logits, key), logits, _pool_dict(*leaves), moe_load
+        return (_host_row(_sample(logits, key), logits, moe_load), logits,
+                _pool_dict(*leaves))
 
     def _rmw_commit_quant(kc, ksc, knew, wp_i, wo_i):
         """[L]-batched twin of the decode step's `_write_token_quant`:

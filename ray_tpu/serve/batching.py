@@ -543,7 +543,10 @@ class ContinuousBatcher:
                           # a hybrid cache's state pool (kv_paging.StatePool)
                           "state_bytes_per_seq", "state_rows_total",
                           "state_rows_free", "state_snapshots",
-                          "state_restores", "state_snapshot_evictions"):
+                          "state_restores", "state_snapshot_evictions",
+                          # what the model programs cost the host
+                          "decode_steps", "rng_dispatches",
+                          "host_transfers"):
                     if k in es:
                         out[k] = es[k]
         return out

@@ -256,16 +256,16 @@ def _compile_paged_program(one_chip, monkeypatch, program, impl, int8,
     key = on_chip(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
     B, T = PAGED_SLOTS, PAGED_TABLE
     if program == "decode":
-        lowered = decode.lower(
-            params, pool, i32(B, T), i32(B), i32(B), i32(B), i32(B), key)
+        lowered = decode.lower(params, pool, i32(B, T + 4), key)
     elif program == "verify":  # k = 4 drafts
         lowered = verify.lower(
             params, pool, i32(B, T), i32(B, 5), i32(B), i32(B), i32(B, 5),
             i32(B, 5), key)
     else:  # a 128-token user turn behind 512 cached tokens
         lowered = jax.jit(
-            functools.partial(prefill, ctx_blocks=8), donate_argnums=(1,)
-        ).lower(params, pool, i32(T), i32(1, 128), i32(), i32(), key)
+            functools.partial(prefill, ctx_blocks=8, Sb=128),
+            donate_argnums=(1,)
+        ).lower(params, pool, i32(3 + 128 + T), key)
     compiled = lowered.compile()
     if impl == "fused":
         assert "tpu_custom_call" in compiled.as_text()
@@ -619,13 +619,12 @@ def _compile_hybrid(one_chip, monkeypatch, program, num_blocks=1036):
     key = on_chip(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
     B, T = PAGED_SLOTS, PAGED_TABLE
     if program == "decode":
-        lowered = decode.lower(
-            params, pool, i32(B, T), i32(B), i32(B), i32(B), i32(B), key)
+        lowered = decode.lower(params, pool, i32(B, T + 4), key)
     else:  # a 512-token turn behind 2,048 cached tokens
         lowered = jax.jit(
-            functools.partial(prefill, ctx_blocks=32), donate_argnums=(1,)
-        ).lower(params, pool, i32(T), i32(1, 512), i32(), i32(), key,
-                row=i32())
+            functools.partial(prefill, ctx_blocks=32, Sb=512),
+            donate_argnums=(1,)
+        ).lower(params, pool, i32(3 + 512 + T), key)
     compiled = lowered.compile()
     assert "tpu_custom_call" in compiled.as_text()  # the paged kernel
     return cfg, pool, compiled

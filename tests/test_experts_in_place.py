@@ -31,6 +31,8 @@ from ray_tpu.models import transformer as tfm
 from ray_tpu.models.transformer import (
     CONFIGS, TransformerConfig, init_paged_kv_cache, init_params,
     make_paged_decoder,
+    pack_decode_inputs,
+    pack_prefill_inputs,
 )
 
 L, X, E, F = 3, 8, 64, 32
@@ -189,23 +191,29 @@ def _run_programs(cfg, params, verify):
     got = []
 
     def padded(tokens, width=32):
-        out = np.zeros((1, width), np.int32)
-        out[0, :len(tokens)] = tokens
+        out = np.zeros(width, np.int32)
+        out[:len(tokens)] = tokens
         return out
 
-    tok, logits, pool = prefill(params, pool, table, padded(prompt[:19]),
-                                np.int32(19), np.int32(0), key, 0)
+    tok, logits, pool = prefill(
+        params, pool, pack_prefill_inputs(table, padded(prompt[:19]), 19, 0),
+        key, 0, 32)
     got += [tok, logits]
-    tok, logits, pool = prefill(params, pool, table, padded(prompt[16:]),
-                                np.int32(14), np.int32(16), key, 2)
+    tok, logits, pool = prefill(
+        params, pool, pack_prefill_inputs(table, padded(prompt[16:]), 14, 16),
+        key, 2, 32)
     got += [tok, logits]
     pos = len(prompt)
     for _ in range(4):
-        tok, logits, pool, load = decode(
-            params, pool, tables, np.array([int(tok[0]), 0], np.int32),
-            np.array([pos, 0], np.int32),
-            np.array([table[pos // BT], 0], np.int32),
-            np.array([pos % BT, 0], np.int32), key)
+        # the tokens and the two expert-load counts ride one vector
+        out, logits, pool = decode(
+            params, pool, pack_decode_inputs(
+                tables, np.array([int(tok[0]), 0], np.int32),
+                np.array([pos, 0], np.int32),
+                np.array([table[pos // BT], 0], np.int32),
+                np.array([pos % BT, 0], np.int32)), key)
+        tok, load = out[:2], out[2:]
+        assert load.shape == (2,)
         got += [tok[:1], logits[:1], load]
         pos += 1
     if verify:
@@ -254,13 +262,12 @@ def _lowered(program, cfg):
         return jax.ShapeDtypeStruct(shape, jnp.int32)
 
     if program == "paged_decode":
-        return decode.lower(params, pool, i32(2, NMAX), i32(2), i32(2),
-                            i32(2), i32(2), key).as_text()
+        return decode.lower(params, pool, i32(2, NMAX + 4), key).as_text()
     if program == "paged_verify":
         return verify.lower(params, pool, i32(2, NMAX), i32(2, 3), i32(2),
                             i32(2), i32(2, 3), i32(2, 3), key).as_text()
-    return jax.jit(lambda *a: prefill(*a, 0)).lower(
-        params, pool, i32(NMAX), i32(1, 16), i32(), i32(), key).as_text()
+    return jax.jit(lambda *a: prefill(*a, 0, 16)).lower(
+        params, pool, i32(3 + 16 + NMAX), key).as_text()
 
 
 @pytest.mark.parametrize("program", ["paged_prefill", "paged_decode",

@@ -784,6 +784,8 @@ def test_int8_logits_within_tolerance(tiny_f32):
     from ray_tpu.models.transformer import (
         init_paged_kv_cache,
         make_paged_decoder,
+        pack_decode_inputs,
+        pack_prefill_inputs,
     )
 
     cfg, params = tiny_f32
@@ -800,8 +802,8 @@ def test_int8_logits_within_tolerance(tiny_f32):
             cfg, block_tokens=bt, kv_dtype=kv_dtype
         )
         _, lg_p, pool = prefill(
-            params, pool, table, padded[None], np.int32(21), np.int32(0),
-            _jax.random.PRNGKey(0), 0,
+            params, pool, pack_prefill_inputs(table, padded, 21, 0),
+            _jax.random.PRNGKey(0), 0, 24,
         )
         toks, _, positions = (
             np.array([int(prompt[0])], np.int32),
@@ -810,8 +812,9 @@ def test_int8_logits_within_tolerance(tiny_f32):
         )
         wp = np.array([table[21 // bt]], np.int32)
         wo = np.array([21 % bt], np.int32)
-        _, lg_d, pool, _ = step(
-            params, pool, table[None], toks, positions, wp, wo,
+        _, lg_d, pool = step(
+            params, pool,
+            pack_decode_inputs(table[None], toks, positions, wp, wo),
             _jax.random.PRNGKey(1),
         )
         results[name] = (np.asarray(lg_p), np.asarray(lg_d))

@@ -38,7 +38,8 @@ from ray_tpu.models import transformer as tfm
 from ray_tpu.models.kv_paging import PagedDecodeEngine
 from ray_tpu.models.transformer import (
     CONFIGS, TransformerConfig, init_paged_kv_cache, init_params,
-    make_forward, make_paged_decoder, serving_params,
+    make_forward, make_paged_decoder, pack_decode_inputs, pack_prefill_inputs,
+    serving_params,
 )
 
 BT = 8  # block tokens
@@ -103,22 +104,24 @@ def _serve(params, prompt, n_new, impl, ctx=0, cfg=CFG):
     if ctx:
         pad = np.zeros((1, 32), np.int32)
         pad[0, :ctx] = prompt[:ctx]
-        _, _, pool = prefill(params, pool, table, pad, np.int32(ctx),
-                             np.int32(0), key, 0)
+        _, _, pool = prefill(
+            params, pool, pack_prefill_inputs(table, pad, ctx, 0), key, 0, 32)
     rest = prompt[ctx:]
     pad = np.zeros((1, 64), np.int32)
     pad[0, :len(rest)] = rest
-    tok, logits, pool = prefill(params, pool, table, pad, np.int32(len(rest)),
-                                np.int32(ctx), key, -(-ctx // BT))
+    tok, logits, pool = prefill(
+        params, pool, pack_prefill_inputs(table, pad, len(rest), ctx), key,
+        -(-ctx // BT), 64)
     out_logits, toks = [np.asarray(logits[0])], [int(tok[0])]
     tables = np.stack([table, np.zeros(nmax, np.int32)])
     for i in range(n_new - 1):
         pos = len(prompt) + i
         toks_in = np.array([toks[-1], 0], np.int32)
-        nxt, logits, pool, _ = decode(
-            params, pool, tables, toks_in, np.array([pos, 0], np.int32),
-            np.array([table[pos // BT], 0], np.int32),
-            np.array([pos % BT, 0], np.int32), key)
+        nxt, logits, pool = decode(
+            params, pool, pack_decode_inputs(
+                tables, toks_in, np.array([pos, 0], np.int32),
+                np.array([table[pos // BT], 0], np.int32),
+                np.array([pos % BT, 0], np.int32)), key)
         out_logits.append(np.asarray(logits[0]))
         toks.append(int(nxt[0]))
     return np.stack(out_logits), toks

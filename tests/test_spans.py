@@ -18,6 +18,7 @@ import pytest
 
 from ray_tpu.models import CONFIGS
 from ray_tpu.models.kv_paging import PagedDecodeEngine
+from ray_tpu.models.transformer import pack_decode_inputs, pack_prefill_inputs
 from ray_tpu.serve import telemetry
 from ray_tpu.util import profiling
 from ray_tpu.util.profiling import span
@@ -218,9 +219,9 @@ def test_prefill_span_covers_first_token_fetch(tmp_path):
     class _Late:
         """a device result that takes `wait_s` to materialise."""
 
-        def __getitem__(self, i):
+        def __array__(self, *a, **k):
             time.sleep(wait_s)
-            return 7
+            return np.array([7], np.int32)
 
     def stub_prefill(params, pool, *a):
         return _Late(), None, pool  # returns at once, like an enqueue
@@ -318,7 +319,7 @@ def test_moe_touched_counts_the_groups_the_live_slots_read(tmp_path,
 
     def spy(*a):
         out = decode(*a)
-        fetched.append(out[3])
+        fetched.append(out[0])
         return out
 
     eng._decode_step = spy
@@ -335,9 +336,9 @@ def test_moe_touched_counts_the_groups_the_live_slots_read(tmp_path,
     spans = [st for _, _, st in tr.spans("engine.decode")]
     assert [st["moe_touched"] for st in spans] == want_touched[1:]
     assert [st["moe_hottest"] for st in spans] == want_hottest[1:]
-    # one array of expert statistics a step, both counts in it
-    assert all(a.shape == (2,) and a.dtype == jnp.int32 for a in fetched)
-    assert [a.tolist() for a in fetched] == [
+    # both counts ride the one vector the step fetches, behind its 4 tokens
+    assert all(a.shape == (4 + 2,) and a.dtype == jnp.int32 for a in fetched)
+    assert [a[4:].tolist() for a in fetched] == [
         list(pair) for pair in zip(want_hottest, want_touched)]
     stats = eng.stats()
     assert stats["moe_touched"] == sum(want_touched)
@@ -346,7 +347,7 @@ def test_moe_touched_counts_the_groups_the_live_slots_read(tmp_path,
     _, dense = _tiny_engine(None)
     dense.admit(0, {"tokens": np.arange(1, 10), "max_new_tokens": 4})
     out = dense._decode_step(*_program_args(dense, "paged_decode")[1])
-    assert out[3] is None
+    assert out[0].shape == (dense.max_batch_size,)  # the tokens alone
 
 
 # --------------------------------------------- (d) names on the device
@@ -357,8 +358,9 @@ def _program_args(eng, which):
     zB = np.zeros(B, np.int32)
     key = jax.random.PRNGKey(0)
     if which == "paged_decode":
-        return eng._decode_step, (eng.params, eng.pool, eng._tables, zB, zB,
-                                  zB, zB, key)
+        return eng._decode_step, (
+            eng.params, eng.pool,
+            pack_decode_inputs(eng._tables, zB, zB, zB, zB), key)
     if which == "paged_verify":
         zK = np.zeros((B, K1), np.int32)
         return eng._verify_step, (eng.params, eng.pool, eng._tables, zK, zB,
@@ -367,8 +369,8 @@ def _program_args(eng, which):
         one = np.ones(1, np.int32)
         return eng._copy_blocks, (eng.pool, one, one)
     fn, = eng._prefill.programs.values()
-    return fn, (eng.params, eng.pool, eng._tables[0],
-                np.zeros((1, 16), np.int32), np.int32(9), np.int32(0), key)
+    return fn, (eng.params, eng.pool, pack_prefill_inputs(
+        eng._tables[0], np.zeros(16, np.int32), 9, 0), key)
 
 
 @pytest.mark.parametrize(
@@ -564,8 +566,6 @@ def test_linear_layers_lower_under_their_scopes(which):
     if which == "paged_prefill":
         eng.admit(0, {"tokens": np.arange(1, 10), "max_new_tokens": 2})
     fn, args = _program_args(eng, which)
-    if which == "paged_prefill":
-        args += (np.int32(0),)  # the slot's row of the state pool
     text = fn.lower(*args).as_text(debug_info=True)
     assert f"module @jit_{which} " in text
     own, other = (("gdn.scan/", "gdn.step/") if which == "paged_prefill"

@@ -19,6 +19,8 @@ from ray_tpu.models import (
     init_params,
     make_forward,
     make_paged_decoder,
+    pack_decode_inputs,
+    pack_prefill_inputs,
 )
 from ray_tpu.parallel import MeshSpec, PRESET_RULES, build_mesh
 
@@ -60,8 +62,9 @@ def _assert_decode_matches(cfg, params, rules=None, mesh=None,
     key = jax.random.PRNGKey(1)
     for i in range(b):
         _, logits, pool = prefill(
-            params, pool, tables[i], tokens[i:i + 1, :prefix],
-            np.int32(prefix), np.int32(0), key, ctx_blocks=0,
+            params, pool,
+            pack_prefill_inputs(tables[i], tokens[i, :prefix], prefix, 0),
+            key, 0, prefix,
         )
         np.testing.assert_allclose(
             np.asarray(logits)[0], full[i, prefix - 1], rtol=tol, atol=tol
@@ -69,9 +72,10 @@ def _assert_decode_matches(cfg, params, rules=None, mesh=None,
     positions = np.full(b, prefix, np.int32)
     rows = np.arange(b)
     for t in range(prefix, total - 1):
-        _, logits, pool, _ = decode_step(
-            params, pool, tables, tokens[:, t], positions,
-            tables[rows, positions // BT], positions % BT, key,
+        _, logits, pool = decode_step(
+            params, pool, pack_decode_inputs(
+                tables, tokens[:, t], positions,
+                tables[rows, positions // BT], positions % BT), key,
         )
         np.testing.assert_allclose(
             np.asarray(logits), full[:, t], rtol=tol, atol=tol
