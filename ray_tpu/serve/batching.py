@@ -34,7 +34,9 @@ from collections import deque
 from concurrent.futures import Future
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
-from ray_tpu.util.profiling import span
+from ray_tpu.util.profiling import mark, span
+
+from .telemetry import STAGE_ATTRS, current_request
 
 
 class _BatchQueue:
@@ -187,6 +189,14 @@ class GenerationStream:
         self._t_last = self.t_submit
         self.n_tokens = 0
         self._tel = None
+        # the request as the rest of the system knows it: the carried
+        # telemetry.RequestClock (None for a caller outside any request),
+        # where the last admission started and into which slot (set by the
+        # batcher), and whether a consumer has taken the first item yet
+        self._clock = None
+        self.t_admit = self.t_submit
+        self._slot = -1
+        self._first_pulled = False
         # finalize-once guard is a real lock: close() (caller thread) and
         # the batcher loop can race _finish on the same stream, and a
         # check-then-set would double-count request metrics
@@ -201,6 +211,7 @@ class GenerationStream:
         if tel is not None:
             if self.n_tokens == 0:
                 tel.ttft.observe(now - self.t_submit)
+                self._mark_first_token(tel, now)
             else:
                 tel.inter_token.observe(now - self._t_last)
         if self.n_tokens == 0:
@@ -208,6 +219,36 @@ class GenerationStream:
         self._t_last = now
         self.n_tokens += 1
         self._q.put(token)
+
+    def _span_ids(self) -> Dict[str, Any]:
+        """`rid` (this batcher's counter) and, inside a carried request,
+        `req` (the id every process knows it by)."""
+        if self._clock is None:
+            return {"rid": self.request_id}
+        return {"rid": self.request_id, "req": self._clock.rid}
+
+    def _mark_first_token(self, tel, now: float) -> None:
+        """Stage 6, once a request: the way here as one zero-length span
+        whose attributes are the stage durations in whole microseconds
+        (`queue_us` from the LAST enqueue, as serve_queue_wait_s has it;
+        `prefill_us` from the last admission's start, through the chunks
+        and the decode steps between them when the prompt was chunked),
+        and the stages before `submit` to serve_request_stage_s."""
+        us = {"queue_us": int((self.t_admit - self.t_enqueue) * 1e6),
+              "prefill_us": int((now - self.t_admit) * 1e6)}
+        if self._clock is not None:
+            for stage, dur in self._clock.stages_in(self.t_submit).items():
+                tel.observe_stage(stage, dur)
+                us[STAGE_ATTRS[stage]] = int(dur * 1e6)
+        mark("batcher.first_token", slot=self._slot, **self._span_ids(), **us)
+
+    def _mark_first_pull(self, tel) -> None:
+        """Stage 7, on the puller's thread: how long the first token lay
+        in the queue before a pull took it."""
+        waited = time.monotonic() - self.t_first
+        tel.observe_stage("first_pull_wait", waited)
+        mark("batcher.first_pull", **self._span_ids(),
+             waited_us=int(waited * 1e6))
 
     def _outcome(self) -> str:
         if self._error is not None:
@@ -292,6 +333,10 @@ class GenerationStream:
                     ended = True
                     break
                 items.append(nxt)
+        if not self._first_pulled and items:
+            self._first_pulled = True
+            if self._tel is not None:
+                self._mark_first_pull(self._tel)
         if ended:
             self._drained = True
             if self._error is not None:
@@ -440,6 +485,7 @@ class ContinuousBatcher:
                 raise ReplicaDrainingError()
             stream = GenerationStream(next(self._ids), request)
             stream._tel = self._tel
+            stream._clock = current_request()
             self._pending.put(stream)
         return stream
 
@@ -627,12 +673,14 @@ class ContinuousBatcher:
             self._active[slot] = stream
         # queue wait ends where ADMISSION STARTS: admit() runs the prefill
         # (possibly a whole long prompt), which must not read as queue time
-        t_admit = time.monotonic()
+        t_admit = stream.t_admit = time.monotonic()
+        stream._slot = slot
         # rid<->slot correlation for the trace and the timeline: the
         # engine's own spans and "admit" event know the slot, not the
-        # request id. The recorder event is named once admission succeeded
+        # request id (`req`: the id the proxy and the handle know it by).
+        # The recorder event is named once admission succeeded
         with span("batcher.admit", self._tel, slot=slot,
-                  rid=stream.request_id) as admit_span:
+                  **stream._span_ids()) as admit_span:
             return self._admit_into(slot, stream, request, t_admit,
                                     admit_span)
 

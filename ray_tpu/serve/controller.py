@@ -278,16 +278,23 @@ class ServeController:
         return self._apps[app_name]["ingress"]
 
     def flush_telemetry(self) -> int:
-        """Fan-out: every live replica force-pushes its flight recorder +
+        """Fan-out: every live replica and every proxy (the fleet's, and
+        the one `serve.run` starts) force-pushes its flight recorder +
         metrics to the head (serve.telemetry.dump_timeline's first step).
-        One shared deadline — a wedged replica costs one bounded wait.
-        Returns the number of replicas reached."""
+        One shared deadline — a wedged process costs one bounded wait.
+        Returns the number of processes reached."""
         import ray_tpu
+
+        from . import _PROXY_NAME
 
         with self._lock:
             replicas = [
                 r for s in self._deployments.values() for r in s.replicas
-            ]
+            ] + list(self._proxies.values())
+        try:
+            replicas.append(ray_tpu.get_actor(_PROXY_NAME))
+        except Exception:
+            pass  # no single proxy running
         refs = []
         for r in replicas:
             try:

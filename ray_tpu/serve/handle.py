@@ -22,6 +22,8 @@ import time
 from collections import deque
 from typing import Any, Optional
 
+from .telemetry import outgoing_request, request_scope
+
 CONTROLLER_NAME = "SERVE_CONTROLLER"
 
 
@@ -162,10 +164,13 @@ def _retryable_errors() -> tuple:
 
 
 class DeploymentResponse:
-    def __init__(self, ref, handle=None, call=None):
+    def __init__(self, ref, handle=None, call=None, ctx=None):
         self._ref = ref
         self._handle = handle
         self._call = call  # (args, kwargs) for replica-death retry
+        # the telemetry.RequestClock the call went out with: a retry of
+        # the same logical call keeps the request's id
+        self._ctx = ctx
         self.retries = 0   # re-route attempts this response consumed
         # the replica actor that served this call: streaming results
         # (ReplicaStreamHandle) must be pulled from the replica that holds
@@ -223,6 +228,7 @@ class DeploymentResponse:
                 retry = self.replica.handle_request.remote(
                     self._handle.method_name, args, kwargs,
                     model_id=self._handle.multiplexed_model_id,
+                    ctx=self._ctx,
                 )
                 out = ray_tpu.get(retry, timeout=_remaining())
                 if breaker is not None:
@@ -273,7 +279,8 @@ class DeploymentResponse:
             self.retries += 1
             try:
                 self._handle._refresh(force=True)
-                retry = self._handle.remote(*args, **kwargs)
+                with request_scope(self._ctx):
+                    retry = self._handle.remote(*args, **kwargs)
                 out = ray_tpu.get(retry.ref, timeout=_remaining())
                 self.replica = retry.replica
                 breaker.record_success()
@@ -543,6 +550,7 @@ class DeploymentHandle:
             from .kv_transfer import request_hint
 
             hint = request_hint(args, kwargs)
+        ctx = outgoing_request()
         for attempt in range(2):
             # re-checked every attempt: a force-refresh after a failed
             # submit may have adopted an empty/draining set. Failing here is
@@ -565,7 +573,7 @@ class DeploymentHandle:
             try:
                 ref = self._replicas[idx].handle_request.remote(
                     self.method_name, args, kwargs,
-                    model_id=self.multiplexed_model_id,
+                    model_id=self.multiplexed_model_id, ctx=ctx,
                 )
                 break
             except Exception:
@@ -578,6 +586,7 @@ class DeploymentHandle:
             )
         self._counts[idx] = self._counts.get(idx, 0) + 1
         self._inflight.append((idx, ref))
-        resp = DeploymentResponse(ref, handle=self, call=(args, kwargs))
+        resp = DeploymentResponse(ref, handle=self, call=(args, kwargs),
+                                  ctx=ctx)
         resp.replica = self._replicas[idx]
         return resp

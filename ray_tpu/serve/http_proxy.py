@@ -42,10 +42,13 @@ from __future__ import annotations
 import asyncio
 import json
 import threading
+import time
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 from typing import Any, Dict, Iterable, Optional
 from urllib.parse import parse_qs, urlsplit
+
+from . import telemetry
 
 # Replica-call threads; streaming holds none. KNOWN LIMIT: the pool bounds
 # concurrent REPLICA CALLS, so >pool-size slow calls queue (and their
@@ -237,6 +240,9 @@ class HTTPProxyActor:
         self.retry_after_s = float(
             _knob(retry_after_s, "serve_http_retry_after_s"))
         self.routes: Dict[str, _Route] = {}
+        # per the serve_telemetry flag in THIS process: None = a request
+        # still gets its id, but no clock is read for it here
+        self._tel = telemetry.get_telemetry()
         self._nconn = 0
         self._ncalls = 0  # replica calls submitted but not yet finished
         # replica calls block a pool thread; the loop never blocks
@@ -419,7 +425,13 @@ class HTTPProxyActor:
                     headers.get("connection", "").lower() != "close"
                     and version.upper() != "HTTP/1.0"
                 )
-                await self._dispatch(writer, method, target, headers, raw)
+                # stage 1, `proxy.recv`: the request is read whole. Its id
+                # is the client's `x-request-id` or minted here
+                ctx = telemetry.new_request(headers.get("x-request-id", ""))
+                if self._tel is not None:
+                    ctx.t_recv = time.time()
+                await self._dispatch(writer, method, target, headers, raw,
+                                     ctx)
                 if not keep_alive:
                     return
         except (ConnectionError, asyncio.CancelledError):
@@ -453,12 +465,47 @@ class HTTPProxyActor:
         writer.write(("\r\n".join(lines) + "\r\n\r\n").encode("latin1"))
         writer.write(payload)
         await writer.drain()
+        self._record_request(status)
+
+    def _record_request(self, status: int) -> None:
+        """The proxy's ONE flight-recorder event of a routed request,
+        `proxy.request`, at the first bytes it writes back — stage 8
+        (`proxy.first_write`) of a live stream, else the reply, an error's
+        included: `dur` from stage 1, args `req`, `status` and, as far as
+        the request got, `dispatch_us` (1 -> 2), `answer_us` (2 -> the
+        handle's reply at the pool thread), `pull_us` (the round trip of
+        the first `stream_next` that delivered). `return_to_client`, from
+        that pull's reply to here, is the proxy's own share of the way
+        back on one clock: serve_request_stage_s. Later writes of the
+        same request record nothing."""
+        ctx = telemetry.current_request()
+        if ctx is None or ctx.status is not None:
+            return
+        ctx.status = status
+        tel = self._tel
+        if tel is None or ctx.t_recv is None:
+            return
+        now = time.time()
+        if ctx.t_pulled is not None:
+            tel.observe_stage("return_to_client", now - ctx.t_pulled)
+        if tel.recorder is None:
+            return
+        args = {"req": ctx.rid, "status": status}
+        if ctx.t_call is not None:
+            args["dispatch_us"] = int((ctx.t_call - ctx.t_recv) * 1e6)
+        if ctx.answer_s is not None:
+            args["answer_us"] = int(ctx.answer_s * 1e6)
+        if ctx.pull_s is not None:
+            args["pull_us"] = int(ctx.pull_s * 1e6)
+        tel.recorder.record("proxy.request", dur=now - ctx.t_recv, args=args)
+        tel.flush_events()  # throttled delta push to the head
 
     async def _reply_chunked(self, writer, resp: StreamingResponse):
         writer.write(
             f"HTTP/1.1 200 OK\r\nContent-Type: {resp.content_type}\r\n"
             "Transfer-Encoding: chunked\r\n\r\n".encode("latin1")
         )
+        self._record_request(200)
         for chunk in resp.chunks:
             data = chunk.encode() if isinstance(chunk, str) else bytes(chunk)
             if not data:
@@ -469,12 +516,22 @@ class HTTPProxyActor:
         writer.write(b"0\r\n\r\n")
         await writer.drain()
 
-    def _call_route(self, route: _Route, args: tuple):
+    def _call_route(self, route: _Route, args: tuple, ctx):
         """Blocking replica call; runs on the bounded pool. Returns the
         DeploymentResponse too: a streaming result must be pulled from the
-        exact replica that holds the live stream (replica affinity)."""
-        resp = route.handle.remote(*args)
-        return resp, resp.result(timeout_s=self.request_timeout_s)
+        exact replica that holds the live stream (replica affinity). `ctx`
+        (the request's clock) is this thread's current request for the
+        call, which is how the handle finds it: no handle signature knows
+        of it."""
+        timed = ctx.t_recv is not None
+        with telemetry.request_scope(ctx):
+            if timed:
+                ctx.t_call = time.time()  # stage 2, `proxy.call`
+            resp = route.handle.remote(*args)
+            result = resp.result(timeout_s=self.request_timeout_s)
+        if timed:
+            ctx.answer_s = time.time() - ctx.t_call
+        return resp, result
 
     async def _pool_call(self, fn, timeout: float):
         """Submit a blocking callable to the call pool with the shared
@@ -505,10 +562,7 @@ class HTTPProxyActor:
         return export_prometheus(timeout=20.0).encode()
 
     async def _dispatch(self, writer, method: str, target: str,
-                        headers: Dict[str, str], raw: bytes):
-        from .handle import DeploymentUnavailableError
-        from .replica import ReplicaDrainingError
-
+                        headers: Dict[str, str], raw: bytes, ctx):
         parts = urlsplit(target)
         path = parts.path.rstrip("/") or "/"
         if method == "GET" and path == "/metrics":
@@ -553,6 +607,17 @@ class HTTPProxyActor:
             args = (arg,)
         else:
             args = () if body is None else (body,)
+        # a routed request: `ctx` is this task's current request from here
+        # to its last byte (what everything below reads it by)
+        with telemetry.request_scope(ctx):
+            await self._serve_route(writer, route, args)
+
+    async def _serve_route(self, writer, route: _Route, args: tuple):
+        from .handle import DeploymentUnavailableError
+        from .replica import ReplicaDrainingError
+
+        ctx = telemetry.current_request()
+
         if self._ncalls >= self.max_queued_calls:
             # saturation backpressure AHEAD of the pool: queueing more work
             # would only grow tail latency past the 504 deadline anyway
@@ -564,7 +629,7 @@ class HTTPProxyActor:
             return
         try:
             dresp, result = await self._pool_call(
-                lambda: self._call_route(route, args),
+                lambda: self._call_route(route, args, ctx),
                 self.request_timeout_s + 5.0,
             )
         except asyncio.TimeoutError:
@@ -680,13 +745,23 @@ class HTTPProxyActor:
         retried = False
         idle_deadline = self._loop.time() + self.request_timeout_s
 
-        def _pull(rep, sid):
+        # the way back is timed until the first chunk is written (stage
+        # 8), and not after: nothing is added per token or per later pull
+        ctx = telemetry.current_request()
+        timed = ctx.t_recv is not None
+
+        def _pull(rep, sid, timed):
             import ray_tpu
 
-            return ray_tpu.get(
+            t0 = time.time() if timed else 0.0
+            out = ray_tpu.get(
                 rep.stream_next.remote(sid, max_chunks, pull_wait),
                 timeout=self.request_timeout_s,
             )
+            if timed:  # overwritten until a pull delivers
+                ctx.t_pulled = time.time()
+                ctx.pull_s = ctx.t_pulled - t0
+            return out
 
         while True:
             if replica is None:
@@ -698,7 +773,8 @@ class HTTPProxyActor:
             try:
                 rep, sid = replica, sh.stream_id
                 chunks, done = await self._pool_call(
-                    lambda: _pull(rep, sid), self.request_timeout_s + 5.0
+                    lambda: _pull(rep, sid, timed),
+                    self.request_timeout_s + 5.0
                 )
             except (asyncio.TimeoutError, GetTimeoutError):
                 # GetTimeoutError is the common spelling (the blocking
@@ -730,7 +806,7 @@ class HTTPProxyActor:
                     # for up to request_timeout_s and must be visible to
                     # the saturation gate
                     dresp, result = await self._pool_call(
-                        lambda: self._call_route(route, args),
+                        lambda: self._call_route(route, args, ctx),
                         self.request_timeout_s + 5.0,
                     )
                 except asyncio.TimeoutError:
@@ -777,6 +853,9 @@ class HTTPProxyActor:
                     writer.write(f"{len(data):x}\r\n".encode() + data + b"\r\n")
                 # backpressure: a slow client parks THIS coroutine only
                 await writer.drain()
+                if timed and chunks:
+                    timed = False
+                    self._record_request(200)  # stage 8, `proxy.first_write`
                 if done:
                     writer.write(b"0\r\n\r\n")
                     await writer.drain()
@@ -801,6 +880,11 @@ class HTTPProxyActor:
                 return
 
     # ---------------------------------------------------------- actor API
+
+    def flush_telemetry(self) -> bool:
+        """Force-push this proxy's flight recorder (its `proxy.request`
+        events) and metrics to the head: dump_timeline()'s fan-out."""
+        return telemetry.flush_to_head()
 
     def ready(self):
         host = self.host

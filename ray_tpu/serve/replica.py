@@ -26,6 +26,8 @@ import time
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
 
+from . import telemetry
+
 
 class ReplicaDrainingError(RuntimeError):
     """Raised by a draining replica for NEW requests. No user code ran, so
@@ -114,7 +116,14 @@ class Replica:
         """This replica's worker process id (chaos tests SIGKILL it)."""
         return os.getpid()
 
-    def handle_request(self, method_name: str, args, kwargs, model_id: str = ""):
+    def handle_request(self, method_name: str, args, kwargs,
+                       model_id: str = "", ctx=None):
+        """`ctx` is the caller's telemetry.RequestClock (the request's id
+        and the proxy's stamps), carried beside `model_id`; a caller that
+        sends none (kv_transfer's peer calls, a direct caller) is served
+        the same."""
+        if ctx is not None:
+            ctx.received()  # stage 3, before anything else
         with self._lock:
             if self._draining:
                 raise ReplicaDrainingError(self.deployment_name)
@@ -125,10 +134,12 @@ class Replica:
 
             _set_model_id(model_id)
         try:
-            if self.is_function or method_name == "__call__":
-                result = self.callable(*args, **kwargs)
-            else:
-                result = getattr(self.callable, method_name)(*args, **kwargs)
+            with telemetry.request_scope(ctx):
+                if self.is_function or method_name == "__call__":
+                    result = self.callable(*args, **kwargs)
+                else:
+                    result = getattr(self.callable, method_name)(
+                        *args, **kwargs)
             return self._maybe_register_stream(result)
         finally:
             if model_id:
@@ -448,40 +459,7 @@ class Replica:
     def flush_telemetry(self) -> bool:
         """Force-push this replica's flight recorder (and metrics) to the
         head — dump_timeline()'s fan-out target, also called on drain."""
-        try:
-            from ray_tpu.util import metrics
-
-            from . import telemetry
-
-            telemetry.flush_events(force=True)
-            metrics.flush()
-            # pushes are fire-and-forget on the worker socket: a round trip
-            # behind them barriers delivery, so a dump_timeline() reading
-            # the head right after this fan-out returns sees these events.
-            # BOUNDED: flush_telemetry sits on the drain path, and a
-            # wedged head must not park the replica's reap forever
-            try:
-                from ray_tpu._private.worker import global_worker
-
-                global_worker.request({"t": "ping"}, timeout=10)
-            except Exception:
-                pass
-            return True
-        except Exception:
-            return False
-
-    def dump_flight_recorder(self) -> List[Dict[str, Any]]:
-        """This replica process's flight-recorder snapshot (wall-clock
-        event dicts) — the direct-pull path for tests/debuggers."""
-        try:
-            from . import telemetry
-
-            tel = telemetry.get_telemetry()
-            if tel is not None and tel.recorder is not None:
-                return tel.recorder.snapshot()
-        except Exception:
-            pass
-        return []
+        return telemetry.flush_to_head()
 
     def check_health(self) -> bool:
         user_check = getattr(self.callable, "check_health", None)

@@ -10,6 +10,14 @@ step-level events (admit, prefill_chunk, decode, verify, rollback,
 preempt, readmit, retire, eos) with monotonic timestamps and slot ids,
 dumpable as Chrome trace-event JSON.
 
+One request, one id: a `RequestClock` is minted where a request enters
+(the HTTP proxy, or `DeploymentHandle.remote` for a handle caller), rides
+the actor call beside `model_id` and is stamped at eight boundaries from
+the proxy's socket to the first token's pull; its stage durations go to
+the sinks `profiling.span()` already has (trace attributes, one histogram
+family, the flight recorder), where `req` lines one request's events up
+across processes.
+
 Three layers, all behind the `serve_telemetry` flag:
 
   ServeTelemetry   per-process singleton bundling the metric handles
@@ -38,12 +46,16 @@ process even when nobody got to call dump_timeline() in time.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
+import itertools
 import json
 import os
+import re
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, Iterator, List, Optional
 
 from ray_tpu.util.metrics import Counter, Gauge, Histogram
 
@@ -53,6 +65,114 @@ LATENCY_BOUNDARIES = [
     0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
     1.0, 2.5, 5.0, 10.0, 30.0, 60.0,
 ]
+# the request-path stages sit in the 0.05-5 ms band: three finer buckets
+# under the latency families' lowest
+STAGE_BOUNDARIES = [0.0001, 0.00025, 0.0005] + LATENCY_BOUNDARIES
+
+# the three stages before `submit` with the `batcher.first_token` span's
+# attribute for each, and all of `serve_request_stage_s{stage}`
+STAGE_ATTRS = {"proxy_dispatch": "proxy_us", "handle_transit": "ingress_us",
+               "replica_presubmit": "replica_us"}
+REQUEST_STAGES = tuple(STAGE_ATTRS) + ("first_pull_wait", "return_to_client")
+
+
+class RequestClock:
+    """One request's id and its stamps on the way IN, carried from the
+    process where the request entered to the replica that serves it.
+
+    The eight stages of a first token: (1) `proxy.recv` the proxy has read
+    the request, (2) `proxy.call` a pool thread is about to call the handle,
+    (3) `replica.recv` `Replica.handle_request` begins, (4) `batcher.submit`,
+    (5) `batcher.admit`, (6) `batcher.first_token` the first `_push`,
+    (7) `batcher.first_pull` a consumer takes it from the stream's queue,
+    (8) `proxy.first_write` the first chunk is drained to the client's
+    socket. 1-3 live here; 4-6 are the `GenerationStream`'s own monotonic
+    `t_submit` / `t_admit` / `t_first`, 7 is read in `next_batch`, 8 in the
+    proxy.
+
+    Stamps compared ACROSS a process boundary (1, 2 against 3) are wall
+    clock: proxy and replica share a host in every cell the benchmark runs;
+    across hosts `handle_transit` includes the two clocks' skew. Stage 3
+    also reads the monotonic clock, which 3 -> 4 is measured on. Pickles as
+    `(rid, t_recv, t_call)`: what the replica stamps stays in the replica,
+    and so does the proxy's bookkeeping of the way back (`status`,
+    `answer_s`, `pull_s`, `t_pulled`: see http_proxy.py)."""
+
+    __slots__ = ("rid", "t_recv", "t_call", "t_replica", "t_replica_mono",
+                 "status", "answer_s", "pull_s", "t_pulled")
+
+    def __init__(self, rid: str, t_recv: Optional[float] = None,
+                 t_call: Optional[float] = None):
+        self.rid = rid
+        self.t_recv, self.t_call = t_recv, t_call
+        self.t_replica = self.t_replica_mono = None
+        self.status = self.answer_s = self.pull_s = self.t_pulled = None
+
+    def __reduce__(self):
+        return (RequestClock, (self.rid, self.t_recv, self.t_call))
+
+    def received(self) -> None:
+        """Stage 3, first line of `Replica.handle_request`. The replica
+        cannot know yet which batcher the callable will submit to, so the
+        process's flag decides: off, no clock is read."""
+        if get_telemetry() is not None:
+            self.t_replica = time.time()
+            self.t_replica_mono = time.monotonic()
+
+    def stages_in(self, t_submit: float) -> Dict[str, float]:
+        """Seconds of each stage before `submit` whose two stamps exist,
+        by `REQUEST_STAGES` name: none without stage 3 (telemetry off in
+        the replica's process), the proxy's two absent for a handle
+        caller."""
+        out: Dict[str, float] = {}
+        if self.t_replica is None:
+            return out
+        if self.t_call is not None:
+            if self.t_recv is not None:
+                out["proxy_dispatch"] = self.t_call - self.t_recv
+            out["handle_transit"] = self.t_replica - self.t_call
+        out["replica_presubmit"] = t_submit - self.t_replica_mono
+        return out
+
+
+_REQUEST: "contextvars.ContextVar[Optional[RequestClock]]" = (
+    contextvars.ContextVar("ray_tpu_serve_request", default=None))
+_REQUEST_IDS = itertools.count(1)
+_RID_UNSAFE = re.compile(r"[^A-Za-z0-9_.:-]")
+
+
+def current_request() -> Optional[RequestClock]:
+    """The request this thread (or asyncio task) is serving, if any."""
+    return _REQUEST.get()
+
+
+def new_request(rid: str = "") -> RequestClock:
+    """A clock under the caller's id (a client's `x-request-id`, cut to 64
+    characters a trace attribute can hold) or a minted `<pid hex>-<n>`."""
+    rid = _RID_UNSAFE.sub("_", rid)[:64] if rid else ""
+    return RequestClock(rid or f"{os.getpid():x}-{next(_REQUEST_IDS)}")
+
+
+def outgoing_request() -> RequestClock:
+    """What a handle sends with a call: the clock the proxy set on this
+    thread; from inside a request a replica is serving, that request's id
+    alone (its stamps belong to the first hop); else a newly minted one."""
+    ctx = _REQUEST.get()
+    if ctx is None:
+        return new_request()
+    if ctx.t_replica is not None:
+        return RequestClock(ctx.rid)
+    return ctx
+
+
+@contextlib.contextmanager
+def request_scope(ctx: Optional[RequestClock]) -> Iterator[None]:
+    """`ctx` is `current_request()` inside the block, on this thread."""
+    token = _REQUEST.set(ctx)
+    try:
+        yield
+    finally:
+        _REQUEST.reset(token)
 
 
 class FlightRecorder:
@@ -179,7 +299,21 @@ class ServeTelemetry:
             "serve_weight_version",
             "learner weight version the replica's engine is serving",
             tag_keys=base)
+        # the request path outside submit -> first token (which stay
+        # serve_queue_wait_s and serve_ttft_s): observed once a request, in
+        # the replica at the first token and its first pull, in the proxy
+        # (`return_to_client`) at the first chunk's write
+        self.request_stage = Histogram(
+            "serve_request_stage_s",
+            "a request's way to its first token by stage: proxy_dispatch "
+            "(proxy has the request -> pool thread calls the handle), "
+            "handle_transit (-> replica has the call), replica_presubmit "
+            "(-> batcher.submit), first_pull_wait (first token pushed -> a "
+            "pull takes it), return_to_client (the pull's reply at the "
+            "proxy -> first chunk written)",
+            boundaries=STAGE_BOUNDARIES, tag_keys=base + ("stage",))
         self._all = [
+            self.request_stage,
             self.ttft, self.inter_token, self.queue_wait,
             self.request_latency, self.engine_step, self.requests,
             self.preemptions, self.tokens, self.kv_util, self.occupancy,
@@ -197,6 +331,14 @@ class ServeTelemetry:
             p: self.engine_step.tags_key({"phase": p})
             for p in ("prefill", "decode", "verify")
         }
+        self._stage_keys = {
+            s: self.request_stage.tags_key({"stage": s})
+            for s in REQUEST_STAGES
+        }
+
+    def observe_stage(self, stage: str, dur: float) -> None:
+        # a wall-clock pair across hosts can read negative by their skew
+        self.request_stage.observe_key(max(0.0, dur), self._stage_keys[stage])
 
     def observe_phase(self, phase: str, dur: float) -> None:
         self.engine_step.observe_key(dur, self._phase_keys[phase])
@@ -302,6 +444,31 @@ def flush_events(force: bool = False) -> None:
     tel = _TEL
     if tel is not None:
         tel.flush_events(force=force)
+
+
+def flush_to_head() -> bool:
+    """Force-push this process's flight recorder and metrics to the head
+    and wait for them to land: `dump_timeline()`'s fan-out target in every
+    replica and proxy, also called on drain."""
+    try:
+        from ray_tpu.util import metrics
+
+        flush_events(force=True)
+        metrics.flush()
+        # pushes are fire-and-forget on the worker socket: a round trip
+        # behind them barriers delivery, so a dump_timeline() reading the
+        # head right after this fan-out returns sees these events.
+        # BOUNDED: this sits on the drain path, and a wedged head must not
+        # park a replica's reap forever
+        try:
+            from ray_tpu._private.worker import global_worker
+
+            global_worker.request({"t": "ping"}, timeout=10)
+        except Exception:
+            pass
+        return True
+    except Exception:
+        return False
 
 
 def record_orphaned_request(mtype: str, rid: int, tag: str = "") -> None:

@@ -17,7 +17,9 @@ two snapshots taken `duration` apart and reports the top allocation sites
 `span()` is the serving hot path's one timing primitive: a context manager
 that writes a layer boundary ONCE to three sinks — the jax.profiler trace
 (so it lies on the device's clock), the serve_engine_step_s histogram and
-the flight recorder (serve/telemetry.py).
+the flight recorder (serve/telemetry.py). `mark()` is its zero-length
+sibling: a point on the trace whose attributes are durations the program
+measured with its own clocks (a request's stage durations, once a request).
 """
 
 from __future__ import annotations
@@ -103,6 +105,14 @@ def span(name: str, tel=None, phase: Optional[str] = None, slot: int = -1,
     `request` / `readmit`). With `tel=None` no clock is read and no
     histogram or recorder work is done."""
     return Span(name, tel, phase, slot, event, counts)
+
+
+def mark(name: str, **counts) -> None:
+    """A zero-length span: one `TraceAnnotation(name, **counts)` opened and
+    closed at once, trace only — no clock read, no histogram, no recorder.
+    What it carries is in its attributes, never in its position."""
+    with Span(name, None, None, -1, None, counts):
+        pass
 
 
 def _frame_label(frame) -> str:

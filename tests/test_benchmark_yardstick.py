@@ -50,3 +50,15 @@ del test_every_new_metric_is_declared_with_its_cells  # noqa: F821
 # Every other assertion of it is held, with Xing4.0 found by name, by
 # test_the_xing4_cell_is_declared_as_pr33_left_it (test_olmo_hybrid_block.py).
 del test_the_cell_is_declared_and_only_appended  # noqa: F821
+
+# pin what PR 39 appends to: `len(names) == 54` per-layer metrics, and the
+# EXACT set of metrics that list the hybrid cell — true until a PR appends a
+# metric that lists it, which the benchmark's contract allows and PR 39 does
+# (five serve-front readers behind the 54; the file is the benchmark's own).
+# Every other assertion of both is held, with the 54 names in their order as
+# a prefix and the cell's set as "PR 35's plus the five", by
+# test_benchmark_json_is_the_parents_plus_the_five_of_pr39 and
+# test_the_hybrid_cell_keeps_its_metrics_and_gains_the_five_of_pr39
+# (test_request_clock_readers.py).
+del test_benchmark_json_is_the_parents_plus_appended_entries  # noqa: F821
+del test_the_hybrid_cell_is_declared_with_its_metrics  # noqa: F821

@@ -360,7 +360,7 @@ def test_plane_timeout_retries_same_replica_never_trips_breaker(monkeypatch):
     retry_log = []
 
     class FakeMethod:
-        def remote(self, method, args, kwargs, model_id=None):
+        def remote(self, method, args, kwargs, model_id=None, ctx=None):
             retry_log.append((method, args, kwargs, model_id))
             return "retry-ref"
 
@@ -406,7 +406,7 @@ def test_plane_timeout_exhaustion_releases_probe_not_failure(monkeypatch):
     monkeypatch.setitem(cfg._overrides, "serve_handle_backoff_max_s", 0.02)
 
     class FakeMethod:
-        def remote(self, method, args, kwargs, model_id=None):
+        def remote(self, method, args, kwargs, model_id=None, ctx=None):
             return "retry-ref"
 
     class FakeReplica:

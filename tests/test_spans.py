@@ -130,6 +130,22 @@ def test_span_without_recorder_event_or_phase():
     assert _prefill_hist(tel) == before
 
 
+def test_mark_is_a_zero_length_span_that_reads_no_clock(tmp_path, monkeypatch):
+    class _NoClock:
+        @staticmethod
+        def monotonic():
+            raise AssertionError("mark read the clock")
+
+    monkeypatch.setattr(profiling, "time", _NoClock)
+    with _Trace(tmp_path) as tr:
+        with span("t.outer"):
+            profiling.mark("t.mark", slot=3, waited_us=1250, req="a-1")
+    (s, e, stats), = tr.spans("t.mark")
+    assert stats == {"slot": 3, "waited_us": 1250, "req": "a-1"}
+    assert e - s < 1e6  # under a millisecond: nothing runs inside it
+    _inside((s, e), tr.spans("t.outer"))
+
+
 # ------------------------------------------- (b) spans of batcher + engine
 
 
@@ -206,6 +222,88 @@ def test_batcher_and_engine_spans_nest(tmp_path):
     assert names.count("decode") == len(decodes) + 1  # + the warm-up step
     dec_ev = [e for e in tel.recorder.snapshot() if e["name"] == "decode"]
     assert all(isinstance(e["args"]["slots"], tuple) for e in dec_ev)
+
+
+def test_first_token_and_first_pull_spans_once_a_request(tmp_path):
+    """A request that carries a clock (telemetry.RequestClock, as the proxy
+    and the handle send it): `batcher.first_token` with the five stage
+    durations and `batcher.first_pull` with `waited_us`, ONCE each however
+    many tokens stream, `req` on both and on `batcher.admit`; a request
+    that carries none (submitted outside any request) has the spans
+    without `req` and without the stages before `submit`."""
+    from ray_tpu.serve.batching import ContinuousBatcher
+
+    tel = telemetry.ServeTelemetry(recorder_capacity=512)
+    cfg, eng = _tiny_engine(tel, prefix_cache=False)
+    rng = np.random.default_rng(0)
+    eng.admit(0, {"tokens": rng.integers(1, cfg.vocab_size, size=11),
+                  "max_new_tokens": 3})
+    eng.step([0])
+    eng.release(0)
+    def stage_counts():  # the registry is the process's: read deltas
+        snap = tel.request_stage._snapshot()["values"]
+        return {dict(k)["stage"]: v["count"] for k, v in snap.items()}
+
+    counts0 = stage_counts()
+    ctx = telemetry.new_request("client-7")
+    ctx.t_recv = time.time()
+    ctx.t_call = time.time()
+    ctx.received()  # stage 3, as Replica.handle_request stamps it
+    with _Trace(tmp_path) as tr:
+        b = ContinuousBatcher(eng, max_batch_size=2, batch_wait_timeout_s=0.0,
+                              telemetry=tel)
+        try:
+            with telemetry.request_scope(ctx):
+                carried = b.submit(
+                    tokens=rng.integers(1, cfg.vocab_size, size=11),
+                    max_new_tokens=20)
+            bare = b.submit(tokens=rng.integers(1, cfg.vocab_size, size=9),
+                            max_new_tokens=4)
+            assert len(list(carried)) == 20 and len(list(bare)) == 4
+        finally:
+            b.close()
+    firsts = {st["rid"]: (s, e, st)
+              for s, e, st in tr.spans("batcher.first_token")}
+    pulls = {st["rid"]: st for _, _, st in tr.spans("batcher.first_pull")}
+    admits = {st["rid"]: (s, e, st) for s, e, st in tr.spans("batcher.admit")}
+    assert set(firsts) == set(pulls) == set(admits) == {
+        carried.request_id, bare.request_id}  # one of each a request
+    s, e, st = firsts[carried.request_id]
+    assert set(st) == {"rid", "req", "slot", "proxy_us", "ingress_us",
+                       "replica_us", "queue_us", "prefill_us"}
+    assert st["req"] == "client-7" and st["slot"] == carried._slot
+    assert all(st[k] >= 0 for k in st if k.endswith("_us"))
+    # the stream's own clock: submit -> admission -> first token
+    assert st["queue_us"] == int(
+        (carried.t_admit - carried.t_submit) * 1e6)
+    assert st["prefill_us"] == int(
+        (carried.t_first - carried.t_admit) * 1e6)
+    assert st["replica_us"] == int(
+        (carried.t_submit - ctx.t_replica_mono) * 1e6)
+    assert st["ingress_us"] == int((ctx.t_replica - ctx.t_call) * 1e6)
+    # opened where the admission returned the token: inside its span, and
+    # (all but) zero-length, so what reads `batcher.admit` reads the same
+    a0, a1, ast = admits[carried.request_id]
+    assert a0 <= s and e <= a1 and e - s < 1e6
+    assert ast == {"rid": carried.request_id, "req": "client-7",
+                   "slot": carried._slot}
+    assert set(pulls[carried.request_id]) == {"rid", "req", "waited_us"}
+    assert pulls[carried.request_id]["req"] == "client-7"
+    assert pulls[carried.request_id]["waited_us"] >= 0
+    assert set(firsts[bare.request_id][2]) == {
+        "rid", "slot", "queue_us", "prefill_us"}
+    assert set(pulls[bare.request_id]) == {"rid", "waited_us"}
+    assert set(admits[bare.request_id][2]) == {"rid", "slot"}
+    # the histogram: each stage once, for the request that carried them
+    assert {k: n - counts0.get(k, 0)
+            for k, n in stage_counts().items()} == {
+        "proxy_dispatch": 1, "handle_transit": 1, "replica_presubmit": 1,
+        "first_pull_wait": 2}
+    # and the recorder's admission events carry the id
+    reqs = [ev["args"] for ev in tel.recorder.snapshot()
+            if ev["name"] == "request"]
+    assert reqs == [{"rid": carried.request_id, "req": "client-7"},
+                    {"rid": bare.request_id}]
 
 
 # -------------------------------- (c) the prefill span covers the fetch
