@@ -805,6 +805,10 @@ class PagedDecodeEngine:
         self.moe_pairs = 0
         self.moe_hottest = 0
         self.moe_touched = 0
+        # summed over the decode steps: the live slots' live blocks (what a
+        # paged kernel has to visit) and the block table's size B x Nmax
+        self.kv_blocks_walked = 0
+        self.kv_table_blocks = 0
         self.prefix_hits = 0
         self.prefix_tokens_reused = 0
         self.preemptions = 0
@@ -1477,20 +1481,26 @@ class PagedDecodeEngine:
             B = self.max_batch_size
             write_phys = np.zeros(B, np.int32)  # inactive rows -> null block
             write_off = np.zeros(B, np.int32)
-            kv_tokens = 0
+            kv_tokens = kv_blocks = 0
             for s in surviving:
                 pos = int(self._positions[s])
                 write_phys[s] = self._tables[s, pos // bt]
                 write_off[s] = pos % bt
                 kv_tokens += pos + 1  # the step attends to 0..pos
+                kv_blocks += pos // bt + 1  # in that many of its blocks
             # everything the program needs from the host, one array
             inputs = pack_decode_inputs(
                 self._tables, self._last_tokens, self._positions,
                 write_phys, write_off)
             key = self._sample_key()
         # slots: the recorder keeps the ids (one timeline lane each), the
-        # trace their number; kv_tokens is what the paged kernel must read
-        step_span.set(slots=tuple(surviving), kv_tokens=kv_tokens)
+        # trace their number; kv_tokens is what the paged kernel must read,
+        # kv_blocks_walked the table entries that hold it, of kv_table_blocks
+        step_span.set(slots=tuple(surviving), kv_tokens=kv_tokens,
+                      kv_blocks_walked=kv_blocks,
+                      kv_table_blocks=self._tables.size)
+        self.kv_blocks_walked += kv_blocks
+        self.kv_table_blocks += self._tables.size
         uploads, fetches = self.uploads, self.fetches
         with span("engine.dispatch"):
             # one launch, and in it one upload: `inputs` is the only
@@ -2020,6 +2030,8 @@ class PagedDecodeEngine:
             "moe_pairs": self.moe_pairs,
             "moe_hottest": self.moe_hottest,
             "moe_touched": self.moe_touched,
+            "kv_blocks_walked": self.kv_blocks_walked,
+            "kv_table_blocks": self.kv_table_blocks,
             "max_batch_size": self.max_batch_size,
             "block_tokens": self.block_tokens,
             "kv_cache_dtype": self.kv_cache_dtype,

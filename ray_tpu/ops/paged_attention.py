@@ -14,10 +14,16 @@ number of queries per slot — one fused implementation serves
   speculative verify q = k+1 (the draft window scored in one pass)
   prefill           q = chunk (chunked prefill of a prompt span)
 
-  grid = (batch, q_tile, block)   block innermost, so the online-softmax
-                          scratch (f32 acc / running max / denominator)
-                          persists across one (slot, q-tile)'s walk of the
-                          slot's block table
+  grid = (live slot, q_tile, block)
+                          block innermost, so the online-softmax scratch
+                          (f32 acc / running max / denominator) persists
+                          across one (slot, q-tile)'s walk of the slot's
+                          block table. The first and the last bound are
+                          TRACED: how many slots hold a visible key, and how
+                          many blocks the longest of them walks
+                          (`_live_walk`, from the call's own tables,
+                          positions and kv_len). One compiled program per
+                          (B, Nmax) serves every occupancy
   k/v BlockSpec           index_map reads the slot's block table (a
                           scalar-prefetch operand) and DMAs physical
                           block `table[b, j]` directly from the pool —
@@ -30,10 +36,21 @@ number of queries per slot — one fused implementation serves
                           DMA addresses block `table[b, j]` of layer `l` in
                           the stacked buffer: the caller never slices a
                           layer out of it. (A 4-D pool is a stack of one.)
-  dead entries            table entries < 0 (padding, inactive slots,
-                          out-of-shard blocks) clamp to block 0 in the
-                          index map — Pallas skips the re-fetch when the
-                          block index repeats — and are masked in-body
+  dead entries            a table entry < 0 is dead: the padding past a
+                          slot's last block, a released slot's whole row,
+                          an out-of-shard block of a block-sharded pool.
+                          The walk's length is what lives, not the table's
+                          shape: a slot with no visible key in a live entry
+                          is not in the grid at all (its rows are the
+                          outputs' initial zeros, aliased in), and no slot
+                          is walked past the longest live slot's last live
+                          block. What is left of the old cost: a shorter
+                          slot's steps under a longer one's walk, and holes
+                          inside a live range — those clamp to block 0 in
+                          the index map (Pallas skips the re-fetch when the
+                          block index repeats) and are skipped in-body, at
+                          about 0.1 us a step on a v5e where a live 64-token
+                          block costs 0.65 us (8 kv heads) to 1.5 us (32)
   causal masking          query i sits at global position positions[b]+i;
                           key position j*block + t is visible iff
                           t' <= positions[b]+i AND t' < kv_len[b]. The
@@ -105,19 +122,24 @@ def _group_values(p, v):
     )
 
 
-def _pa_kernel(tables_ref, pos_ref, kvlen_ref, layer_ref, q_ref, k_ref, v_ref,
-               *rest, bt, qb, n_rep, scale, quantized, partial_out, out_dtype):
+def _pa_kernel(tables_ref, pos_ref, kvlen_ref, layer_ref, slots_ref, q_ref,
+               k_ref, v_ref, *rest, bt, qb, n_rep, scale, quantized,
+               partial_out, out_dtype):
     del layer_ref  # read by the k/v index maps only
     if quantized:
         ks_ref, vs_ref = rest[0], rest[1]
         rest = rest[2:]
+    # the outputs' initial values ride in as aliased HBM operands the body
+    # never touches: a slot the grid does not visit keeps them
+    n_out = 3 if partial_out else 1
+    rest = rest[n_out:]
     if partial_out:
         o_ref, m_ref, l_ref = rest[:3]
         acc, m_i, l_i = rest[3:]
     else:
         o_ref = rest[0]
         acc, m_i, l_i = rest[1:]
-    b = pl.program_id(0)
+    b = slots_ref[pl.program_id(0)]  # the grid's first axis walks LIVE slots
     qt = pl.program_id(1)
     j = pl.program_id(2)
     nj = pl.num_programs(2)
@@ -130,14 +152,13 @@ def _pa_kernel(tables_ref, pos_ref, kvlen_ref, layer_ref, q_ref, k_ref, v_ref,
         m_i[:] = jnp.full_like(m_i, NEG_INF)
         l_i[:] = jnp.zeros_like(l_i)
 
-    entry = tables_ref[b, j]
     pos = pos_ref[b]
     kvl = kvlen_ref[b]
     qbase = pos + qt * qb  # global position of this tile's first query
     # the block matters iff any of the tile's queries can see any key in it:
     # its first key must precede both the kv_len cap and the LAST query
     live = jnp.logical_and(
-        entry >= 0,
+        tables_ref[b, j] >= 0,
         jnp.logical_and(j * bt < kvl, j * bt <= qbase + qb - 1),
     )
 
@@ -199,12 +220,34 @@ def _pa_kernel(tables_ref, pos_ref, kvlen_ref, layer_ref, q_ref, k_ref, v_ref,
             o_ref[0] = unflat(acc[:] / safe_l).astype(out_dtype)
 
 
+def _live_walk(ptable, positions, kv_len, n_queries, bt):
+    """What a call has to visit, from its own scalars: (`slots` [B] int32,
+    the live slots' ids first and in slot order; how many are live; the
+    longest live walk in blocks). A slot's walk ends with its last live
+    entry (>= 0) among the blocks that hold a key some query may see, i.e.
+    before ceil(min(kv_len, positions + Q) / bt): a released slot (a table
+    of dead entries) walks nothing, and neither does the table's tail."""
+    b, nmax = ptable.shape
+    seen = jnp.minimum(kv_len, positions + n_queries)
+    j = jnp.arange(nmax, dtype=jnp.int32)[None, :]
+    visible = jnp.logical_and(ptable >= 0, j * bt < seen[:, None])
+    walk = jnp.max(jnp.where(visible, j + 1, 0), axis=1)  # [B] blocks
+    live = walk > 0
+    # slots[i] = the i-th live slot: the first slot with i + 1 live slots at
+    # or before it ([B, B] compares; no sort, nothing the pool's size touches)
+    i = jnp.arange(b, dtype=jnp.int32)
+    upto = jnp.sum(jnp.logical_and(live[None, :], i[None, :] <= i[:, None]),
+                   axis=1, dtype=jnp.int32)
+    slots = jnp.sum(upto[None, :] <= i[:, None], axis=1, dtype=jnp.int32)
+    return (jnp.minimum(slots, b - 1), jnp.sum(live, dtype=jnp.int32),
+            jnp.max(walk))
+
+
 def _paged_attention_pallas(q, k_pool, v_pool, ptable, positions, kv_len,
                             layer, k_scale, v_scale, scale, partial_out,
                             interpret, block_q):
     b, Q, h, d = q.shape
     _, _, bt, kv, _ = k_pool.shape
-    nmax = ptable.shape[1]
     n_rep = h // kv
     quantized = k_scale is not None
     qb = max(1, min(int(block_q), Q))
@@ -213,18 +256,31 @@ def _paged_attention_pallas(q, k_pool, v_pool, ptable, positions, kv_len,
         # padded queries sit past every real one; their rows mask to zeros
         # and are sliced off below
         q = jnp.pad(q, ((0, 0), (0, qp - Q), (0, 0), (0, 0)))
-    grid = (b, qp // qb, nmax)
+    # the walk's length comes from the call's scalars, not from the table's
+    # shape: live slots x q tiles x the longest live slot's blocks, traced
+    # grid bounds of ONE compiled program. (At least one step a dimension:
+    # with nothing live, the last slot's first entry is visited, found dead
+    # and finalized to what `init` holds already.)
+    slots, n_live, n_blocks = _live_walk(ptable, positions, kv_len, Q, bt)
+    grid = (jnp.maximum(n_live, 1), qp // qb, jnp.maximum(n_blocks, 1))
 
-    q_spec = pl.BlockSpec((1, qb, h, d), lambda b_, qt_, j_, *_: (b_, qt_, 0, 0))
+    def tile(i_, qt_, j_, tbl, pos, kvl, lyr, slt):
+        return slt[i_], qt_, 0, 0
+
+    q_spec = pl.BlockSpec((1, qb, h, d), tile)
     kv_spec = pl.BlockSpec(
         # the layer dim is squeezed: the body sees [1, bt, KV, D], block
         # `table[b, j]` of layer `lyr[0]`, DMA'd straight from the stacked
         # pool — no per-layer slice of it ever exists
         (None, 1, bt, kv, d),
         # dead entries (< 0) clamp to block 0: repeated indices skip the
-        # DMA, so a slot's padding tail costs one null-block fetch total
-        lambda b_, qt_, j_, tbl, pos, kvl, lyr: (
-            lyr[0], jnp.maximum(tbl[b_, j_], 0), 0, 0, 0),
+        # DMA, so a shorter slot's steps under a longer one's walk cost one
+        # null-block fetch in all. (Asking here what the body asks — is the
+        # block past kv_len or the tile's last query — saves a prefill tile
+        # at most Q / bt fetches and costs every step of every walk ~0.03 us
+        # of scalar work, +0.3 to +4.7 % a call: measured, not kept.)
+        lambda i_, qt_, j_, tbl, pos, kvl, lyr, slt: (
+            lyr[0], jnp.maximum(tbl[slt[i_], j_], 0), 0, 0, 0),
     )
     in_specs = [q_spec, kv_spec, kv_spec]
     operands = [q, k_pool, v_pool]
@@ -237,27 +293,34 @@ def _paged_attention_pallas(q, k_pool, v_pool, ptable, positions, kv_len,
         # dims whole — a (1, KV) block of the 3-D array would break the
         # sublane tiling rule
         sc_spec = pl.BlockSpec(
-            (None, 1, kv, 1), lambda b_, qt_, j_, *_: (b_, j_, 0, 0)
+            (None, 1, kv, 1),
+            lambda i_, qt_, j_, tbl, pos, kvl, lyr, slt: (slt[i_], j_, 0, 0),
         )
         idx = jnp.maximum(ptable, 0)
         in_specs += [sc_spec, sc_spec]
         operands += [k_scale[layer, idx][..., None],
                      v_scale[layer, idx][..., None]]
-    o_map = lambda b_, qt_, j_, *_: (b_, qt_, 0, 0)
     if partial_out:
         out_specs = [
-            pl.BlockSpec((1, qb, h, d), o_map),
-            pl.BlockSpec((1, qb, h, 1), o_map),
-            pl.BlockSpec((1, qb, h, 1), o_map),
+            pl.BlockSpec((1, qb, h, d), tile),
+            pl.BlockSpec((1, qb, h, 1), tile),
+            pl.BlockSpec((1, qb, h, 1), tile),
         ]
-        out_shape = [
-            jax.ShapeDtypeStruct((b, qp, h, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, qp, h, 1), jnp.float32),
-            jax.ShapeDtypeStruct((b, qp, h, 1), jnp.float32),
+        # what a slot with no live key reads: acc 0, m NEG_INF, l 0
+        init = [
+            jnp.zeros((b, qp, h, d), jnp.float32),
+            jnp.full((b, qp, h, 1), NEG_INF, jnp.float32),
+            jnp.zeros((b, qp, h, 1), jnp.float32),
         ]
     else:
-        out_specs = [pl.BlockSpec((1, qb, h, d), o_map)]
-        out_shape = [jax.ShapeDtypeStruct((b, qp, h, d), q.dtype)]
+        out_specs = [pl.BlockSpec((1, qb, h, d), tile)]
+        init = [jnp.zeros((b, qp, h, d), q.dtype)]
+    # the outputs start as `init` (aliased, left in HBM): the rows of a slot
+    # the grid never visits are exactly what a walk over no key would write
+    n_scalars = 5
+    aliases = {n_scalars + len(operands) + i: i for i in range(len(init))}
+    in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * len(init)
+    operands += init
 
     kernel = functools.partial(
         _pa_kernel, bt=bt, qb=qb, n_rep=n_rep, scale=scale,
@@ -266,7 +329,7 @@ def _paged_attention_pallas(q, k_pool, v_pool, ptable, positions, kv_len,
     outs = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=n_scalars,
             grid=grid,
             in_specs=in_specs,
             out_specs=out_specs,
@@ -276,11 +339,12 @@ def _paged_attention_pallas(q, k_pool, v_pool, ptable, positions, kv_len,
                 pltpu.VMEM((qb * h, 1), jnp.float32),
             ],
         ),
-        out_shape=out_shape,
-        # batch and q-tile iterations are independent (scratch re-inits at
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in init],
+        input_output_aliases=aliases,
+        # slot and q-tile iterations are independent (scratch re-inits at
         # j == 0); the block walk is sequential — it carries the
         # online-softmax scratch. Telling Mosaic lets it
-        # parallelize/pipeline over (b, qt) while keeping each walk ordered.
+        # parallelize/pipeline over (slot, qt) while keeping each walk ordered.
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
@@ -289,7 +353,7 @@ def _paged_attention_pallas(q, k_pool, v_pool, ptable, positions, kv_len,
         # %paged_attention.<n> whatever wrapper (closed_call, shard_map) the
         # kernel is called under: the benchmark's reduction finds it by this
         name="paged_attention",
-    )(ptable, positions, kv_len, jnp.reshape(layer, (1,)), *operands)
+    )(ptable, positions, kv_len, jnp.reshape(layer, (1,)), slots, *operands)
     if partial_out:
         acc, m, l = outs
         return acc[:, :Q], m[:, :Q, :, 0], l[:, :Q, :, 0]

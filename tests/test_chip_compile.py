@@ -12,6 +12,7 @@ library keeps it until exit, so a second test file doing the same could
 land on another xdist worker and skip in silence.
 """
 
+import dataclasses
 import functools
 import os
 import re
@@ -671,3 +672,37 @@ def test_hybrid_programs_copy_no_pool_and_no_state_leaf(one_chip, monkeypatch,
     assert any(dims == row and op.startswith("dynamic-slice") or
                (op == "fusion" and "dynamic-slice" in name and dims == row)
                for _, name, _, dims, op, _ in found) or f"[{row}]" in text
+
+
+# ---- the walk of the paged kernel is bounded by what lives (ISSUE 40) -----
+
+
+@pytest.mark.parametrize("block", ["mistral", "olmoe", "hybrid"])
+def test_decode_holds_one_paged_attention_call_a_layer_body(
+        one_chip, monkeypatch, block):
+    """The kernel's grid bounds (live slots, the longest live walk) are
+    operands of ONE custom call a layer body, named `paged_attention` — the
+    name the benchmark's readers find it by. The scalars that bound the walk
+    come from the step's own tables and positions inside the program: no
+    second kernel, nothing that scales with the pool, and the same one
+    decode program whatever the occupancy (the engine's side of that:
+    tests/test_engine_host_traffic.py,
+    test_kv_blocks_walked_is_the_live_slots_live_blocks)."""
+    if block == "hybrid":
+        cfg, pool, compiled = _compile_hybrid(one_chip, monkeypatch, "decode")
+        heads, num_blocks = pool["k"].shape[3], pool["k"].shape[1]
+    else:
+        num_blocks = 2049
+        cfg, compiled = _compile_paged_program(
+            one_chip, monkeypatch, "decode", "fused", False, num_blocks,
+            cfg={"mistral": _mistral_block, "olmoe": _olmoe_block}[block](),
+            tree="held")
+        heads = cfg.n_kv_heads
+    text = compiled.as_text()
+    calls = [name for _, name, _, _, op, rest in _HLO_INSTRUCTION.findall(text)
+             if op == "custom-call" and "tpu_custom_call" in rest
+             and not name.startswith("ragged-dot")]
+    assert len(calls) == 1 and calls[0].startswith("paged_attention"), calls
+    # whatever the kernel's wrapper computes, nothing of it is pool-sized
+    leaf = dataclasses.replace(cfg, n_kv_heads=heads)  # the pool's own heads
+    assert not _pool_sized_moves(text, leaf, num_blocks)

@@ -26,7 +26,7 @@ def _tiny():
     return cfg, init_params(jax.random.PRNGKey(0), cfg)
 
 
-def _script(cfg, params, temperature):
+def _script(cfg, params, temperature, watch=None, **engine_kw):
     """A fixed script over one small pool: two admissions, a fork, a
     chunked prefill, a preemption and its re-admission, every stream run
     to its end -> ({stream: [tokens]}, engine). Stream "b" forks off "a"
@@ -34,10 +34,13 @@ def _script(cfg, params, temperature):
     write copies the shared block); "c" prefills in 16-token chunks while the
     others decode; the pool (11 usable blocks of 8 tokens) cannot hold all
     three, so the newest stream is preempted and comes back when one of
-    the others has finished."""
+    the others has finished. `watch(eng)` sees the engine before its first
+    dispatch."""
     eng = PagedDecodeEngine(
         cfg, params, max_batch_size=3, block_tokens=8, num_blocks=12,
-        prefill_chunk_tokens=16, temperature=temperature, seed=7)
+        prefill_chunk_tokens=16, temperature=temperature, seed=7, **engine_kw)
+    if watch is not None:
+        watch(eng)
     rng = np.random.default_rng(36)
     prompts = {"a": rng.integers(1, cfg.vocab_size, size=12),
                "c": rng.integers(1, cfg.vocab_size, size=40)}
@@ -163,6 +166,19 @@ def _watch(eng):
     return seen
 
 
+def _record_uploads(eng):
+    """-> the list every later decode step appends its uploaded array to."""
+    uploads, decode = [], eng._decode_step
+
+    def call(params, pool, inputs, key):
+        uploads.append(np.array(inputs))
+        return decode(params, pool, inputs, key)
+
+    call._cache_size = decode._cache_size
+    eng._decode_step = call
+    return uploads
+
+
 @pytest.mark.parametrize("kind", [*_KINDS, "logprobs"])
 def test_one_upload_one_program_one_fetch(kind):
     """A decode step and an admission at temperature 0: each launch takes
@@ -278,13 +294,7 @@ def test_a_finished_stream_leaves_nothing_in_its_row(kind, how):
         eng.release(0)
     else:
         eng._preempt(0)
-    uploads, decode = [], eng._decode_step
-
-    def call(params, pool, inputs, key):
-        uploads.append(np.array(inputs))
-        return decode(params, pool, inputs, key)
-
-    eng._decode_step = call
+    uploads = _record_uploads(eng)
     eng.step([2])
     (up,) = uploads
     assert not up[0].any() and not up[1].any()  # finished; never used
@@ -363,3 +373,41 @@ def test_the_script_gives_the_parents_tokens(temperature):
     assert out["a"] == _greedy_rollout(cfg, params, a, 30)
     assert out["b"] == out["a"][6:]  # the fork carries on where "a" was
     assert out["c"] == _greedy_rollout(cfg, params, c, 20)
+
+
+@pytest.mark.parametrize("temperature", [0.0, 1.0], ids=["greedy", "sampled"])
+def test_kv_blocks_walked_is_the_live_slots_live_blocks(temperature):
+    """What a paged kernel has to visit in a decode step, beside what it
+    could: `kv_blocks_walked` = the sum over the step's slots of
+    ceil((pos + 1) / block_tokens), read here off the positions the step
+    UPLOADED, and `kv_table_blocks` = B x Nmax; both on the `engine.decode`
+    span and summed in stats(). Counting them changes no token and adds no
+    program: the script compiles what it compiled at the parent."""
+    cfg, params = _tiny()
+    tel = telemetry.ServeTelemetry(recorder_capacity=1024)
+    uploads = []
+    out, eng = _script(
+        cfg, params, temperature, telemetry=tel,
+        watch=lambda eng: uploads.append(_record_uploads(eng)))
+    (uploads,) = uploads
+    if temperature:
+        assert out == _PARENT_SAMPLED
+    st = eng.stats()
+    decodes = [e["args"] for e in tel.recorder.snapshot()
+               if e["name"] == "decode"]
+    assert len(decodes) == len(uploads) == st["decode_steps"] > 40
+    bt, nmax, B = eng.block_tokens, eng.blocks_per_slot, eng.max_batch_size
+    for up, args in zip(uploads, decodes):
+        positions = up[:, nmax + 1]
+        assert args["kv_blocks_walked"] == sum(
+            -(-(int(positions[s]) + 1) // bt) for s in args["slots"])
+        assert args["kv_tokens"] == sum(
+            int(positions[s]) + 1 for s in args["slots"])
+        assert args["kv_table_blocks"] == B * nmax
+    assert st["kv_blocks_walked"] == sum(a["kv_blocks_walked"] for a in decodes)
+    assert st["kv_table_blocks"] == B * nmax * len(decodes)
+    assert 0 < st["kv_blocks_walked"] < st["kv_table_blocks"]
+    # one decode program whatever the occupancy, the parent's prefill shapes
+    assert eng._decode_step._cache_size() == 1
+    assert sorted(eng.prefill_shapes) == [(0, 2), (2, 2), (4, 2), (8, 2)]
+    assert len(eng._prefill.programs) == 4

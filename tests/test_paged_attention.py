@@ -397,6 +397,88 @@ def test_stacked_pool_layer_equals_per_layer_call(impl, kw, int8, partial_out, Q
             np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
+# ---- the walk follows what lives (ISSUE 40) -------------------------------
+#
+# The kernel's grid is bounded by the call's own scalars: live slots x the
+# longest live slot's blocks. Each case is one occupancy; `lens` is the keys
+# a slot holds once its queries are written (0 = a released slot: position
+# 0 and a table of null entries).
+
+
+def _mixed_lens():
+    lens = np.random.default_rng(40).integers(1, 4 * 8 + 1, size=32)
+    lens[[0, 3, 4, 11, 17, 30, 31]] = 0
+    return [int(n) for n in lens]
+
+
+_OCCUPANCIES = {
+    "all-dead": dict(lens=[0, 0, 0]),
+    "one-token": dict(lens=[0, 1, 0]),
+    # 8 and 16 end exactly on a block boundary, 9 and 17 one past it
+    "block-boundary": dict(lens=[8, 9, 0, 16, 17]),
+    "32-mixed": dict(lens=_mixed_lens()),
+    # signed tables, an out-of-shard block INSIDE two live ranges
+    "hole-partial": dict(lens=[20, 0, 30], holes=[(0, 1), (2, 0), (2, 2)]),
+    "hole-only": dict(lens=[7, 0, 12], holes=[(0, 0)]),
+    # q = k + 1, the window ends strictly before the first query
+    "verify": dict(lens=[11, 0, 27, 3], Q=3, verify=True),
+    # 6 queries in tiles of 4 from position 5: tile 0 straddles blocks 0/1
+    "prefill-tile": dict(lens=[11, 0], Q=6, block_q=4),
+    "int8": dict(lens=[9, 0, 25], int8=True),
+    "mha": dict(lens=[9, 0, 25, 32], h=2, kv=2),
+    "gqa4": dict(lens=[9, 0, 25, 32], h=8, kv=2),
+}
+
+
+@pytest.mark.parametrize("case", list(_OCCUPANCIES))
+def test_kernel_walks_what_lives(case):
+    spec = dict(_OCCUPANCIES[case])
+    lens = np.asarray(spec.pop("lens"))
+    Q, h, kv = spec.pop("Q", 1), spec.pop("h", 4), spec.pop("kv", 2)
+    holes, verify = spec.pop("holes", None), spec.pop("verify", False)
+    int8 = spec.pop("int8", False)
+    d, bt, n_max, n_pool = 16, 8, 4, 40
+    b = len(lens)
+    rng = np.random.default_rng(b + Q)
+    q = jnp.asarray(rng.normal(size=(b, Q, h, d)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(n_pool, bt, kv, d)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(n_pool, bt, kv, d)), jnp.float32)
+    tables = np.zeros((b, n_max), np.int32)
+    for s, n in enumerate(lens):
+        nb = -(-int(n) // bt)
+        tables[s, :nb] = rng.integers(1, n_pool, size=nb)
+    positions = np.maximum(lens - Q, 0).astype(np.int32)
+    kw = dict(spec)
+    dead = lens == 0
+    if holes is not None:
+        tables = np.where(tables > 0, tables, -1)
+        for s, j in holes:
+            tables[s, j] = -1
+        kw.update(signed_tables=True, partial_out=True)
+        dead |= (tables < 0).all(axis=1)
+    if verify:
+        kw["kv_len"] = jnp.asarray(positions)
+        dead |= positions == 0
+    if int8:
+        (kp, ks), (vp, vs) = _quantize_pool(kp), _quantize_pool(vp)
+        kw.update(k_scale=ks, v_scale=vs)
+    args = (q, kp, vp, jnp.asarray(tables), jnp.asarray(positions))
+    want = paged_attention(*args, impl="xla", **kw)
+    got = paged_attention(*args, impl="kernel", interpret=True, **kw)
+    assert pa_mod._LAST_IMPL == "kernel"
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(w), atol=2e-5, rtol=2e-5)
+    # a dead slot's rows are exactly zero: the output, or with partial_out
+    # the accumulator and the denominator (its running max stays NEG_INF)
+    out, *stats = jax.tree.leaves(got)
+    assert dead.any() and not np.asarray(out)[dead].any()
+    if stats:
+        m, l = (np.asarray(x)[dead] for x in stats)
+        assert not l.any() and (m == pa_mod.NEG_INF).all()
+    assert np.asarray(out)[~dead].any() or dead.all()
+
+
 def test_validation_errors():
     q, kp, vp, tables, positions = _setup()
     with pytest.raises(ValueError, match="layer"):

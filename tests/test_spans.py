@@ -203,6 +203,10 @@ def test_batcher_and_engine_spans_nest(tmp_path):
             live[rid] = [by_rid[rid][0], by_rid[rid][1] - 1]
         assert dec[2]["slots"] == it[2]["slots"] == len(live)
         assert dec[2]["kv_tokens"] == sum(p + 1 for p, _ in live.values())
+        # ... in that many of its 8-token blocks, of a [2, Nmax] table
+        assert dec[2]["kv_blocks_walked"] == sum(
+            p // 8 + 1 for p, _ in live.values())
+        assert dec[2]["kv_table_blocks"] == 2 * eng.blocks_per_slot
         for rid in list(live):
             live[rid][0] += 1
             live[rid][1] -= 1
