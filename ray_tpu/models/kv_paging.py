@@ -803,6 +803,7 @@ class PagedDecodeEngine:
         self.fetches = 0
         # sparse-expert models: see the decode step
         self.moe_pairs = 0
+        self.moe_pairs_held = 0  # of those, the pairs on experts held here
         self.moe_hottest = 0
         self.moe_touched = 0
         # summed over the decode steps: the live slots' live blocks (what a
@@ -1514,7 +1515,8 @@ class PagedDecodeEngine:
             # with experts their two counts, in logprob mode the logprobs
             toks, moe_load, lps = split_host_row(
                 self._fetch(out), self.max_batch_size,
-                experts=bool(self.cfg.n_experts), logprobs=self.logprobs)
+                experts=bool(self.cfg.n_experts), logprobs=self.logprobs,
+                share=self.cfg.expert_share)
             step_span.set(uploads=self.uploads - uploads,
                           fetches=self.fetches - fetches)
             if moe_load is not None:
@@ -1522,13 +1524,17 @@ class PagedDecodeEngine:
                 # pairs, the load of its fullest expert and the experts
                 # with any pair (the groups the grouped matmul reads), each
                 # summed over the layers (pairs * n_experts / hottest = 1:
-                # even)
+                # even). Where the replica holds a share of the routed
+                # experts, hottest and touched are over the held ones and
+                # `moe_pairs_held` are the pairs it computes
                 pairs = (len(surviving) * self.cfg.top_k
                          * self.cfg.n_expert_layers)
-                hottest, touched = map(int, moe_load)
+                hottest, touched, *held = map(int, moe_load)
+                held = held[0] if held else pairs
                 step_span.set(moe_pairs=pairs, moe_hottest=hottest,
-                              moe_touched=touched)
+                              moe_touched=touched, moe_pairs_held=held)
                 self.moe_pairs += pairs
+                self.moe_pairs_held += held
                 self.moe_hottest += hottest
                 self.moe_touched += touched
         with span("engine.bookkeep"):
@@ -2028,6 +2034,7 @@ class PagedDecodeEngine:
             # pairs, the summed load of each step's fullest expert, and
             # the (layer, expert) groups with a pair: what the steps read
             "moe_pairs": self.moe_pairs,
+            "moe_pairs_held": self.moe_pairs_held,
             "moe_hottest": self.moe_hottest,
             "moe_touched": self.moe_touched,
             "kv_blocks_walked": self.kv_blocks_walked,
@@ -2048,6 +2055,9 @@ class PagedDecodeEngine:
                 int(leaf.nbytes) for leaf in jax.tree_util.tree_leaves(self.params)
             ),
             "param_dtype": np.dtype(self.params["embed"].dtype).name,
+            # a layer's experts held here, of those its router scores
+            "experts_held": self.cfg.n_experts,
+            "experts_routed": self.cfg.router_width,
             "kv_block_bytes": self.kv_block_bytes,
             # what one resident token costs over all layers, by the pool's
             # own leaves (a latent pool: one row a layer)

@@ -176,6 +176,19 @@ class TransformerConfig:
     # moe_renormalize) times moe_route_scale
     moe_scoring: str = "softmax"
     moe_route_scale: float = 1.0
+    # an expert layer's SHARE of an expert-parallel deployment: the router
+    # scores n_routed_experts (0: n_experts, every expert is held) and
+    # chooses over all of them; this replica holds the n_experts from
+    # expert `expert_offset` on and computes the chosen pairs whose expert
+    # it holds — what the absent experts would add is another chip's part
+    n_routed_experts: int = 0
+    expert_offset: int = 0
+    # group-limited choice (DeepSeek-V2 `group_limited_greedy`, softmax
+    # scores): the routed experts lie in moe_n_group groups of consecutive
+    # ones, a group scores its best expert, and the top_k are taken among
+    # the moe_topk_group best groups' experts. 1 group: the plain top_k
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
     # experts every token goes through, beside the routed ones: ONE gated
     # MLP of width n_shared_experts * d_ff
     n_shared_experts: int = 0
@@ -248,6 +261,37 @@ class TransformerConfig:
                 f"moe_scoring must be 'softmax' or 'sigmoid', "
                 f"got {self.moe_scoring!r}"
             )
+        R = self.router_width
+        if self.n_routed_experts and not self.n_experts:
+            raise ValueError("n_routed_experts needs n_experts, the share held")
+        if self.expert_share:
+            if R % self.n_experts or self.expert_offset % self.n_experts \
+                    or not 0 <= self.expert_offset < R:
+                raise ValueError(
+                    f"a share of {self.n_experts} experts from expert "
+                    f"{self.expert_offset} is not one of "
+                    f"n_routed_experts={R} cut into equal parts")
+            if self.moe_impl == "dense" or self.moe_capacity_factor is not None:
+                raise NotImplementedError(
+                    "an expert layer that holds a share of its experts "
+                    "(n_routed_experts > n_experts) runs dropless "
+                    "(moe_capacity_factor=None) only: the capacity buffer "
+                    "and the dense oracle over a share are not written")
+        if self.moe_n_group > 1:
+            if R % self.moe_n_group or not (
+                    0 < self.moe_topk_group <= self.moe_n_group):
+                raise ValueError(
+                    f"moe_n_group {self.moe_n_group} / moe_topk_group "
+                    f"{self.moe_topk_group} do not cut {R} routed experts "
+                    "into equal groups of which some are kept")
+            if self.moe_scoring != "softmax":
+                raise NotImplementedError(
+                    "group-limited routing over sigmoid scores is not "
+                    "written (moe_n_group > 1 needs moe_scoring='softmax')")
+            if self.top_k > self.moe_topk_group * (R // self.moe_n_group):
+                raise ValueError(
+                    f"top_k {self.top_k} exceeds the experts of "
+                    f"{self.moe_topk_group} groups")
         if not 0 <= self.first_k_dense <= self.n_layers:
             raise ValueError(
                 f"first_k_dense {self.first_k_dense} outside 0..n_layers "
@@ -289,6 +333,16 @@ class TransformerConfig:
         strided walk and the kernel call a whole-pool relayout); 640 keeps
         rows contiguous. The tail columns stay zero."""
         return -(-self.latent_width // 128) * 128
+
+    @property
+    def router_width(self) -> int:
+        """Experts the router scores: all of the deployment's."""
+        return self.n_routed_experts or self.n_experts
+
+    @property
+    def expert_share(self) -> bool:
+        """Whether this replica holds only a share of the routed experts."""
+        return self.router_width != self.n_experts
 
     @property
     def n_expert_layers(self) -> int:
@@ -345,7 +399,7 @@ class TransformerConfig:
         if self.qk_norm:
             lp += self.d_head * (self.n_heads + self.n_kv_heads)
         if self.n_experts:
-            lp += self.d_model * self.n_experts  # router
+            lp += self.d_model * self.router_width  # router
             lp += self.n_experts * 3 * self.d_model * self.d_ff
         else:
             lp += (2 if self.mlp_variant == "gelu" else 3) * self.d_model * self.d_ff
@@ -452,7 +506,7 @@ def _extra_leaves(cfg: TransformerConfig, stack: str):
         ]
     if stack == "layers" and cfg.n_experts:
         if cfg.moe_scoring == "sigmoid":
-            out.append(("router_bias", (cfg.n_experts,), ("expert",), "bias"))
+            out.append(("router_bias", (cfg.router_width,), ("expert",), "bias"))
         if cfg.n_shared_experts:
             Fs = cfg.n_shared_experts * cfg.d_ff
             out += [
@@ -654,9 +708,10 @@ def init_params(rng: jax.Array, cfg: TransformerConfig, *,
     if cfg.qk_norm:
         layer.update(q_norm=norm_init(L, H, D), k_norm=norm_init(L, KV, D))
     if cfg.n_experts:
-        X = cfg.n_experts
+        X = cfg.n_experts  # held: the stacks are this replica's share
         layer.update(
-            router=dense_init("router", next(keys), (L, E, X), E),
+            router=dense_init(
+                "router", next(keys), (L, E, cfg.router_width), E),
             w_gate=dense_init("w_gate", next(keys), (L, X, E, F), E),
             w_up=dense_init("w_up", next(keys), (L, X, E, F), E),
             w_down=dense_init("w_down", next(keys), (L, X, F, E), F),
@@ -705,10 +760,15 @@ def init_params(rng: jax.Array, cfg: TransformerConfig, *,
 
 
 def _moe_route(x, lp, cfg: TransformerConfig):
-    """Router of one expert layer. x [N, E] -> (w [N, k] f32, idx [N, k]).
+    """Router of one expert layer. x [N, E] -> (w [N, k] f32, idx [N, k]),
+    idx over ALL `cfg.router_width` routed experts, held here or not.
 
-    "softmax": softmax in f32 over ALL experts, then the k largest; the
-    weights are divided by their sum only under `cfg.moe_renormalize`.
+    "softmax": softmax in f32 over ALL experts, then the k largest — under
+    `moe_n_group` > 1 among the experts of the `moe_topk_group` groups whose
+    best expert scores highest (the other groups' scores are zeroed first:
+    DeepSeek-V2's `group_limited_greedy`); the weights are divided by their
+    sum only under `cfg.moe_renormalize`, and multiplied by
+    `moe_route_scale`.
     "sigmoid" (DeepSeek-V3's `noaux_tc` with one group): a score per
     expert, the k largest of score + `router_bias` — the bias steers the
     CHOICE only — and the weights are the chosen experts' unbiased scores,
@@ -728,9 +788,20 @@ def _moe_route(x, lp, cfg: TransformerConfig):
                 w = w / (w.sum(-1, keepdims=True) + 1e-20)
             return w * cfg.moe_route_scale, idx
         probs = jax.nn.softmax(gate_logits, axis=-1)
+        if cfg.moe_n_group > 1:
+            with jax.named_scope("moe.groups"):
+                G = cfg.moe_n_group
+                best = jnp.max(probs.reshape(-1, G, probs.shape[-1] // G), -1)
+                _, kept = lax.top_k(best, cfg.moe_topk_group)      # [N, g]
+                keep = jnp.sum(jax.nn.one_hot(kept, G, dtype=jnp.int32), 1)
+                probs = jnp.where(
+                    jnp.repeat(keep, probs.shape[-1] // G, axis=-1) > 0,
+                    probs, 0.0)
         w, idx = lax.top_k(probs, cfg.top_k)
         if cfg.moe_renormalize:
             w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)
+        if cfg.moe_route_scale != 1.0:
+            w = w * cfg.moe_route_scale
     return w, idx
 
 
@@ -811,10 +882,21 @@ def _moe_dropless(x, w, idx, lp, cfg: TransformerConfig, layer=None):
     A stack is therefore read in place — viewed as L*X groups (merging the
     two leading dims moves nothing), with the router's sizes written at
     this layer's X groups and zero rows for every other layer's. Same
-    rows, same experts, same three matmuls."""
+    rows, same experts, same three matmuls.
+
+    Under `cfg.expert_share` idx names experts of the whole deployment and
+    the leaves are the X held ones: only the pairs whose expert is held get
+    a group, they sort to the front, and the rows behind the groups' sum —
+    the pairs another chip computes — are not multiplied."""
     N, E = x.shape
     X, k = cfg.n_experts, cfg.top_k
     flat_e = idx.reshape(-1)                       # [N*k] destination expert
+    if cfg.expert_share:
+        # the held experts' pairs first, by expert; every other pair sorts
+        # behind them under the key X, which no group counts
+        local = flat_e - cfg.expert_offset
+        held = jnp.logical_and(local >= 0, local < X)
+        flat_e = jnp.where(held, local, X)
     order = jnp.argsort(flat_e, stable=True)       # pairs grouped by expert
     sizes = jnp.sum(
         jax.nn.one_hot(flat_e, X, dtype=jnp.int32), axis=0
@@ -840,6 +922,10 @@ def _moe_dropless(x, w, idx, lp, cfg: TransformerConfig, layer=None):
     inv = jnp.zeros((N * k,), jnp.int32).at[order].set(
         jnp.arange(N * k, dtype=jnp.int32))
     y = y[inv].reshape(N, k, E).astype(jnp.float32)
+    if cfg.expert_share:
+        # a row past the groups' sum is not multiplied, and what the kernel
+        # leaves there is not specified: such a pair adds nothing
+        y = jnp.where(held.reshape(N, k, 1), y, 0.0)
     return jnp.sum(y * w[..., None], axis=1).astype(x.dtype)
 
 
@@ -869,14 +955,20 @@ def _moe(h, lp, cfg: TransformerConfig, constrain_fn, layer=None):
     return out.reshape(B, S, E), idx
 
 
-def _expert_load(idx, live, n_experts: int):
-    """What the tokens marked `live` ([N] bool) ask of one expert layer,
-    idx [N, k] -> int32 [2]: the load of the fullest expert (the most
-    (token, expert) pairs any one expert got) and the number of experts
-    with at least one pair — the groups the grouped matmul reads."""
-    hits = jax.nn.one_hot(idx, n_experts, dtype=jnp.int32) * live[:, None, None]
-    load = jnp.sum(hits, axis=(0, 1))
-    return jnp.stack([jnp.max(load), jnp.sum(load > 0, dtype=jnp.int32)])
+def _expert_load(idx, live, cfg: TransformerConfig):
+    """What the tokens marked `live` ([N] bool) ask of one expert layer's
+    HELD experts, idx [N, k] -> int32 [2]: the load of the fullest expert
+    (the most (token, expert) pairs any one expert got) and the number of
+    experts with at least one pair — the groups the grouped matmul reads.
+    Under `cfg.expert_share` int32 [3]: behind them the pairs on held
+    experts, all the layer computes of the live tokens' k each."""
+    hits = jax.nn.one_hot(idx - cfg.expert_offset if cfg.expert_share else idx,
+                          cfg.n_experts, dtype=jnp.int32)
+    load = jnp.sum(hits * live[:, None, None], axis=(0, 1))
+    out = [jnp.max(load), jnp.sum(load > 0, dtype=jnp.int32)]
+    if cfg.expert_share:
+        out.append(jnp.sum(load))
+    return jnp.stack(out)
 
 
 _MATMUL_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "router",
@@ -1684,12 +1776,14 @@ def pack_prefill_inputs(table, tokens, length, ctx_len, row=0):
         np.asarray(table, np.int32)])
 
 
-def split_host_row(out, n: int, experts: bool = False, logprobs: bool = False):
+def split_host_row(out, n: int, experts: bool = False, logprobs: bool = False,
+                   share: bool = False):
     """The vector a paged program hands back, on the host (NumPy) ->
     (tokens [n], moe_load [2] or None, logprobs float32 [n] or None). The
-    programs lay it out as tokens | expert counts (decode, with experts) |
-    the tokens' log-probabilities bit-cast to int32 (`logprobs`)."""
-    at = n + (2 if experts else 0)
+    programs lay it out as tokens | expert counts (decode, with experts;
+    [3] where the layer holds a `share` of its experts) | the tokens'
+    log-probabilities bit-cast to int32 (`logprobs`)."""
+    at = n + (2 + bool(share) if experts else 0)
     return (out[:n], out[n:at] if experts else None,
             out[at:at + n].view(np.float32) if logprobs else None)
 
@@ -1928,7 +2022,9 @@ def make_paged_decoder(
       without experts; with them int32 [2], both summed over the expert
       layers: `moe_hottest`, the load of the step's fullest expert (pairs
       routed to it by the live slots), and `moe_touched`, the experts with
-      at least one such pair — the groups whose weights the step reads.
+      at least one such pair — the groups whose weights the step reads;
+      both over the HELD experts, and where those are a share of the routed
+      ones (`cfg.expert_share`) a third, the pairs on held experts.
 
     paged_verify_step(params, pool, tables[B,Nmax], tokens[B,K1],
                       positions[B], draft_len[B], write_phys[B,K1],
@@ -2486,7 +2582,7 @@ def make_paged_decoder(
         def load(idx):
             # what the live slots ask of the layer's experts (an inactive
             # slot writes to the null block, 0)
-            return _expert_load(idx, write_phys > 0, cfg.n_experts)
+            return _expert_load(idx, write_phys > 0, cfg)
 
         if hybrid:
             # the rows of the state pool this step advances: the slots that

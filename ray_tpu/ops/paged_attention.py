@@ -586,7 +586,30 @@ def _mla_kernel(tables_ref, pos_ref, kvlen_ref, layer_ref, q_ref, *rest,
 
 
 def _mla_attention_pallas(q, pool, ptable, positions, kv_len, layer, rank,
-                          scale, interpret, block_q, blocks_per_step):
+                          scale, interpret, block_q, blocks_per_step,
+                          block_rows):
+    """The latent walk as a Pallas call: grid (slot, query tile, step of
+    `blocks_per_step` table entries), `_mla_kernel` the body.
+
+    How a tile is sized. Every head of a query scores the same cached row,
+    so a tile's matmul rows are queries x HEADS, and what the body keeps in
+    VMEM goes by rows, not by queries: the f32 accumulator [rows, rank] and
+    score tile [rows, span] (span = blocks_per_step x block_tokens = 512),
+    2 KB a row each and a third such tile for the probabilities, beside the
+    query tile [rows, W] bf16 and the output tile [rows, rank] bf16, both
+    double-buffered by the pipeline (2 x (1,280 + 1,024) B a row), and the
+    step's pool blocks (8 x 64 x 640 x 2 B, twice: 1.3 MB). A tile is
+    therefore min(block_q, block_rows // heads) queries:
+
+      32 heads   16 queries = 512 rows: 3 x 1 MB f32 + 2.3 MB of q and
+                 output + 1.3 MB of blocks = 6.6 MB (the tile this kernel
+                 was written and measured with)
+      128 heads  16 queries would be 2,048 rows: 3 x 4 MB + 9.2 MB + 1.3 MB
+                 = 22.5 MB, over the 16 MB of scoped VMEM a kernel gets on a
+                 v5e; 4 queries = 512 rows is the same 6.6 MB
+
+    and in decode (one query) a tile is `heads` rows: 128 heads fill the
+    MXU's 128 rows with one slot's query."""
     b, Q, h, w = q.shape
     n_layers, n_blocks, bt = pool.shape[:3]
     # [L, N, bt, 1, W] -> [L, N, bt, W]: the unit head dim would otherwise
@@ -597,7 +620,7 @@ def _mla_attention_pallas(q, pool, ptable, positions, kv_len, layer, rank,
     if nmax != ptable.shape[1]:
         ptable = jnp.pad(ptable, ((0, 0), (0, nmax - ptable.shape[1])),
                          constant_values=-1)
-    qb = max(1, min(int(block_q), Q))
+    qb = max(1, min(int(block_q), int(block_rows) // h, Q))
     qp = -(-Q // qb) * qb
     if qp != Q:
         q = jnp.pad(q, ((0, 0), (0, qp - Q), (0, 0), (0, 0)))
@@ -667,8 +690,9 @@ def mla_paged_attention(
     kv_len: Optional[jnp.ndarray] = None,
     impl: str = "auto",    # auto | kernel | xla
     interpret: Optional[bool] = None,
-    block_q: int = 16,       # query rows a tile (the per-head op's default)
+    block_q: int = 16,       # queries a tile at most (the per-head op's default)
     blocks_per_step: int = 8,  # pool blocks in flight a grid step
+    block_rows: int = 512,   # queries x heads a tile at most: what VMEM holds
 ) -> jnp.ndarray:
     """Absorbed multi-query attention over a latent (MLA) pool: every head
     of query i of slot b scores ALL W columns of each cached row at key
@@ -700,7 +724,7 @@ def mla_paged_attention(
             interpret = jax.default_backend() != "tpu"
         return _mla_attention_pallas(
             q, pool, ptable, positions, kv_len, layer, rank, float(scale),
-            interpret, block_q, blocks_per_step,
+            interpret, block_q, blocks_per_step, block_rows,
         )
     out = _paged_attention_xla(
         q, pool, pool, ptable, positions, kv_len, layer, None, None,

@@ -62,3 +62,16 @@ del test_the_cell_is_declared_and_only_appended  # noqa: F821
 # (test_request_clock_readers.py).
 del test_benchmark_json_is_the_parents_plus_appended_entries  # noqa: F821
 del test_the_hybrid_cell_is_declared_with_its_metrics  # noqa: F821
+
+# pin a LAST place that PR 42's appended configuration, cell and thirteen
+# readers take: the exact list of configurations and cells and
+# `names == accepted + the five` (the first), "behind Xing4.0's cell only the
+# hybrid cell" (`serve_tokens_per_s` now ends with PR 42's cell: the second),
+# and `per_layer[54:]` read as "PR 39's five" (the third). The files are the
+# benchmark's own. Every other assertion of the three is held by
+# test_benchmark_json_is_the_parents_plus_appended_entries_pr42,
+# test_the_xing4_cell_is_declared_as_pr33_left_it_pr42 and
+# test_the_hybrid_cell_keeps_its_metrics_pr42 (test_deepseek_v2_block.py).
+del test_benchmark_json_is_the_parents_plus_the_five_of_pr39  # noqa: F821
+del test_the_xing4_cell_is_declared_as_pr33_left_it  # noqa: F821
+del test_the_hybrid_cell_keeps_its_metrics_and_gains_the_five_of_pr39  # noqa: F821

@@ -401,11 +401,15 @@ def _xing4_block(n_layers=2):
     )
 
 
-@pytest.mark.parametrize("batch,q_len,table", [(32, 1, 288), (1, 256, 260)],
-                         ids=["decode", "prefill"])
-def test_mla_kernel_compiles(one_chip, batch, q_len, table):
-    """The latent kernel at the benchmark cell's shapes: 32 heads over
-    640-wide rows, 8 blocks a grid step, an 18,432-token table."""
+@pytest.mark.parametrize("batch,q_len,table,heads", [
+    (32, 1, 288, 32), (1, 256, 260, 32), (64, 1, 80, 128), (1, 2048, 80, 128)],
+    ids=["decode", "prefill", "decode-128-heads", "prefill-128-heads"])
+def test_mla_kernel_compiles(one_chip, batch, q_len, table, heads):
+    """The latent kernel at the benchmark cells' shapes: 32 heads over
+    640-wide rows, 8 blocks a grid step, an 18,432-token table (Xing4.0);
+    128 heads, 64 slots and a 5,120-token table (DeepSeek-V2), where a
+    prefill tile of 16 queries x 128 heads would pass the kernel's VMEM
+    and `block_rows` cuts it to 4 queries."""
     import importlib
 
     pa = importlib.import_module("ray_tpu.ops.paged_attention")
@@ -419,13 +423,89 @@ def test_mla_kernel_compiles(one_chip, batch, q_len, table):
             scale=0.14, impl="kernel", interpret=False)
 
     compiled = _compile(
-        fn, sds((batch, q_len, 32, 640), jnp.bfloat16),
+        fn, sds((batch, q_len, heads, 640), jnp.bfloat16),
         sds((2, 1036, BLOCK_TOKENS, 1, 640), jnp.bfloat16),
         sds((batch, table), jnp.int32), sds((batch,), jnp.int32))
     text = compiled.as_text()
     assert "mla_paged_attention" in text and "tpu_custom_call" in text
     # the [L, N, bt, 1, W] -> [L, N, bt, W] view is free: no copy of the pool
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_mla_prefill_tile_is_sized_in_rows(one_chip):
+    """16 queries x 128 heads a tile is refused by the chip's compiler (the
+    f32 accumulator and score tile alone are 2 x 4 MB, twice with the
+    pipeline's q and output tiles): what `block_rows` is for."""
+    import importlib
+
+    pa = importlib.import_module("ray_tpu.ops.paged_attention")
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fn(q, pool, tables, positions):
+        return pa.mla_paged_attention(
+            q, pool, tables, positions, layer=jnp.int32(1), rank=512,
+            scale=0.14, impl="kernel", interpret=False, block_rows=16 * 128)
+
+    with pytest.raises(Exception, match="(?i)vmem|memory|exceed"):
+        _compile(fn, sds((1, 256, 128, 640), jnp.bfloat16),
+                 sds((2, 1036, BLOCK_TOKENS, 1, 640), jnp.bfloat16),
+                 sds((1, 80), jnp.int32), sds((1,), jnp.int32))
+
+
+def _deepseek_v2_share(n_layers=2):
+    """DeepSeek-V2's layers as benchmark/blocks/deepseek_v2.py maps the
+    chip's share: 40 of 160 experts under the 160-wide group-limited
+    router, 128 heads, a plain residual."""
+    from ray_tpu.models.transformer import TransformerConfig
+
+    return TransformerConfig(
+        vocab_size=25600, d_model=5120, n_layers=n_layers, n_heads=128,
+        n_kv_heads=128, d_head=192, d_ff=1536, max_seq_len=5120,
+        q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, rope_factor=40.0,
+        rope_original_max=4096, rope_mscale=0.707, rope_mscale_all_dim=0.707,
+        first_k_dense=1, d_ff_dense=12288, n_experts=40, n_routed_experts=160,
+        expert_offset=0, top_k=6, moe_n_group=8, moe_topk_group=3,
+        moe_renormalize=False, moe_route_scale=16.0, n_shared_experts=2,
+        moe_capacity_factor=None,
+    )
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_share_programs_group_the_held_experts_alone(one_chip, monkeypatch,
+                                                     program):
+    """Decode and prefill of one chip's share: three grouped matmuls a
+    layer whose weight operand is the HELD experts' stack (40 groups a
+    layer under a 160-wide router), read in place; every routed pair has a
+    row (N x 6 of d_model) and the group sizes decide which are multiplied;
+    the latent kernel at 128 heads is in the program and the pool is not
+    copied."""
+    cfg, compiled = _compile_paged_program(
+        one_chip, monkeypatch, program, "fused", False, 2049,
+        cfg=_deepseek_v2_share(3), tree="held")
+    text = compiled.as_text()
+    assert "mla_paged_attention" in text
+    insts = _HLO_INSTRUCTION.findall(text)
+    calls = [(dims, rest) for _, _, _, dims, op, rest in insts
+             if op == "custom-call" and "ragged_dot_tiling" in rest]
+    assert len(calls) == 3
+    n = (32 if program == "decode" else 128) * cfg.top_k
+    assert sorted(dims for dims, _ in calls) == sorted(
+        [f"{n},{cfg.d_ff}", f"{n},{cfg.d_ff}", f"{n},{cfg.d_model}"])
+    defs = {name: (op, rest) for _, name, _, _, op, rest in insts}
+    for _, rest in calls:
+        operands = re.findall(r"%([^\s,)]+)", rest.split(")")[0])
+        assert _operand_source(defs, operands[-1]) in (
+            "parameter", "get-tuple-element"), rest[:200]
+    shapes = set(re.findall(r"= \w+\[([\d,]+)\]", text))
+    # the router is as wide as the deployment, the stacks as the share
+    assert any(d.endswith(f",{cfg.router_width}") for d in shapes)
+    assert not any(d.startswith(f"{cfg.router_width},{cfg.d_model},")
+                   for d in shapes)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cfg.n_layers * 2049 * BLOCK_TOKENS * 640 * 2
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])

@@ -640,6 +640,112 @@ def test_latent_decode_span_and_stats_keep_their_attributes(tmp_path):
     assert stats["attention_kernel"] == "gather"  # "pallas" only on a TPU
 
 
+# ---- one chip's share of an expert-parallel layer, group-limited routing ----
+
+
+def _share_engine(**kw):
+    """A small model of the DeepSeek-V2 layer as one chip's share: latent
+    attention under a plain residual, a dense layer before softmax-routed
+    experts in 4 groups of which 2 are kept, 4 of 16 experts held (rank 1),
+    two shared experts."""
+    from ray_tpu.models.transformer import TransformerConfig
+
+    cfg = TransformerConfig(
+        vocab_size=128, d_model=64, n_layers=3, n_heads=8, n_kv_heads=8,
+        d_head=24, d_ff=32, max_seq_len=128, n_experts=4, top_k=3,
+        n_routed_experts=16, expert_offset=4, moe_n_group=4, moe_topk_group=2,
+        moe_renormalize=False, moe_route_scale=16.0, moe_capacity_factor=None,
+        q_lora_rank=48, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, first_k_dense=1, d_ff_dense=96,
+        n_shared_experts=2)
+    return cfg, PagedDecodeEngine(
+        cfg, max_batch_size=4, seed=0, block_tokens=8, prefix_cache=False,
+        prefill_buckets=(16,), **kw)
+
+
+@pytest.mark.parametrize("which", ["paged_prefill", "paged_decode"])
+def test_group_limited_layer_lowers_under_its_scopes(which):
+    """`moe.groups` NESTED under `moe.route` (so `moe_device_ms` counts the
+    group selection with the router) and `moe.shared` under `moe.experts`;
+    a layer with one group has no such scope. The programs keep their
+    names."""
+    _, eng = _share_engine(attention_impl="fused")
+    if which == "paged_prefill":
+        eng.admit(0, {"tokens": np.arange(1, 10), "max_new_tokens": 2})
+    fn, args = _program_args(eng, which)
+    text = fn.lower(*args).as_text(debug_info=True)
+    assert f"module @jit_{which} " in text
+    for scope in ("moe.route/moe.groups/", "moe.experts/moe.shared/"):
+        assert scope in text, scope
+    assert "hc.mix" not in text  # the plain residual
+    _, one_group = _latent_engine(attention_impl="fused")
+    if which == "paged_prefill":
+        one_group.admit(0, {"tokens": np.arange(1, 10), "max_new_tokens": 2})
+    fn, args = _program_args(one_group, which)
+    assert "moe.groups" not in fn.lower(*args).as_text(debug_info=True)
+
+
+def test_share_decode_span_and_stats_carry_the_held_counts(tmp_path):
+    """`engine.decode` of a replica that holds a share: `moe_pairs` is what
+    the live slots' router chose over ALL routed experts (slots x top_k x
+    expert layers), `moe_pairs_held` those on held experts, `moe_hottest`
+    / `moe_touched` over the held experts alone; the latent pool's walk is
+    counted as the per-head pool's is (`kv_blocks_walked` of
+    `kv_table_blocks`). `engine.stats()` sums them and says what is held
+    of what is routed. A replica that holds every expert reports
+    `moe_pairs_held == moe_pairs`."""
+    cfg, eng = _share_engine()
+    rng = np.random.default_rng(0)
+    for slot, n in ((0, 11), (1, 5), (2, 19)):
+        eng.admit(slot, {"tokens": rng.integers(1, cfg.vocab_size, size=n),
+                         "max_new_tokens": 8})
+    eng.step([0, 1, 2])  # compiled outside the trace
+    with _Trace(tmp_path) as tr:
+        eng.step([0, 1, 2])
+        eng.step([2])
+    three, one = (st for _, _, st in tr.spans("engine.decode"))
+    per_slot = cfg.top_k * 2  # two EXPERT layers of three
+    assert three["moe_pairs"] == 3 * per_slot and one["moe_pairs"] == per_slot
+    for st in (three, one):
+        assert 0 <= st["moe_pairs_held"] <= st["moe_pairs"]
+        assert st["moe_touched"] <= st["moe_pairs_held"]
+        assert st["moe_hottest"] <= st["moe_pairs_held"]
+        assert st["moe_touched"] <= 2 * cfg.n_experts  # held groups a layer
+    # kv_tokens 13 + 7 + 21, in 2 + 1 + 3 blocks of 8, of a 4 x 16 table
+    assert three["kv_tokens"] == 41
+    assert three["kv_blocks_walked"] == 6 and one["kv_blocks_walked"] == 3
+    assert three["kv_table_blocks"] == one["kv_table_blocks"] == 4 * 16
+    stats = eng.stats()
+    assert (stats["experts_held"], stats["experts_routed"]) == (4, 16)
+    assert stats["moe_pairs"] == 7 * per_slot
+    assert 0 < stats["moe_pairs_held"] < stats["moe_pairs"]
+    assert stats["kv_blocks_walked"] == 6 + 6 + 3
+    assert stats["kv_table_blocks"] == 3 * 64
+    assert stats["param_bytes"] == sum(
+        a.nbytes for a in jax.tree.leaves(eng.params))
+
+    whole_cfg, whole = _latent_engine()
+    whole.admit(0, {"tokens": np.arange(1, 12), "max_new_tokens": 8})
+    whole.step([0])
+    with _Trace(tmp_path / "whole") as tr:
+        whole.step([0])
+    (_, _, st), = tr.spans("engine.decode")
+    assert st["moe_pairs_held"] == st["moe_pairs"] == whole_cfg.top_k
+    stats = whole.stats()
+    assert stats["experts_held"] == stats["experts_routed"] == 4
+    assert stats["moe_pairs_held"] == stats["moe_pairs"]
+
+
+@pytest.mark.parametrize("asked,word", [
+    (dict(kv_cache_dtype="int8"), "kv_dtype"),
+    (dict(speculative_k=2), "speculative_k"),
+    (dict(mesh="a mesh"), "mesh"),
+], ids=["int8", "speculation", "mesh"])
+def test_the_shares_latent_pool_refuses_what_it_has_no_path_for(asked, word):
+    with pytest.raises(NotImplementedError, match=f"latent.*{word}"):
+        _share_engine(**asked)
+
+
 # ---- a hybrid cache: linear (gated-delta-rule) layers beside full ones -----
 
 
