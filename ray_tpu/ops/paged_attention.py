@@ -220,18 +220,25 @@ def _pa_kernel(tables_ref, pos_ref, kvlen_ref, layer_ref, slots_ref, q_ref,
             o_ref[0] = unflat(acc[:] / safe_l).astype(out_dtype)
 
 
-def _live_walk(ptable, positions, kv_len, n_queries, bt):
-    """What a call has to visit, from its own scalars: (`slots` [B] int32,
-    the live slots' ids first and in slot order; how many are live; the
-    longest live walk in blocks). A slot's walk ends with its last live
-    entry (>= 0) among the blocks that hold a key some query may see, i.e.
-    before ceil(min(kv_len, positions + Q) / bt): a released slot (a table
-    of dead entries) walks nothing, and neither does the table's tail."""
-    b, nmax = ptable.shape
+def _walk_blocks(ptable, positions, kv_len, n_queries, bt):
+    """[B] int32: how many table entries each slot's walk covers. A walk
+    ends with the slot's last live entry (>= 0) among the blocks that hold a
+    key some query may see, i.e. before ceil(min(kv_len, positions + Q) /
+    bt): a released slot (a table of dead entries) walks nothing, and
+    neither does the table's tail."""
+    nmax = ptable.shape[1]
     seen = jnp.minimum(kv_len, positions + n_queries)
     j = jnp.arange(nmax, dtype=jnp.int32)[None, :]
     visible = jnp.logical_and(ptable >= 0, j * bt < seen[:, None])
-    walk = jnp.max(jnp.where(visible, j + 1, 0), axis=1)  # [B] blocks
+    return jnp.max(jnp.where(visible, j + 1, 0), axis=1)
+
+
+def _live_walk(ptable, positions, kv_len, n_queries, bt):
+    """What a per-head call has to visit, from its own scalars: (`slots` [B]
+    int32, the live slots' ids first and in slot order; how many are live;
+    the longest live walk in blocks, `_walk_blocks`)."""
+    b = ptable.shape[0]
+    walk = _walk_blocks(ptable, positions, kv_len, n_queries, bt)
     live = walk > 0
     # slots[i] = the i-th live slot: the first slot with i + 1 live slots at
     # or before it ([B, B] compares; no sort, nothing the pool's size touches)
@@ -523,14 +530,56 @@ def paged_attention(
 # --------------------------------------------------------------------------
 
 
-def _mla_kernel(tables_ref, pos_ref, kvlen_ref, layer_ref, q_ref, *rest,
-                bt, qb, nb, heads, rank, scale, out_dtype):
-    del layer_ref  # read by the pool's index maps only
-    tiles, (o_ref, acc, m_i, l_i) = rest[:nb], rest[nb:]
-    b = pl.program_id(0)
-    qt = pl.program_id(1)
-    j = pl.program_id(2)
-    nj = pl.num_programs(2)
+def _live_steps(ptable, positions, kv_len, n_queries, bt, nb):
+    """The latent kernel's work list, from the call's own scalars: the
+    (slot, step) pairs that hold a key some query may see, in slot order,
+    a step being `nb` consecutive table entries (`ptable` [B, Nmax], Nmax a
+    multiple of nb). Returns
+
+      step_slot, step_j  [B * Nmax / nb] int32: pair i is step `step_j[i]`
+                         of slot `step_slot[i]`, for i < n_steps
+      step_blocks        [B * Nmax] int32: pair i's pool blocks, its nb
+                         table entries at i * nb (a dead one is block 0),
+                         gathered ONCE here so that each of the kernel's nb
+                         index maps reads one scalar
+      n_steps            int32 scalar = sum over slots of ceil(walk / nb),
+                         `walk` the slot's `_walk_blocks`
+      keys               [B] int32: how many of a slot's cached keys exist
+                         and may be seen, min(kv_len, positions + Q,
+                         walk * bt) (a latent table is live from the front)
+
+    A slot's last step is the one with (step_j + 1) * nb * bt >= keys. With
+    nothing live, pair 0 is the LAST slot's step 0 and that slot's `keys` is
+    0: visited, found dead, finalized to zeros."""
+    b, nmax = ptable.shape
+    walk = _walk_blocks(ptable, positions, kv_len, n_queries, bt)
+    counts = (walk + (nb - 1)) // nb  # [B] steps a slot
+    ends = jnp.cumsum(counts)  # slot s owns pairs [ends - counts, ends)
+    i = jnp.arange(b * (nmax // nb), dtype=jnp.int32)
+    # the slots that end at or before pair i are the slots before its own
+    # ([steps, B] compares, as `_live_walk`: no sort)
+    before = ends[None, :] <= i[:, None]
+    step_slot = jnp.minimum(jnp.sum(before, axis=1, dtype=jnp.int32), b - 1)
+    step_j = i - jnp.sum(jnp.where(before, counts[None, :], 0), axis=1,
+                         dtype=jnp.int32)
+    # past the list's end step_j runs on: clamped, never visited
+    step_blocks = jnp.maximum(
+        ptable.reshape(b, nmax // nb, nb)[
+            step_slot, jnp.minimum(step_j, nmax // nb - 1)], 0)
+    keys = jnp.minimum(jnp.minimum(kv_len, positions + n_queries), walk * bt)
+    return step_slot, step_j, step_blocks.reshape(-1), ends[-1], keys
+
+
+def _mla_kernel(blocks_ref, pos_ref, keys_ref, layer_ref, slot_ref, step_ref,
+                q_ref, *rest, bt, qb, nb, heads, rank, scale, out_dtype):
+    del blocks_ref, layer_ref  # read by the index maps only
+    # after the step's tiles, the output's aliased zeros: an HBM operand the
+    # body never touches (a slot the grid does not visit keeps them)
+    tiles, (_, o_ref, acc, m_i, l_i) = rest[:nb], rest[nb:]
+    qt = pl.program_id(0)
+    i = pl.program_id(1)  # the second axis walks LIVE (slot, step) pairs
+    b = slot_ref[i]
+    j = step_ref[i]
     rows = qb * heads
     span = nb * bt  # tokens one grid step attends
 
@@ -540,16 +589,12 @@ def _mla_kernel(tables_ref, pos_ref, kvlen_ref, layer_ref, q_ref, *rest,
         m_i[:] = jnp.full_like(m_i, NEG_INF)
         l_i[:] = jnp.zeros_like(l_i)
 
-    pos = pos_ref[b]
-    qbase = pos + qt * qb  # global position of this tile's first query
-    # a slot's table is live from the front (the engine fills it in order,
-    # and a latent pool is never sharded), so the keys of this step that
-    # exist are those before `n_live` blocks' worth and before kv_len
-    n_live = jnp.int32(0)
-    for i in range(nb):
-        n_live += (tables_ref[b, j * nb + i] >= 0).astype(jnp.int32)
-    kvl = jnp.minimum(kvlen_ref[b], (j * nb + n_live) * bt)
-    live = jnp.logical_and(j * span < kvl, j * span <= qbase + qb - 1)
+    keys = keys_ref[b]
+    qbase = pos_ref[b] + qt * qb  # global position of this tile's first query
+    # a listed step holds a key SOME query sees (the one step of an empty
+    # list holds none); this tile attends it iff its own last query does (an
+    # early prefill tile under later steps)
+    live = jnp.logical_and(j * span < keys, j * span <= qbase + qb - 1)
 
     @pl.when(live)
     def _attend():
@@ -563,7 +608,7 @@ def _mla_kernel(tables_ref, pos_ref, kvlen_ref, layer_ref, q_ref, *rest,
         kpos = j * span + lax.broadcasted_iota(jnp.int32, (rows, span), 1)
         # row = query * heads + head
         qi = lax.broadcasted_iota(jnp.int32, (rows, span), 0) // heads
-        mask = jnp.logical_and(kpos <= qbase + qi, kpos < kvl)
+        mask = jnp.logical_and(kpos <= qbase + qi, kpos < keys)
         s = jnp.where(mask, s, NEG_INF)
         m_prev = m_i[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -578,7 +623,7 @@ def _mla_kernel(tables_ref, pos_ref, kvlen_ref, layer_ref, q_ref, *rest,
         )
         acc[:] = acc[:] * alpha + pv
 
-    @pl.when(j == nj - 1)
+    @pl.when((j + 1) * span >= keys)  # the slot's last step
     def _finalize():
         l = l_i[:]
         safe_l = jnp.where(l == 0.0, 1.0, l)
@@ -588,25 +633,50 @@ def _mla_kernel(tables_ref, pos_ref, kvlen_ref, layer_ref, q_ref, *rest,
 def _mla_attention_pallas(q, pool, ptable, positions, kv_len, layer, rank,
                           scale, interpret, block_q, blocks_per_step,
                           block_rows):
-    """The latent walk as a Pallas call: grid (slot, query tile, step of
-    `blocks_per_step` table entries), `_mla_kernel` the body.
+    """The latent walk as a Pallas call: grid (query tile, LIVE (slot, step)
+    pair), `_mla_kernel` the body.
+
+    What is walked. A step is `blocks_per_step` consecutive table entries
+    (16: 1,024 tokens), and the grid's second axis is the flat list of the
+    steps that hold a key some query of the call may see (`_live_steps`,
+    computed inside the program from tables, positions and kv_len): slot
+    after slot, each for ceil(its walk / blocks_per_step) steps. The list's
+    length is a TRACED grid bound, so one compiled program per (B, Nmax, Q)
+    serves every occupancy; index maps and body read the pair's slot, step
+    and pool blocks from three scalar-prefetch arrays. Scratch is
+    initialised at a slot's step 0 and the output tile written at its last
+    step; the output starts as aliased zeros, so a slot with no visible key
+    is never visited and reads exactly zero. Nothing of the table's shape is
+    left in the walk: not dead slots, not the tail of a live slot's row, not
+    a short slot's steps under a longer one's (the rectangle the per-head
+    kernel keeps). A dead entry inside a slot's last step is block 0 in the
+    list, as the per-head maps clamp it, and its keys are masked by `keys`.
+
+    How wide a step is. A grid step costs ~0.4 us whatever it moves (a fit
+    to the sweep) and a slot's last step is on average half empty, so a wide
+    step suits long contexts and a narrow one short ones. Swept on a v5e at
+    both latent cells' shapes (PERF.md, PR 43; us a decode call at 8 / 16
+    entries a step): 6 live slots of 4k-16k keys under 32 heads 117 / 103,
+    22 such slots 459 / 389; 64 live slots of ~1.2k keys under 128 heads
+    319 / 324; 4 entries lose everywhere but a short prefill. What decides
+    is the context's length, not the head count, so nothing is derived from
+    `heads`: ONE width, 16, which loses 1.5 % at most where 8 loses 15 %.
 
     How a tile is sized. Every head of a query scores the same cached row,
     so a tile's matmul rows are queries x HEADS, and what the body keeps in
-    VMEM goes by rows, not by queries: the f32 accumulator [rows, rank] and
-    score tile [rows, span] (span = blocks_per_step x block_tokens = 512),
-    2 KB a row each and a third such tile for the probabilities, beside the
-    query tile [rows, W] bf16 and the output tile [rows, rank] bf16, both
-    double-buffered by the pipeline (2 x (1,280 + 1,024) B a row), and the
-    step's pool blocks (8 x 64 x 640 x 2 B, twice: 1.3 MB). A tile is
-    therefore min(block_q, block_rows // heads) queries:
+    VMEM goes by rows, not by queries: the f32 accumulator [rows, rank]
+    (2 KB a row), the score tile [rows, span] (span = blocks_per_step x
+    block_tokens = 1,024: 4 KB a row) and a second such tile for the
+    probabilities, beside the query tile [rows, W] bf16 and the output tile
+    [rows, rank] bf16, both double-buffered by the pipeline (2 x (1,280 +
+    1,024) B a row), and the step's pool blocks (16 x 64 x 640 x 2 B, twice:
+    2.6 MB). A tile is therefore min(block_q, block_rows // heads) queries:
 
-      32 heads   16 queries = 512 rows: 3 x 1 MB f32 + 2.3 MB of q and
-                 output + 1.3 MB of blocks = 6.6 MB (the tile this kernel
-                 was written and measured with)
-      128 heads  16 queries would be 2,048 rows: 3 x 4 MB + 9.2 MB + 1.3 MB
-                 = 22.5 MB, over the 16 MB of scoped VMEM a kernel gets on a
-                 v5e; 4 queries = 512 rows is the same 6.6 MB
+      32 heads   16 queries = 512 rows: 5 MB f32 + 2.3 MB of q and output
+                 + 2.6 MB of blocks = 9.9 MB
+      128 heads  16 queries would be 2,048 rows: 20 MB + 9.2 MB + 2.6 MB,
+                 over the 16 MB of scoped VMEM a kernel gets on a v5e;
+                 4 queries = 512 rows is the same 9.9 MB
 
     and in decode (one query) a tile is `heads` rows: 128 heads fill the
     MXU's 128 rows with one slot's query."""
@@ -624,32 +694,28 @@ def _mla_attention_pallas(q, pool, ptable, positions, kv_len, layer, rank,
     qp = -(-Q // qb) * qb
     if qp != Q:
         q = jnp.pad(q, ((0, 0), (0, qp - Q), (0, 0), (0, 0)))
-    grid = (b, qp // qb, nmax // nb)
+    step_slot, step_j, step_blocks, n_steps, keys = _live_steps(
+        ptable, positions, kv_len, Q, bt, nb)
+    # at least one step: with nothing live the list's first pair is visited,
+    # found dead and finalized to the zeros the output holds already
+    grid = (qp // qb, jnp.maximum(n_steps, 1))
 
-    def tile_spec(i):
-        def index(b_, qt_, j_, tbl, pos, kvl, lyr):
-            entry = tbl[b_, j_ * nb + i]
-            first = (j_ * nb + i) * bt  # the block's first token position
-            # a block no query of this tile may see — dead, past kv_len, or
-            # after the tile's last query — maps to block 0: a repeated
-            # index is not fetched again
-            seen = jnp.logical_and(
-                entry >= 0,
-                jnp.logical_and(first < kvl[b_],
-                                first <= pos[b_] + (qt_ + 1) * qb - 1))
-            return lyr[0], jnp.where(seen, entry, 0), 0, 0
+    def tile_spec(k):  # block k of the pair's step
+        return pl.BlockSpec(
+            (None, None, bt, w),
+            lambda qt_, i_, blk, pos, kys, lyr, slt, stp: (
+                lyr[0], blk[i_ * nb + k], 0, 0))
 
-        return pl.BlockSpec((None, None, bt, w), index)
-
-    o_map = lambda b_, qt_, j_, *_: (b_, qt_, 0, 0)
+    o_map = lambda qt_, i_, blk, pos, kys, lyr, slt, stp: (slt[i_], qt_, 0, 0)
     kernel = functools.partial(
         _mla_kernel, bt=bt, qb=qb, nb=nb, heads=h, rank=rank, scale=scale,
         out_dtype=q.dtype,
     )
+    n_scalars = 6
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=n_scalars,
             grid=grid,
             # the pool goes in once per block of a step, each with its own
             # index map: `nb` table entries' blocks are in flight together,
@@ -657,7 +723,8 @@ def _mla_attention_pallas(q, pool, ptable, positions, kv_len, layer, rank,
             # whatever it moves; at one 64-token block a step the walk of a
             # 16k context is all overhead)
             in_specs=[pl.BlockSpec((1, qb, h, w), o_map)]
-            + [tile_spec(i) for i in range(nb)],
+            + [tile_spec(k) for k in range(nb)]
+            + [pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=[pl.BlockSpec((1, qb, h, rank), o_map)],
             scratch_shapes=[
                 pltpu.VMEM((qb * h, rank), jnp.float32),
@@ -666,14 +733,20 @@ def _mla_attention_pallas(q, pool, ptable, positions, kv_len, layer, rank,
             ],
         ),
         out_shape=[jax.ShapeDtypeStruct((b, qp, h, rank), q.dtype)],
+        # the output starts as zeros (aliased, left in HBM): the rows of a
+        # slot the grid never visits are what a walk over no key would write
+        input_output_aliases={n_scalars + 1 + nb: 0},
+        # query tiles are independent; the pairs are walked in order — a
+        # slot's steps carry its online-softmax scratch
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
+            dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
         # the profiler's event is %mla_paged_attention.<n>: the benchmark's
         # readers find the kernel by this name
         name="mla_paged_attention",
-    )(ptable, positions, kv_len, jnp.reshape(layer, (1,)), q, *([pool] * nb))
+    )(step_blocks, positions, keys, jnp.reshape(layer, (1,)), step_slot,
+      step_j, q, *([pool] * nb), jnp.zeros((b, qp, h, rank), q.dtype))
     return out[0][:, :Q]
 
 
@@ -691,7 +764,7 @@ def mla_paged_attention(
     impl: str = "auto",    # auto | kernel | xla
     interpret: Optional[bool] = None,
     block_q: int = 16,       # queries a tile at most (the per-head op's default)
-    blocks_per_step: int = 8,  # pool blocks in flight a grid step
+    blocks_per_step: int = 16,  # pool blocks in flight a grid step
     block_rows: int = 512,   # queries x heads a tile at most: what VMEM holds
 ) -> jnp.ndarray:
     """Absorbed multi-query attention over a latent (MLA) pool: every head
@@ -699,9 +772,12 @@ def mla_paged_attention(
     positions t <= positions[b] + i, t < kv_len[b], and the output is the
     softmax-weighted sum of the rows' first `rank` columns -> [B, Q, H,
     rank] in q's dtype. One row serves as key and value for all H heads, so
-    the walk reads a token's row once. Tables are live from the front (no
-    signed / sharded tables here). The XLA twin is the per-head op's chunked
-    walk with the pool as K and as V over one kv head."""
+    the walk reads a token's row once. The kernel visits the call's live
+    (slot, step) pairs and nothing else of the [B, Nmax] table
+    (`_mla_attention_pallas`); a slot with no visible key reads zeros.
+    Tables are live from the front (no signed / sharded tables here). The
+    XLA twin is the per-head op's chunked walk with the pool as K and as V
+    over one kv head."""
     global _LAST_IMPL
     if pool.ndim != 5 or pool.shape[3] != 1 or q.shape[-1] != pool.shape[-1]:
         raise ValueError(

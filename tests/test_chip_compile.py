@@ -406,10 +406,11 @@ def _xing4_block(n_layers=2):
     ids=["decode", "prefill", "decode-128-heads", "prefill-128-heads"])
 def test_mla_kernel_compiles(one_chip, batch, q_len, table, heads):
     """The latent kernel at the benchmark cells' shapes: 32 heads over
-    640-wide rows, 8 blocks a grid step, an 18,432-token table (Xing4.0);
+    640-wide rows, 16 blocks a grid step, an 18,432-token table (Xing4.0);
     128 heads, 64 slots and a 5,120-token table (DeepSeek-V2), where a
     prefill tile of 16 queries x 128 heads would pass the kernel's VMEM
-    and `block_rows` cuts it to 4 queries."""
+    and `block_rows` cuts it to 4 queries. The grid's second bound is
+    traced (the call's live (slot, step) pairs): Mosaic takes it."""
     import importlib
 
     pa = importlib.import_module("ray_tpu.ops.paged_attention")
@@ -518,7 +519,15 @@ def test_latent_paged_programs_move_no_pool(one_chip, monkeypatch, program):
         cfg, compiled = _compile_paged_program(
             one_chip, monkeypatch, program, "fused", False, num_blocks,
             cfg=_xing4_block(), tree="held")
-        assert "mla_paged_attention" in compiled.as_text()
+        # ONE kernel a layer body (the dense stack's and the expert
+        # stack's), whose grid bound is the call's live (slot, step) pairs:
+        # no second kernel for another occupancy
+        calls = [name for _, name, _, _, op, rest
+                 in _HLO_INSTRUCTION.findall(compiled.as_text())
+                 if op == "custom-call" and "tpu_custom_call" in rest
+                 and not name.startswith("ragged-dot")]
+        assert len(calls) == 2 and all(
+            c.startswith("mla_paged_attention") for c in calls), calls
         mem = compiled.memory_analysis()
         pool_bytes = cfg.n_layers * num_blocks * BLOCK_TOKENS * 640 * 2
         assert mem.alias_size_in_bytes >= pool_bytes

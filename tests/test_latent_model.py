@@ -156,32 +156,130 @@ def test_forward_gives_the_reference_logits(params):
         np.asarray(got), _ref(params, PROMPT, None), atol=TOL, rtol=0)
 
 
-@pytest.mark.parametrize("batch,q_len,positions,block_q,per_step", [
-    (3, 1, [13, 40, 0], 16, 3), (1, 12, [17], 4, 8), (2, 5, [0, 30], 2, 1),
-], ids=["decode", "prefill-tiles", "one-block-a-step"])
-def test_mla_kernel_equals_its_xla_twin(batch, q_len, positions, block_q,
-                                        per_step):
+# The latent kernel's grid is the flat list of the call's live (slot, step)
+# pairs (ISSUE 43). Each case is one occupancy of a [B, 7] table of 8-token
+# blocks: `positions` a slot's first query (None = a released slot: position
+# 0 and a table of null entries), `q_len` queries a slot, in tiles of
+# `block_q`, `per_step` table entries a grid step.
+_MLA_CASES = {
+    "decode": dict(positions=[13, 40, None], block_q=16, per_step=3),
+    "prefill-tiles": dict(positions=[17], q_len=12, block_q=4, per_step=8),
+    "one-block-a-step": dict(positions=[0, 30], q_len=5, block_q=2,
+                             per_step=1),
+    "all-dead": dict(positions=[None, None, None], per_step=2),
+    "dead-between-live": dict(positions=[None, 13, None, None, 40, 5, None],
+                              per_step=2),
+    # one block beside the whole table (7 blocks = 56 tokens)
+    "unequal-lengths": dict(positions=[3, 55, 0, 55, 9], per_step=2),
+    # 38 keys = 5 blocks: two steps of 4 entries, the last a quarter full
+    "last-step-partly-full": dict(positions=[37, None, 20], per_step=4),
+    # 7 entries in steps of 3: the table is padded to 9 with dead entries
+    "table-not-a-multiple": dict(positions=[55, 17, None], per_step=3),
+    # three tiles of 4 queries behind 17 cached keys, the window capped at
+    # 22 keys: kv_len < positions + Q, the last tile's late queries see less
+    "prefill-behind-cache-short-kv": dict(
+        positions=[17, None], q_len=12, block_q=4, per_step=2, kv_short=7),
+    # the verify shape: the window ends strictly before the first query
+    "kv-len-before-the-queries": dict(
+        positions=[11, None, 27], q_len=3, per_step=2, kv_short=3),
+    # blocks reserved ahead of what is visible: live entries the walk skips
+    "reserved-ahead": dict(positions=[10, 0, None], per_step=2, reserve=3),
+}
+
+
+@pytest.mark.parametrize("case", list(_MLA_CASES))
+def test_mla_kernel_equals_its_xla_twin(case):
+    spec = _MLA_CASES[case]
+    q_len, block_q = spec.get("q_len", 1), spec.get("block_q", 16)
     pa = importlib.import_module("ray_tpu.ops.paged_attention")
     rng = np.random.default_rng(0)
     n_blocks, width, rank, heads, nmax = 20, 128, 32, 4, 7
     pool = jnp.asarray(rng.normal(size=(2, n_blocks, BT, 1, width)), jnp.float32)
+    dead = np.array([p is None for p in spec["positions"]])
+    positions = np.array([p or 0 for p in spec["positions"]], np.int32)
+    batch = len(positions)
     tables = np.zeros((batch, nmax), np.int32)
     for b, pos in enumerate(positions):
-        need = -(-(pos + q_len) // BT)
-        tables[b, :need] = rng.permutation(np.arange(1, n_blocks))[:need]
-    tables[-1] = tables[-1] if batch < 3 else 0  # an inactive slot
+        if not dead[b]:
+            need = min(-(-(pos + q_len) // BT) + spec.get("reserve", 0), nmax)
+            tables[b, :need] = rng.permutation(np.arange(1, n_blocks))[:need]
+    kv_len = np.where(dead, 0, positions + q_len - spec.get("kv_short", 0))
+    dead |= kv_len == 0
     q = jnp.asarray(rng.normal(size=(batch, q_len, heads, width)), jnp.float32)
-    kw = dict(layer=1, rank=rank, scale=0.2,
-              kv_len=jnp.asarray(positions) + q_len)
+    kw = dict(layer=1, rank=rank, scale=0.2, kv_len=jnp.asarray(kv_len))
     got = pa.mla_paged_attention(
         q, pool, jnp.asarray(tables), jnp.asarray(positions), impl="kernel",
-        interpret=True, block_q=block_q, blocks_per_step=per_step, **kw)
+        interpret=True, block_q=block_q, blocks_per_step=spec["per_step"],
+        **kw)
     want = pa.mla_paged_attention(
         q, pool, jnp.asarray(tables), jnp.asarray(positions), impl="xla", **kw)
     assert got.shape == (batch, q_len, heads, rank)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
-    if batch == 3:
-        assert not np.asarray(got[2]).any()  # a dead table attends nothing
+    # a slot with no visible key is never visited: its rows are exactly zero
+    assert not np.asarray(got)[dead].any()
+    assert np.asarray(got)[~dead].any() or dead.all()
+
+
+def test_live_steps_lists_the_pairs_that_hold_a_visible_key():
+    """The work list by hand: bt 8, 2 entries a step, a [4, 6] table."""
+    pa = importlib.import_module("ray_tpu.ops.paged_attention")
+    dead = [-1] * 6
+    ptable = jnp.asarray([
+        [5, 6, 7, -1, -1, -1],   # 21 keys seen = 3 blocks = 2 steps
+        dead,                    # released: no step
+        [1, 2, 3, 4, 8, 9],      # kv_len caps the walk at 10 keys = 1 step
+        [11, -1, -1, -1, -1, -1],  # one key = 1 step
+    ], jnp.int32)
+    positions = jnp.asarray([20, 0, 30, 0], jnp.int32)
+    kv_len = jnp.asarray([21, 0, 10, 1], jnp.int32)
+    slot, j, blocks, n, keys = pa._live_steps(
+        ptable, positions, kv_len, 1, 8, 2)
+    assert slot.shape == j.shape == (4 * 3,) and slot.dtype == jnp.int32
+    assert int(n) == 2 + 0 + 1 + 1  # sum of ceil(visible blocks / 2)
+    assert np.asarray(slot)[:4].tolist() == [0, 0, 2, 3]  # slot order
+    assert np.asarray(j)[:4].tolist() == [0, 1, 0, 0]
+    # pair i's two pool blocks sit at 2 * i; a dead entry is block 0
+    assert np.asarray(blocks)[:8].tolist() == [5, 6, 7, 0, 1, 2, 11, 0]
+    assert np.asarray(keys).tolist() == [21, 0, 10, 1]
+    # a slot's last step is where (j + 1) * 16 reaches its keys
+    last = (np.asarray(j)[:4] + 1) * 16 >= np.asarray(keys)[np.asarray(slot)[:4]]
+    assert last.tolist() == [False, True, True, True]
+    # 12 queries a slot: a walk ends with the last query's block (slot 2:
+    # 21 keys = 3 blocks) and with the slot's last live entry (slots 0, 3)
+    *_, n, keys = pa._live_steps(ptable, positions, kv_len + 11, 12, 8, 2)
+    assert int(n) == 2 + 0 + 2 + 1
+    assert np.asarray(keys).tolist() == [24, 0, 21, 8]
+    # nothing live: pair 0 is the LAST slot's step 0, and it holds no key
+    slot, j, blocks, n, keys = pa._live_steps(
+        jnp.asarray([dead] * 4, jnp.int32), positions, kv_len, 1, 8, 2)
+    assert int(n) == 0 and int(slot[0]) == 3 and int(j[0]) == 0
+    assert not np.asarray(keys).any() and not np.asarray(blocks).any()
+
+
+def test_one_compiled_program_serves_every_live_set():
+    """The list's length is a traced grid bound: three occupancies of one
+    (B, Nmax), nothing live among them, run ONE compiled program."""
+    pa = importlib.import_module("ray_tpu.ops.paged_attention")
+    rng = np.random.default_rng(1)
+    pool = jnp.asarray(rng.normal(size=(1, 12, BT, 1, 128)), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(3, 1, 2, 128)), jnp.float32)
+
+    @jax.jit
+    def both(tables, positions):
+        kw = dict(layer=0, rank=32, scale=0.3, blocks_per_step=2)
+        return tuple(pa.mla_paged_attention(
+            q, pool, tables, positions, impl=impl, **kw)
+            for impl in ("kernel", "xla"))
+
+    for lens in ([9, 0, 30], [0, 0, 0], [1, 40, 17]):
+        tables = np.zeros((3, 5), np.int32)
+        for b, n in enumerate(lens):
+            tables[b, :-(-n // BT)] = 1 + rng.permutation(11)[:-(-n // BT)]
+        got, want = both(jnp.asarray(tables),
+                         jnp.asarray(np.maximum(np.array(lens) - 1, 0)))
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+        assert not np.asarray(got)[np.array(lens) == 0].any()
+    assert both._cache_size() == 1
 
 
 def test_latent_pool_is_one_leaf_of_padded_rows():
